@@ -166,6 +166,8 @@ def run(verbose: bool = True) -> Dict:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None,

@@ -339,6 +339,8 @@ def run(verbose: bool = True) -> Dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     out_path = None
     if "--out" in sys.argv:
         idx = sys.argv.index("--out") + 1

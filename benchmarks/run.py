@@ -50,6 +50,8 @@ BENCHES = [
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import importlib
     csv_lines = ["name,us_per_call,derived"]
     failures = []

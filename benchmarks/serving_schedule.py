@@ -325,6 +325,8 @@ def _flag(name: str) -> Optional[str]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     out_path = _flag("--out")
     spans_path = _flag("--spans-out")
     metrics_path = _flag("--metrics-out")

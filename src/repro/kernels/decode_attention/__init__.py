@@ -1,5 +1,4 @@
-from repro.kernels.decode_attention.ops import (decode_attention,
-                                                decode_attention_cache,
+from repro.kernels.decode_attention.ops import (decode_attention_cache,
                                                 paged_decode_attention)
 from repro.kernels.decode_attention.ref import (decode_attention_ref,
                                                 paged_decode_attention_ref)

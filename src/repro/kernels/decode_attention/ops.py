@@ -1,7 +1,6 @@
-"""Jitted wrapper for decode attention. The model-level entry point accepts the
-boolean mask the reference attention uses and converts to the kernel's
-(pos, q_pos) form when the caller has them; the direct (pos, q_pos) API is the
-efficient path used by the serving engine."""
+"""Jitted wrappers for decode attention over the dense ring cache and over the
+paged block pools (the serving hot path). Both take positions, not masks:
+``pos`` per cache slot and ``q_pos`` per sequence."""
 from __future__ import annotations
 
 import functools
@@ -14,11 +13,8 @@ from repro.kernels.decode_attention.decode_attention import (
     decode_attention_pallas, paged_decode_attention_pallas)
 from repro.kernels.decode_attention.ref import (decode_attention_ref,
                                                 paged_decode_attention_ref)
+from repro.kernels.platform import interpret_mode
 from repro.obs.profiling import kernel_scope
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "window", "block_k"))
@@ -30,7 +26,7 @@ def decode_attention_cache(q, k_cache, v_cache, pos, q_pos, *,
         return decode_attention_pallas(q, k_cache, v_cache, pos, q_pos,
                                        scale=scale, window=window,
                                        block_k=block_k,
-                                       interpret=not _on_tpu())
+                                       interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("scale",))
@@ -41,17 +37,8 @@ def paged_decode_attention(q, k_pool, v_pool, pos_pool, block_table, q_pos, *,
     with kernel_scope("paged_decode_attention"):
         return paged_decode_attention_pallas(q, k_pool, v_pool, pos_pool,
                                              block_table, q_pos, scale=scale,
-                                             interpret=not _on_tpu())
+                                             interpret=interpret_mode())
 
 
-def decode_attention(q, k_cache, v_cache, mask, *, scale=None):
-    """Mask-based compatibility shim for repro.models.attention: falls back to
-    the reference math (the mask already encodes positions/window)."""
-    import numpy as np
-    from repro.models.attention import sdpa
-    return sdpa(q, k_cache, v_cache, mask,
-                scale if scale is not None else q.shape[-1] ** -0.5)
-
-
-__all__ = ["decode_attention_cache", "decode_attention", "decode_attention_ref",
+__all__ = ["decode_attention_cache", "decode_attention_ref",
            "paged_decode_attention", "paged_decode_attention_ref"]

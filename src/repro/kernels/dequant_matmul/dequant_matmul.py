@@ -13,19 +13,21 @@ Both kernels run a (m, n, k) grid with the contraction innermost (the
 * int8 (per-out-channel scales): the scale folds out of the k-sum exactly, so
   raw integer products accumulate and one multiply by ``scale[n]`` happens at
   write-out.
-* int4 (group-wise scales): ``block_k`` equals the quantization group size,
-  so each grid step covers exactly one scale group. The packed rows stay
-  packed — the even input rows multiply the low nibbles and the odd rows the
-  high nibbles, which avoids materializing an interleaved unpacked tile:
+* int4 (group-wise scales): each grid step covers a K-block of whole scale
+  groups (a multiple of 256 input rows, so the half-width x tiles stay
+  128-lane aligned for Mosaic). The packed rows stay packed — the even input
+  rows multiply the low nibbles and the odd rows the high nibbles, which
+  avoids materializing an interleaved unpacked tile; each packed row is
+  scaled by its group's scale before the matmul (dequantize, then matmul):
 
-      acc += (x_even @ lo + x_odd @ hi) * scale[g]
+      acc += x_even @ (lo * scale[g(r)]) + x_odd @ (hi * scale[g(r)])
 
-Inputs are padded to block multiples (outputs sliced back); K never needs
-padding for int4 because quantization guarantees ``K % group_size == 0``.
+Inputs are padded to block multiples (outputs sliced back).
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -82,23 +84,26 @@ def dequant_matmul_int8_pallas(x: jnp.ndarray, qw: jnp.ndarray,
     return out[:M, :N]
 
 
-def _int4_kernel(x_ref, qw_ref, scale_ref, o_ref, acc_ref):
-    g, ng = pl.program_id(2), pl.num_programs(2)
+def _int4_kernel(xe_ref, xo_ref, qw_ref, scale_ref, o_ref, acc_ref):
+    k, nk = pl.program_id(2), pl.num_programs(2)
 
-    @pl.when(g == 0)
+    @pl.when(k == 0)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.float32)            # (bm, gs)
-    bm, gs = x.shape
-    xt = x.reshape(bm, gs // 2, 2)                # even/odd input rows
-    p = qw_ref[...]                               # (gs//2, bn) packed uint8
-    lo = (((p & 0xF).astype(jnp.int32) ^ 8) - 8).astype(jnp.float32)
-    hi = (((p >> 4).astype(jnp.int32) ^ 8) - 8).astype(jnp.float32)
-    part = jax.lax.dot(xt[:, :, 0], lo) + jax.lax.dot(xt[:, :, 1], hi)
-    acc_ref[...] += part * scale_ref[...]         # one scale group per step
+    p = qw_ref[...].astype(jnp.int32)             # (bk//2, bn) packed bytes
+    lo = (((p & 0xF) ^ 8) - 8).astype(jnp.float32)
+    hi = (((p >> 4) ^ 8) - 8).astype(jnp.float32)
+    s = scale_ref[0]                              # (ng, bn) groups in block
+    ng, bn = s.shape
+    rows = lo.shape[0] // ng                      # packed rows per group
+    s_rows = jnp.broadcast_to(s[:, None, :], (ng, rows, bn)).reshape(
+        ng * rows, bn)
+    acc_ref[...] += (
+        jax.lax.dot(xe_ref[...].astype(jnp.float32), lo * s_rows) +
+        jax.lax.dot(xo_ref[...].astype(jnp.float32), hi * s_rows))
 
-    @pl.when(g == ng - 1)
+    @pl.when(k == nk - 1)
     def _finish():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
@@ -113,30 +118,40 @@ def dequant_matmul_int4_pallas(x: jnp.ndarray, packed: jnp.ndarray,
 
     The group size ``K // G`` is implied by the shapes; it must be even (the
     quantizer guarantees this — two rows pack per byte).
+
+    The even and odd input columns of ``x`` are split here, once, so the
+    kernel needs no lane shuffle. Each grid step covers ``bk`` input rows:
+    the least common multiple of 256 and the group size, so that the
+    half-width x blocks are 128-lane multiples and a block holds whole
+    groups. K is zero-padded to ``bk`` (inert: zero nibbles decode to 0).
     """
     M, K = x.shape
     N = packed.shape[1]
     G = scale.shape[0]
     gs = K // G
+    bk = math.lcm(256, gs)
     bm = min(block_m, max(M, 8))
     bn = min(block_n, max(N, 128))
-    pm, pn = (-M) % bm, (-N) % bn
-    xp = jnp.pad(x, ((0, pm), (0, 0)))
-    # zero nibbles decode to 0 ((0 ^ 8) - 8 == 0), so N-padding is inert
-    qp = jnp.pad(packed, ((0, 0), (0, pn)))
-    sp = jnp.pad(scale.astype(jnp.float32), ((0, 0), (0, pn)))
-    grid = ((M + pm) // bm, (N + pn) // bn, G)
+    pm, pn, pk = (-M) % bm, (-N) % bn, (-K) % bk
+    xp = jnp.pad(x, ((0, pm), (0, pk)))
+    # zero nibbles decode to 0 ((0 ^ 8) - 8 == 0), so padding is inert
+    qp = jnp.pad(packed, ((0, pk // 2), (0, pn)))
+    ng = bk // gs
+    sp = jnp.pad(scale.astype(jnp.float32), ((0, pk // gs), (0, pn)))
+    sp = sp.reshape(-1, ng, N + pn)               # (K-blocks, ng, N)
+    grid = ((M + pm) // bm, (N + pn) // bn, (K + pk) // bk)
     out = pl.pallas_call(
         _int4_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, gs), lambda i, j, g: (i, g)),
-            pl.BlockSpec((gs // 2, bn), lambda i, j, g: (g, j)),
-            pl.BlockSpec((1, bn), lambda i, j, g: (g, j)),
+            pl.BlockSpec((bm, bk // 2), lambda i, j, k: (i, k)),
+            pl.BlockSpec((bm, bk // 2), lambda i, j, k: (i, k)),
+            pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)),
+            pl.BlockSpec((1, ng, bn), lambda i, j, k: (k, 0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, g: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M + pm, N + pn), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(xp, qp, sp)
+    )(xp[:, 0::2], xp[:, 1::2], qp, sp)
     return out[:M, :N]

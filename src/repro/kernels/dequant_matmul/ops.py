@@ -4,11 +4,12 @@ Format is discriminated by the quantized-weight dtype (no side metadata, so
 the dispatch survives `jax.lax.scan` over stacked per-layer params): ``int8``
 means per-out-channel int8, ``uint8`` means nibble-packed group-wise int4.
 
-On TPU the fused Pallas kernel runs (HBM moves the packed bytes); everywhere
-else the jnp oracle runs directly — unlike the attention ops wrappers this
-does *not* interpret the kernel on CPU, because the oracle's dequantize-
-then-matmul rounding is the semantics the serving bit-parity test pins and
-interpret-mode parity is covered by ``tests/test_quant.py``.
+On TPU the fused Pallas kernel runs (HBM moves the packed bytes); on CPU the
+jnp oracle runs directly — unlike the attention ops wrappers this does *not*
+interpret the kernel there, because the oracle's dequantize-then-matmul
+rounding is the semantics the serving bit-parity test pins and
+interpret-mode parity is covered by ``tests/test_quant.py``. Any other
+backend raises (`repro.kernels.platform.interpret_mode`).
 """
 from __future__ import annotations
 
@@ -21,30 +22,23 @@ from repro.kernels.dequant_matmul.ref import (dequant_matmul_int4_ref,
                                               dequant_matmul_int8_ref,
                                               dequantize_int4,
                                               dequantize_int8, unpack_int4)
+from repro.kernels.platform import interpret_mode
 from repro.obs.profiling import kernel_scope
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @jax.jit
 def dequant_matmul(x: jnp.ndarray, qw: jnp.ndarray,
                    scale: jnp.ndarray) -> jnp.ndarray:
     """``x (..., K) @ dequantize(qw, scale) -> (..., N)`` in x.dtype."""
-    lead = x.shape[:-1]
+    int4 = qw.dtype == jnp.uint8
     with kernel_scope("dequant_matmul"):
-        if qw.dtype == jnp.uint8:
-            if _on_tpu():
-                y = dequant_matmul_int4_pallas(x.reshape(-1, x.shape[-1]),
-                                               qw, scale)
-                return y.reshape(*lead, y.shape[-1])
-            return dequant_matmul_int4_ref(x, qw, scale)
-        if _on_tpu():
-            y = dequant_matmul_int8_pallas(x.reshape(-1, x.shape[-1]),
-                                           qw, scale)
-            return y.reshape(*lead, y.shape[-1])
-        return dequant_matmul_int8_ref(x, qw, scale)
+        if interpret_mode():
+            return (dequant_matmul_int4_ref if int4
+                    else dequant_matmul_int8_ref)(x, qw, scale)
+        kernel = (dequant_matmul_int4_pallas if int4
+                  else dequant_matmul_int8_pallas)
+        y = kernel(x.reshape(-1, x.shape[-1]), qw, scale)
+        return y.reshape(*x.shape[:-1], y.shape[-1])
 
 
 __all__ = ["dequant_matmul", "dequant_matmul_int8_pallas",

@@ -1,7 +1,8 @@
 """Jitted public wrapper for the flash attention kernel.
 
 On CPU the Pallas kernel executes in interpret mode (the kernel body runs in
-Python/XLA for correctness validation); on TPU the same call compiles to Mosaic.
+Python/XLA for correctness validation); on TPU the same call compiles to Mosaic
+(`repro.kernels.platform.interpret_mode`).
 """
 from __future__ import annotations
 
@@ -13,11 +14,8 @@ import jax.numpy as jnp
 
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
 from repro.kernels.flash_attention.ref import flash_attention_ref
+from repro.kernels.platform import interpret_mode
 from repro.obs.profiling import kernel_scope
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
@@ -30,7 +28,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
         return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                       scale=scale, block_q=block_q,
                                       block_k=block_k,
-                                      interpret=not _on_tpu())
+                                      interpret=interpret_mode())
 
 
 __all__ = ["flash_attention", "flash_attention_ref"]

@@ -8,11 +8,8 @@ import jax.numpy as jnp
 
 from repro.kernels.moe_gemm.moe_gemm import moe_gemm_pallas
 from repro.kernels.moe_gemm.ref import moe_gemm_ref
+from repro.kernels.platform import interpret_mode
 from repro.obs.profiling import kernel_scope
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "block_f", "block_d"))
@@ -20,7 +17,7 @@ def moe_gemm(x, w, *, block_c: int = 128, block_f: int = 128,
              block_d: int = 128) -> jnp.ndarray:
     with kernel_scope("moe_gemm"):
         return moe_gemm_pallas(x, w, block_c=block_c, block_f=block_f,
-                               block_d=block_d, interpret=not _on_tpu())
+                               block_d=block_d, interpret=interpret_mode())
 
 
 __all__ = ["moe_gemm", "moe_gemm_ref"]

@@ -2,18 +2,12 @@
 layout (B*H, nc, Q, ...)."""
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
+from repro.kernels.platform import interpret_mode
 from repro.kernels.ssd_scan.ref import ssd_chunk_ref
 from repro.kernels.ssd_scan.ssd_scan import ssd_chunk_pallas
 from repro.obs.profiling import kernel_scope
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def ssd_chunk(xc, dtc, dA, dA_cs, Bc, Cc):
@@ -35,7 +29,7 @@ def ssd_chunk(xc, dtc, dA, dA_cs, Bc, Cc):
 
     with kernel_scope("ssd_scan"):
         y, st = ssd_chunk_pallas(x_k, dt_k, dA_k, cs_k, b_k, c_k,
-                                 interpret=not _on_tpu())
+                                 interpret=interpret_mode())
     y = jnp.moveaxis(y.reshape(B, H, nc, Q, P), 1, 3)        # (B,nc,Q,H,P)
     st = st.reshape(B, H, nc, P, N).transpose(0, 2, 1, 3, 4)  # (B,nc,H,P,N)
     return y, st

@@ -24,28 +24,28 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_chunk_kernel(x_ref, dt_ref, dA_ref, dAcs_ref, b_ref, c_ref,
-                      y_ref, st_ref):
-    x = x_ref[0, 0].astype(jnp.float32)        # (Q, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)      # (Q, 1)
-    dA = dA_ref[0, 0].astype(jnp.float32)      # (Q, 1)
-    dAcs = dAcs_ref[0, 0].astype(jnp.float32)  # (Q, 1)
-    B = b_ref[0, 0].astype(jnp.float32)        # (Q, N)
-    C = c_ref[0, 0].astype(jnp.float32)        # (Q, N)
+def _ssd_chunk_kernel(x_ref, dt_ref, dt_row_ref, cs_ref, cs_row_ref, b_ref,
+                      c_ref, y_ref, st_ref):
+    x = x_ref[0, 0].astype(jnp.float32)          # (Q, P)
+    dt = dt_ref[0, 0].astype(jnp.float32)        # (Q, 1)
+    dt_row = dt_row_ref[0, 0].astype(jnp.float32)  # (1, Q)
+    cs = cs_ref[0, 0].astype(jnp.float32)        # (Q, 1) cumsum(dA)
+    cs_row = cs_row_ref[0, 0].astype(jnp.float32)  # (1, Q)
+    B = b_ref[0, 0].astype(jnp.float32)          # (Q, N)
+    C = c_ref[0, 0].astype(jnp.float32)          # (Q, N)
     Q = x.shape[0]
 
     # decay matrix L[q, s] = exp(sum_{s<k<=q} dA[k]) for s<=q, else 0
-    cs = dAcs[:, 0]
-    diff = cs[:, None] - cs[None, :]           # (Q, Q)
+    diff = cs - cs_row                           # (Q, Q)
     tri = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) >= \
         jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
     L = jnp.where(tri, jnp.exp(diff), 0.0)
 
     scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())))  # (Q, Q)
-    scores = scores * L * dt[None, :, 0]
+    scores = scores * L * dt_row
     y_ref[0, 0] = jax.lax.dot(scores, x).astype(y_ref.dtype)      # (Q, P)
 
-    decay = jnp.exp(cs[-1] - cs)[:, None] * dt                    # (Q, 1)
+    decay = jnp.exp(cs[Q - 1:, :] - cs) * dt                      # (Q, 1)
     xw = x * decay                                                # (Q, P)
     st = jax.lax.dot_general(xw, B, (((0,), (0,)), ((), ())))     # (P, N)
     st_ref[0, 0] = st.astype(st_ref.dtype)
@@ -54,18 +54,23 @@ def _ssd_chunk_kernel(x_ref, dt_ref, dA_ref, dAcs_ref, b_ref, c_ref,
 def ssd_chunk_pallas(x, dt, dA, dAcs, B, C, *, interpret: bool = True):
     """All inputs laid out (BH, nc, Q, ...): x (BH,nc,Q,P); dt/dA/dAcs
     (BH,nc,Q,1); B/C (BH,nc,Q,N). Returns (Y_diag (BH,nc,Q,P),
-    states (BH,nc,P,N)) in f32."""
+    states (BH,nc,P,N)) in f32.
+
+    ``dA`` enters only through its cumulative sum ``dAcs``. The kernel takes
+    dt and dAcs both as columns (Q, 1) and as rows (1, Q) — free reshapes
+    here — so it needs no in-kernel transpose."""
+    del dA
     BH, nc, Q, P = x.shape
     N = B.shape[-1]
     grid = (BH, nc)
+    col = pl.BlockSpec((1, 1, Q, 1), lambda i, c: (i, c, 0, 0))
+    row = pl.BlockSpec((1, 1, 1, Q), lambda i, c: (i, c, 0, 0))
     y, st = pl.pallas_call(
         _ssd_chunk_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, Q, P), lambda i, c: (i, c, 0, 0)),
-            pl.BlockSpec((1, 1, Q, 1), lambda i, c: (i, c, 0, 0)),
-            pl.BlockSpec((1, 1, Q, 1), lambda i, c: (i, c, 0, 0)),
-            pl.BlockSpec((1, 1, Q, 1), lambda i, c: (i, c, 0, 0)),
+            col, row, col, row,
             pl.BlockSpec((1, 1, Q, N), lambda i, c: (i, c, 0, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda i, c: (i, c, 0, 0)),
         ],
@@ -78,5 +83,5 @@ def ssd_chunk_pallas(x, dt, dA, dAcs, B, C, *, interpret: bool = True):
             jax.ShapeDtypeStruct((BH, nc, P, N), jnp.float32),
         ],
         interpret=interpret,
-    )(x, dt, dA, dAcs, B, C)
+    )(x, dt, dt.reshape(BH, nc, 1, Q), dAcs, dAcs.reshape(BH, nc, 1, Q), B, C)
     return y, st

@@ -89,11 +89,11 @@ def lower_and_compile(arch: str, shape_name: str, mesh_kind: str = "single",
     if moe_dense:
         cfg = cfg.with_overrides(moe_dense_decode=True)
     from repro.distributed import hints
+    mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     if shard_hints:
-        hints.enable()
+        hints.enable(mesh)
     else:
         hints.disable()
-    mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     n_chips = mesh.devices.size
     policy = ShardingPolicy(mesh, fsdp_enabled=fsdp,
                             tensor_enabled=not no_tp)

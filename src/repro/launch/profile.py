@@ -64,12 +64,16 @@ def _compiled_costs(fn, *args) -> Optional[Dict[str, float]]:
 
 def _records(kernel: str, reps: List[float], flops: float, bytes_moved: float,
              cost_source: str, quant: str = "fp32") -> List[dict]:
+    # roofline_us is modelled from the TPU_V5E table whatever ran the reps;
+    # device/platform say what actually ran them
     roofline = tpu_roofline_us(flops, bytes_moved)
+    dev = jax.devices()[0]
     backend = jax.default_backend()
     return [{"kind": "kernel", "kernel": kernel, "rep": i,
              "flops": flops, "bytes": bytes_moved,
              "measured_us": us, "roofline_us": roofline,
-             "device": TPU_V5E.name,
+             "roofline_source": f"modelled:{TPU_V5E.name}",
+             "device": dev.device_kind, "platform": dev.platform,
              "backend": backend if backend == "tpu" else f"{backend}-interpret",
              "cost_source": cost_source, "quant": quant}
             for i, us in enumerate(reps)]

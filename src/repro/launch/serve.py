@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -24,11 +25,15 @@ import numpy as np
 from repro.configs import ASSIGNED_ARCHS, get_config
 from repro.core import (Constraints, GreedyOrchestrator, SafetyMonitor,
                         Workload, EDGE_PLATFORM)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.serving import ServingEngine
+from repro.serving.backend import GenerationResult
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> List[GenerationResult]:
+    """Serve ``--requests`` seeded random prompts; return the results of
+    the requests that completed (a rejected or cancelled one is absent)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ASSIGNED_ARCHS)
     ap.add_argument("--smoke", action="store_true")
@@ -109,7 +114,8 @@ def main() -> None:
                     help="simulated seconds between periodic metrics writes")
     ap.add_argument("--spans-out", default=None,
                     help="write request lifecycle spans (JSONL) here")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro.obs import NULL_OBS, PeriodicReporter, make_observability
     obs = (make_observability() if args.metrics_out or args.spans_out
@@ -119,11 +125,16 @@ def main() -> None:
     if args.smoke:
         cfg = cfg.reduced()
     model = Model(cfg, dtype=jnp.float32 if args.smoke else jnp.bfloat16)
-    params = model.init(jax.random.key(0))
-    if args.quant != "bf16":
+    key = jax.random.key(0)
+    if args.quant == "bf16":
+        params = jax.jit(model.init)(key)
+    else:
+        # init and quantize in one program: only the quantized tree is an
+        # output, so the full-precision weights never sit beside it
         from repro.quant import param_bytes, quantize_model
-        before = param_bytes(params)
-        params = quantize_model(params, args.quant, args.group_size)
+        before = param_bytes(jax.eval_shape(model.init, key))
+        params = jax.jit(lambda k: quantize_model(
+            model.init(k), args.quant, args.group_size))(key)
         print(f"[quant] weights {args.quant}: {before / 1e6:.1f} MB -> "
               f"{param_bytes(params) / 1e6:.1f} MB")
 
@@ -363,6 +374,7 @@ def main() -> None:
     if args.spans_out and obs.tracer.enabled:
         obs.tracer.save(args.spans_out)
         print(f"[obs] {len(obs.tracer)} spans -> {args.spans_out}")
+    return results
 
 
 if __name__ == "__main__":

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -20,13 +21,14 @@ import numpy as np
 from repro.configs import ASSIGNED_ARCHS, get_config
 from repro.data import DataConfig, data_iterator
 from repro.distributed import ShardingPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.models import Model
 from repro.training import (AdamWConfig, init_opt_state, make_train_step,
                             save_checkpoint)
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ASSIGNED_ARCHS)
     ap.add_argument("--smoke", action="store_true",
@@ -38,7 +40,8 @@ def main() -> None:
     ap.add_argument("--mesh", default="none", choices=["none", "debug",
                                                        "single", "multi"])
     ap.add_argument("--checkpoint-dir", default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

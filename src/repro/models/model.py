@@ -62,14 +62,16 @@ class Model:
         return spec
 
     def init(self, rng) -> Dict:
+        """Random parameters from ``rng``. The super-blocks are built stacked
+        (vmapped over their keys), never as a list and then a copy, so under
+        ``jax.jit(model.init)`` the peak is about the size of the output."""
         cfg, dt = self.cfg, self.dtype
         n_prefix = cache_mod.n_prefix_layers(cfg)
         n_super = cache_mod.n_scanned_super_blocks(cfg)
         k_embed, k_blocks, k_head, k_prefix = jax.random.split(rng, 4)
         block_keys = jax.random.split(k_blocks, n_super)
-        stacked = jax.tree.map(
-            lambda *xs: jnp.stack(xs),
-            *[blk.super_block_init(k, cfg, n_prefix, dt) for k in block_keys])
+        stacked = jax.vmap(
+            lambda k: blk.super_block_init(k, cfg, n_prefix, dt))(block_keys)
         prefix_keys = jax.random.split(k_prefix, max(n_prefix, 1))
         params = {
             "embed": embed_init(k_embed, cfg.padded_vocab, cfg.d_model, dt,

@@ -8,8 +8,8 @@ attached:
   ``repro.kernels/<name>`` metadata. This works *inside* jit (it annotates
   at trace time) and is how XLA profiles / ``jax.profiler`` traces
   attribute device time back to the kernel that produced it. A
-  ``jax.profiler.TraceAnnotation`` is layered on when available: under jit
-  it only brackets trace time, but the same ops wrappers are also called
+  ``jax.profiler.TraceAnnotation`` is layered on: under jit it only
+  brackets trace time, but the same ops wrappers are also called
   eagerly (interpret-mode tests, `launch/profile`), where it emits real
   host TraceMe events.
 * `annotate(name)` — host-level ``TraceAnnotation`` alone, for timing loops
@@ -32,28 +32,21 @@ from typing import Iterator
 SCOPE_PREFIX = "repro.kernels"
 
 
-def _trace_annotation(label: str):
-    """Host-level TraceMe context when the jax build has one, else a
-    null context (older jax: TraceAnnotation lived elsewhere/not at all)."""
-    import jax
-    ta = getattr(jax.profiler, "TraceAnnotation", None)
-    return ta(label) if ta is not None else contextlib.nullcontext()
-
-
 @contextlib.contextmanager
 def kernel_scope(name: str) -> Iterator[None]:
     """Annotate one kernel call site: HLO metadata (named_scope) + host
     TraceMe. Wraps the ops-layer body, inside or outside jit."""
     import jax
     label = f"{SCOPE_PREFIX}/{name}"
-    with jax.named_scope(label), _trace_annotation(label):
+    with jax.named_scope(label), jax.profiler.TraceAnnotation(label):
         yield
 
 
 @contextlib.contextmanager
 def annotate(name: str) -> Iterator[None]:
     """Host-level profiler annotation only (timing loops outside jit)."""
-    with _trace_annotation(f"{SCOPE_PREFIX}/{name}"):
+    import jax
+    with jax.profiler.TraceAnnotation(f"{SCOPE_PREFIX}/{name}"):
         yield
 
 

@@ -111,10 +111,11 @@ SMALL_MESH_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, json
     from repro.configs import get_config
     from repro.distributed import ShardingPolicy
+    from repro.launch.mesh import make_debug_mesh
     from repro.models import Model
     from repro.training import AdamWConfig, init_opt_state, make_train_step
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_debug_mesh(4, 2)
     cfg = get_config("granite-moe-3b-a800m").reduced()
     model = Model(cfg, dtype=jnp.float32)
     policy = ShardingPolicy(mesh)
@@ -150,6 +151,33 @@ def test_real_spmd_execution_small_mesh():
     result = json.loads(out.stdout.strip().splitlines()[-1])
     assert result["n_devices"] == 8
     assert all(l > 0 and l == l for l in result["losses"])
+
+
+DEBUG_MESH_TRAIN_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+    import jax, json
+    from repro.launch import train
+    train.main(["--arch", "chatglm3-6b", "--smoke", "--steps", "2",
+                "--batch", "4", "--seq", "16", "--mesh", "debug"])
+    print(json.dumps({"n_devices": jax.device_count()}))
+""")
+
+
+def test_train_launcher_debug_mesh_four_devices():
+    """`repro.launch.train --mesh debug` runs sharded steps on the 2x2
+    `make_debug_mesh()` over 4 virtual devices (subprocess)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run([sys.executable, "-c", DEBUG_MESH_TRAIN_SCRIPT],
+                         capture_output=True, text=True, env=env,
+                         timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    lines = out.stdout.strip().splitlines()
+    assert json.loads(lines[-1])["n_devices"] == 4
+    losses = [float(l.split()[3]) for l in lines if l.startswith("step")]
+    assert len(losses) == 2 and all(0 < l < 1e3 for l in losses), lines
 
 
 def test_dryrun_artifacts_all_pass():

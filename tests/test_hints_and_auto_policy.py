@@ -32,6 +32,23 @@ def test_hints_enabled_outside_mesh_graceful():
         hints.disable()
 
 
+def test_hints_on_a_mesh_apply_or_raise():
+    """On a mesh the hint is applied, and a constraint that cannot be
+    applied raises instead of silently returning its input."""
+    import pytest
+    from repro.launch.mesh import make_debug_mesh
+    hints.enable(make_debug_mesh(1, 1))
+    try:
+        x = jnp.arange(32.0).reshape(4, 8)
+        y = jax.jit(lambda a: hints.constrain(a, (None, "tensor")))(x)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
+        with pytest.raises(ValueError):
+            # a 2-entry spec for a rank-1 array is no valid sharding
+            hints.constrain(jnp.ones((4,)), ("tensor", None))
+    finally:
+        hints.disable()
+
+
 def test_auto_policy_small_model_goes_dp_only():
     mesh = FakeMesh((16, 16), ("data", "model"))
     small = get_config("mamba2-370m")
