@@ -15,8 +15,8 @@ stack on.
     obs.metrics.write("metrics.json")          # + metrics.prom sibling
     obs.tracer.save("spans.jsonl")             # TraceStore-compatible
 
-This package is dependency-light by design: metrics and tracing are pure
-python/stdlib; `profiling` imports jax lazily.
+This package is dependency-light by design: metrics are pure
+python/stdlib; a live `Tracer` and `profiling` import jax when used.
 """
 from repro.obs.metrics import (Counter, DEFAULT_BUCKETS, Gauge, Histogram,
                                MetricsRegistry, NullRegistry,

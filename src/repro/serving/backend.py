@@ -78,7 +78,6 @@ token slots per sequence (`request_blocks` prices it into admission).
 """
 from __future__ import annotations
 
-import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import (Any, Deque, Dict, List, Optional, Sequence, Set, Tuple,
@@ -524,6 +523,7 @@ class ExecutionBackend:
         self.last_placement = None
         self.placements: Deque = deque(maxlen=256)
         self.set_obs(obs)
+        self._prefills = 0             # start_batch calls (profiler steps)
         self._prefill_jit = jax.jit(self._prefill)
         self._decode_jit = jax.jit(self._decode_step,
                                    static_argnames=("kv_len", "greedy"))
@@ -903,65 +903,98 @@ class ExecutionBackend:
         B = int(sum(repeats))
 
         tracer = self.obs.tracer
-        t0 = time.perf_counter() if tracer.enabled else 0.0
         n_spec = self._consume_spec_n()
-        if self.prefix_pool is not None:
-            h = self._start_batch_pooled(prompts, repeats, rep, base, B,
-                                         plen, max_new, temperature, rng,
-                                         extras, mc)
-            # only the post-dedup tails were prefilled
-            prefilled = (len(prompts) * plen
-                         - h.pool_hit_blocks * self.allocator.block_size)
-        elif self.allocator is not None:
-            h = self._start_batch_paged(prompts, repeats, rep, base, B, plen,
-                                        max_new, temperature, rng, extras, mc)
-            prefilled = len(prompts) * plen     # one row per unique prompt
-        else:
-            h = self._start_batch_dense(prompts, repeats, rep, base, B, plen,
-                                        max_new, temperature, rng, extras, mc)
-            prefilled = B * plen
-        if h.pending_prefill is not None:
-            # chunked: nothing forwarded yet — each chunk step meters its
-            # own tokens and spans; the draft depth arms at finish
-            h.pending_prefill.n_spec = n_spec
-            n_spec = 0
-            prefilled = 0
-        if n_spec > 0:
-            first = np.asarray(h.tok).ravel()
-            lp0 = np.asarray(h.out_lps[0]).ravel()
-            hists: List[np.ndarray] = []
-            for prompt, k in zip(prompts, repeats):
-                p = np.asarray(prompt, np.int64).ravel()
-                for _ in range(k):
-                    i = len(hists)
-                    hists.append(np.concatenate([p, first[i:i + 1]]))
-            h.spec = SpecState(
-                policy=self.spec_policy, n=n_spec,
-                committed=np.ones(B, np.int64), histories=hists,
-                toks=[[int(t)] for t in first],
-                lps=[[float(x)] for x in lp0])
-        self._live[id(h)] = h
-        if tracer.enabled:
-            # wall clock: real dispatch time of prefill + first sample,
-            # batch id supplied by the scheduler via tracer.batch_context
-            tracer.emit("prefill", t0, time.perf_counter(), clock="wall",
-                        prefill_tokens=prefilled, n_sequences=B, plen=plen)
-        if self._m is not None:
-            self._m["tokens_in"].inc(prefilled)
-            if h.pending_prefill is None:       # else metered at finish
-                self._m["tokens_out"].inc(B)    # first token per sequence
-            if self.prefix_pool is not None and h.paged is not None:
-                lookupable = len(prompts) * (plen // self.allocator.block_size)
-                misses = lookupable - h.pool_hit_blocks
-                self._m["pool_hits"].inc(h.pool_hit_blocks)
-                self._m["pool_misses"].inc(misses)
-                if h.pool_evictions:
-                    self._m["pool_evictions"].inc(h.pool_evictions)
-                if lookupable:
-                    self._m["pool_ratio"].observe(
-                        h.pool_hit_blocks / lookupable)
-            self._note_occupancy()
+        self._prefills += 1
+        # the batch id comes from the scheduler via tracer.batch_context
+        with tracer.span("prefill", step_num=self._prefills, plen=plen,
+                         n_sequences=B) as span:
+            if self.prefix_pool is not None:
+                h = self._start_batch_pooled(prompts, repeats, rep, base, B,
+                                             plen, max_new, temperature, rng,
+                                             extras, mc)
+                # only the post-dedup tails were prefilled
+                prefilled = (len(prompts) * plen
+                             - h.pool_hit_blocks * self.allocator.block_size)
+            elif self.allocator is not None:
+                h = self._start_batch_paged(prompts, repeats, rep, base, B,
+                                            plen, max_new, temperature, rng,
+                                            extras, mc)
+                prefilled = len(prompts) * plen  # one row per unique prompt
+            else:
+                h = self._start_batch_dense(prompts, repeats, rep, base, B,
+                                            plen, max_new, temperature, rng,
+                                            extras, mc)
+                prefilled = B * plen
+            if h.pending_prefill is not None:
+                # chunked: nothing forwarded yet — each chunk step meters its
+                # own tokens and spans; the draft depth arms at finish
+                h.pending_prefill.n_spec = n_spec
+                n_spec = 0
+                prefilled = 0
+            else:
+                self._fetch_first(h)
+            if n_spec > 0:
+                self._arm_spec(h, n_spec)
+            self._live[id(h)] = h
+            span.set(prefill_tokens=prefilled)
+            if self._m is not None:
+                self._m["tokens_in"].inc(prefilled)
+                if h.pending_prefill is None:       # else metered at finish
+                    self._m["tokens_out"].inc(B)    # first token per seq
+                if self.prefix_pool is not None and h.paged is not None:
+                    lookupable = len(prompts) * (plen
+                                                 // self.allocator.block_size)
+                    misses = lookupable - h.pool_hit_blocks
+                    self._m["pool_hits"].inc(h.pool_hit_blocks)
+                    self._m["pool_misses"].inc(misses)
+                    if h.pool_evictions:
+                        self._m["pool_evictions"].inc(h.pool_evictions)
+                    if lookupable:
+                        self._m["pool_ratio"].observe(
+                            h.pool_hit_blocks / lookupable)
+                self._note_occupancy()
         return h
+
+    def _sample_first(self, last_logits, rng, temperature: float, rep, mc):
+        """First token of every sequence from the prefill logits (fanned
+        out ``rep`` ways unless None): ``(rng, tok, lp)``, on the device.
+        One sampler for every start path keeps their first tokens
+        bit-identical."""
+        with self.obs.tracer.span("prefill.dispatch"):
+            rng, sub = jax.random.split(rng)
+            lf = last_logits.astype(jnp.float32)
+            if rep is not None:
+                lf = jnp.repeat(lf, rep, axis=0)
+            logp0 = jax.nn.log_softmax(lf, axis=-1)
+            if temperature > 0:
+                tok = jax.random.categorical(sub, lf / temperature, axis=-1)
+            else:
+                tok = jnp.argmax(lf, axis=-1)
+            lp = jnp.take_along_axis(logp0, tok[..., None], axis=-1)[..., 0]
+            return rng, tok, (lp if not mc else lp.mean(-1))
+
+    def _fetch_first(self, h: InFlightBatch) -> None:
+        """Bring the first sampled token and log-prob to the host."""
+        with self.obs.tracer.span("prefill.fetch"):
+            h.out_toks = [np.asarray(h.tok)]
+            h.out_lps = [np.asarray(h.out_lps[0])]
+
+    def _arm_spec(self, h: InFlightBatch, n_spec: int) -> None:
+        """Draft state of a speculative batch, seeded with its first
+        token."""
+        first = h.out_toks[0].ravel()
+        lp0 = h.out_lps[0].ravel()
+        hists: List[np.ndarray] = []
+        for prompt, k in zip(h.prompts, h.repeats):
+            p = np.asarray(prompt, np.int64).ravel()
+            for _ in range(k):
+                i = len(hists)
+                hists.append(np.concatenate([p, first[i:i + 1]]))
+        h.spec = SpecState(
+            policy=self.spec_policy, n=n_spec,
+            committed=np.ones(h.n_sequences, np.int64), histories=hists,
+            toks=[[int(t)] for t in first],
+            lps=[[float(x)] for x in lp0])
 
     def _start_batch_dense(self, prompts, repeats, rep, base, B, plen,
                            max_new, temperature, rng, extras,
@@ -975,27 +1008,20 @@ class ExecutionBackend:
         tiled_extras = {k: jnp.repeat(jnp.asarray(v), rep, axis=0)
                         for k, v in extras.items()}
 
-        cache = self.model.init_cache(B, plen + max_new + self._spec_slack())
-        last_logits, cache = self._prefill_jit(
-            self.params, jnp.asarray(tokens), cache, tiled_extras)
-
+        with self.obs.tracer.span("prefill.dispatch"):
+            cache = self.model.init_cache(B,
+                                          plen + max_new + self._spec_slack())
+            last_logits, cache = self._prefill_jit(
+                self.params, jnp.asarray(tokens), cache, tiled_extras)
         # first sampled token comes from the prefill logits
-        rng, sub = jax.random.split(rng)
-        lf = last_logits.astype(jnp.float32)
-        logp0 = jax.nn.log_softmax(lf, axis=-1)
-        if temperature > 0:
-            tok = jax.random.categorical(sub, lf / temperature, axis=-1)
-        else:
-            tok = jnp.argmax(lf, axis=-1)
-        lp = jnp.take_along_axis(logp0, tok[..., None], axis=-1)[..., 0]
-
+        rng, tok, lp = self._sample_first(last_logits, rng, temperature,
+                                          None, mc)
         self.slots_in_use += B
         return InFlightBatch(
             prompts=list(prompts), repeats=repeats, plen=plen,
             max_new=max_new, temperature=temperature, rng=rng,
             extras=tiled_extras, cache=cache, tok=tok, step=1,
-            out_toks=[np.asarray(tok)],
-            out_lps=[np.asarray(lp if not mc else lp.mean(-1))])
+            out_toks=[tok], out_lps=[lp])
 
     def _start_batch_paged(self, prompts, repeats, rep, base, B, plen,
                            max_new, temperature, rng, extras,
@@ -1010,9 +1036,10 @@ class ExecutionBackend:
         layout = build_paged_layout(self.allocator, plen,
                                     max_new + self._spec_slack(), repeats)
         try:
-            cache = self.model.init_paged_cache(
-                layout.n_pool_blocks, layout.block_size,
-                kv_dtype=jnp.int8 if self.kv_format == "int8" else None)
+            with self.obs.tracer.span("prefill.dispatch"):
+                cache = self.model.init_paged_cache(
+                    layout.n_pool_blocks, layout.block_size,
+                    kv_dtype=jnp.int8 if self.kv_format == "int8" else None)
             # prefill rows are the unique prompts (extras per-prompt as-is);
             # decode rows are the tiled sequences — both tiled exactly once
             prefill_extras = {k: jnp.asarray(v) for k, v in extras.items()}
@@ -1041,11 +1068,12 @@ class ExecutionBackend:
                         last_rows=[None] * R, rep=rep, mc=mc,
                         full_prefix=0))
             has_cow = layout.copy_src.size > 0
-            last_logits, cache = self._prefill_jit(
-                self.params, jnp.asarray(base), cache, prefill_extras,
-                jnp.asarray(layout.prefill_table),
-                jnp.asarray(layout.copy_src) if has_cow else None,
-                jnp.asarray(layout.copy_dst) if has_cow else None)
+            with self.obs.tracer.span("prefill.dispatch"):
+                last_logits, cache = self._prefill_jit(
+                    self.params, jnp.asarray(base), cache, prefill_extras,
+                    jnp.asarray(layout.prefill_table),
+                    jnp.asarray(layout.copy_src) if has_cow else None,
+                    jnp.asarray(layout.copy_dst) if has_cow else None)
         except BaseException:
             # no handle exists yet to release() — return every reference the
             # layout took, or a failed prefill permanently shrinks the budget
@@ -1056,21 +1084,13 @@ class ExecutionBackend:
 
         # fan the unique-prompt logits out to the repeats, then sample with
         # the same key/shape as the dense path — bit-identical first token
-        rng, sub = jax.random.split(rng)
-        lf = jnp.repeat(last_logits.astype(jnp.float32), rep, axis=0)
-        logp0 = jax.nn.log_softmax(lf, axis=-1)
-        if temperature > 0:
-            tok = jax.random.categorical(sub, lf / temperature, axis=-1)
-        else:
-            tok = jnp.argmax(lf, axis=-1)
-        lp = jnp.take_along_axis(logp0, tok[..., None], axis=-1)[..., 0]
-
+        rng, tok, lp = self._sample_first(last_logits, rng, temperature, rep,
+                                          mc)
         return InFlightBatch(
             prompts=list(prompts), repeats=repeats, plen=plen,
             max_new=max_new, temperature=temperature, rng=rng,
             extras=decode_extras, cache=cache, tok=tok, step=1,
-            out_toks=[np.asarray(tok)],
-            out_lps=[np.asarray(lp if not mc else lp.mean(-1))],
+            out_toks=[tok], out_lps=[lp],
             paged=layout, block_table=jnp.asarray(layout.decode_table),
             prefill_bytes_saved=float((B - R) * plen * self.kv_token_bytes))
 
@@ -1099,31 +1119,31 @@ class ExecutionBackend:
         bs = alloc.block_size
         R = len(prompts)
         full_prefix = plen // bs
-        # 1. acquire hit chains first: refs pin them, so the eviction pass
-        #    below can never reclaim a block this batch just resolved
-        hit_chains = [pool.acquire(p, self._max_hit(plen), holders=k)
-                      for p, k in zip(prompts, repeats)]
-        horizon = max_new + self._spec_slack()
-        n_logical = max(-(-(plen + horizon - 1) // bs), 1)
-        need = sum(full_prefix - len(ch) + k * (n_logical - full_prefix)
-                   for ch, k in zip(hit_chains, repeats))
-        # 2. make room for the tails (LRU over idle trie leaves only)
-        evicted = pool.ensure_free(need)
-        if need > alloc.blocks_free:
-            for ch, k in zip(hit_chains, repeats):
-                for g in ch:
-                    for _ in range(k):
-                        alloc.free(g)
-            raise RuntimeError(
-                f"KV block budget exceeded: need {need} tail blocks > "
-                f"{alloc.blocks_free} free after {evicted} eviction(s) "
-                "(scheduler must check capacity_free)")
-        # 3. static block schedule over GLOBAL ids (allocation cannot fail
-        #    past the check above)
-        layout = build_paged_layout(alloc, plen, horizon, repeats,
-                                    hit_chains=hit_chains)
-        cache = self._ensure_pool_cache()
-        try:
+        tracer = self.obs.tracer
+        with tracer.span("pool.match"):
+            # 1. acquire hit chains first: refs pin them, so the eviction
+            #    pass below can never reclaim a block this batch just resolved
+            hit_chains = [pool.acquire(p, self._max_hit(plen), holders=k)
+                          for p, k in zip(prompts, repeats)]
+            horizon = max_new + self._spec_slack()
+            n_logical = max(-(-(plen + horizon - 1) // bs), 1)
+            need = sum(full_prefix - len(ch) + k * (n_logical - full_prefix)
+                       for ch, k in zip(hit_chains, repeats))
+            # 2. make room for the tails (LRU over idle trie leaves only)
+            evicted = pool.ensure_free(need)
+            if need > alloc.blocks_free:
+                for ch, k in zip(hit_chains, repeats):
+                    for g in ch:
+                        for _ in range(k):
+                            alloc.free(g)
+                raise RuntimeError(
+                    f"KV block budget exceeded: need {need} tail blocks > "
+                    f"{alloc.blocks_free} free after {evicted} eviction(s) "
+                    "(scheduler must check capacity_free)")
+            # 3. static block schedule over GLOBAL ids (allocation cannot
+            #    fail past the check above)
+            layout = build_paged_layout(alloc, plen, horizon, repeats,
+                                        hit_chains=hit_chains)
             # invalidate the pos slots of every block allocated this batch:
             # the resident cache outlives batches, and a block back from the
             # free list still carries its previous occupant's positions — a
@@ -1132,61 +1152,68 @@ class ExecutionBackend:
             hit_gids = {int(g) for ch in hit_chains for g in ch}
             fresh = sorted({int(g) for gids in layout.seq_gids
                             for g in gids} - hit_gids)
-            if fresh:
-                cache = self._reset_blocks_jit(
-                    cache, jnp.asarray(fresh, jnp.int32))
-            prefill_extras = {k: jnp.asarray(v) for k, v in extras.items()}
-            decode_extras = {k: jnp.repeat(jnp.asarray(v), rep, axis=0)
-                             for k, v in extras.items()}
-            # 4. tail-only prefill, one static-shape forward per hit depth
             groups: Dict[int, List[int]] = {}
             for i, c in enumerate(layout.hit_counts):
                 groups.setdefault(c, []).append(i)
-            if self.prefill_chunk is not None:
-                # chunked: enqueue the tail slices instead of forwarding —
-                # decode_step drains one per call; CoW fan-out, trie
-                # indexing and first-token sampling run at finish
-                jobs: Deque[Tuple[List[int], int, int]] = deque()
+        try:
+            with tracer.span("prefill.dispatch"):
+                cache = self._ensure_pool_cache()
+                if fresh:
+                    cache = self._reset_blocks_jit(
+                        cache, jnp.asarray(fresh, jnp.int32))
+                prefill_extras = {k: jnp.asarray(v)
+                                  for k, v in extras.items()}
+                decode_extras = {k: jnp.repeat(jnp.asarray(v), rep, axis=0)
+                                 for k, v in extras.items()}
+                block_table = jnp.asarray(layout.decode_table)
+                if self.prefill_chunk is not None:
+                    # chunked: enqueue the tail slices instead of forwarding
+                    # — decode_step drains one per call; CoW fan-out, trie
+                    # indexing and first-token sampling run at finish
+                    jobs: Deque[Tuple[List[int], int, int]] = deque()
+                    for c, idxs in sorted(groups.items()):
+                        s = c * bs
+                        while s < plen:
+                            e = min(s + self.prefill_chunk, plen)
+                            jobs.append((idxs, s, e))
+                            s = e
+                    self._pool_cache = cache
+                    tail_tokens = sum(plen - c * bs
+                                      for c in layout.hit_counts)
+                    return InFlightBatch(
+                        prompts=list(prompts), repeats=repeats, plen=plen,
+                        max_new=max_new, temperature=temperature, rng=rng,
+                        extras=decode_extras, cache=None, tok=None, step=0,
+                        paged=layout, block_table=block_table,
+                        prefill_bytes_saved=float((B * plen - tail_tokens)
+                                                  * self.kv_token_bytes),
+                        pool_hit_blocks=sum(layout.hit_counts),
+                        pool_evictions=evicted,
+                        pending_prefill=PendingPrefill(
+                            jobs=jobs, base=base, extras=prefill_extras,
+                            last_rows=[None] * R, rep=rep, mc=mc,
+                            full_prefix=full_prefix))
+                # 4. tail-only prefill, one static-shape forward per hit
+                #    depth
+                last_rows: List[Any] = [None] * R
                 for c, idxs in sorted(groups.items()):
-                    s = c * bs
-                    while s < plen:
-                        e = min(s + self.prefill_chunk, plen)
-                        jobs.append((idxs, s, e))
-                        s = e
-                self._pool_cache = cache
-                tail_tokens = sum(plen - c * bs
-                                  for c in layout.hit_counts)
-                return InFlightBatch(
-                    prompts=list(prompts), repeats=repeats, plen=plen,
-                    max_new=max_new, temperature=temperature, rng=rng,
-                    extras=decode_extras, cache=None, tok=None, step=0,
-                    paged=layout,
-                    block_table=jnp.asarray(layout.decode_table),
-                    prefill_bytes_saved=float((B * plen - tail_tokens)
-                                              * self.kv_token_bytes),
-                    pool_hit_blocks=sum(layout.hit_counts),
-                    pool_evictions=evicted,
-                    pending_prefill=PendingPrefill(
-                        jobs=jobs, base=base, extras=prefill_extras,
-                        last_rows=[None] * R, rep=rep, mc=mc,
-                        full_prefix=full_prefix))
-            last_rows: List[Any] = [None] * R
-            for c, idxs in sorted(groups.items()):
-                gl, cache = self._tail_prefill_jit(
-                    self.params, jnp.asarray(base[idxs][:, c * bs:]),
-                    jnp.asarray(c * bs, jnp.int32), cache,
-                    {k: v[jnp.asarray(idxs)]
-                     for k, v in prefill_extras.items()},
-                    jnp.asarray(layout.prefill_table[idxs]), kv_len=plen)
-                for j, i in enumerate(idxs):
-                    last_rows[i] = gl[j]
-            last_logits = jnp.stack(last_rows, axis=0)
-            if layout.copy_src.size > 0:
-                # CoW fan-out of the shared partial prefix block (partials
-                # are never pool-shared — same schedule as per-batch paging)
-                cache = self._copy_blocks_jit(cache,
-                                              jnp.asarray(layout.copy_src),
-                                              jnp.asarray(layout.copy_dst))
+                    gl, cache = self._tail_prefill_jit(
+                        self.params, jnp.asarray(base[idxs][:, c * bs:]),
+                        jnp.asarray(c * bs, jnp.int32), cache,
+                        {k: v[jnp.asarray(idxs)]
+                         for k, v in prefill_extras.items()},
+                        jnp.asarray(layout.prefill_table[idxs]),
+                        kv_len=plen)
+                    for j, i in enumerate(idxs):
+                        last_rows[i] = gl[j]
+                last_logits = jnp.stack(last_rows, axis=0)
+                if layout.copy_src.size > 0:
+                    # CoW fan-out of the shared partial prefix block
+                    # (partials are never pool-shared — same schedule as
+                    # per-batch paging)
+                    cache = self._copy_blocks_jit(
+                        cache, jnp.asarray(layout.copy_src),
+                        jnp.asarray(layout.copy_dst))
         except BaseException:
             # every reference the batch took (hits included — their holder
             # refs unwind to the trie ref) must return, or a failed prefill
@@ -1206,23 +1233,15 @@ class ExecutionBackend:
 
         # fan the unique-prompt logits out to the repeats, then sample with
         # the same key/shape as the dense path — bit-identical first token
-        rng, sub = jax.random.split(rng)
-        lf = jnp.repeat(last_logits.astype(jnp.float32), rep, axis=0)
-        logp0 = jax.nn.log_softmax(lf, axis=-1)
-        if temperature > 0:
-            tok = jax.random.categorical(sub, lf / temperature, axis=-1)
-        else:
-            tok = jnp.argmax(lf, axis=-1)
-        lp = jnp.take_along_axis(logp0, tok[..., None], axis=-1)[..., 0]
-
+        rng, tok, lp = self._sample_first(last_logits, rng, temperature, rep,
+                                          mc)
         tail_tokens = sum(plen - c * bs for c in layout.hit_counts)
         return InFlightBatch(
             prompts=list(prompts), repeats=repeats, plen=plen,
             max_new=max_new, temperature=temperature, rng=rng,
             extras=decode_extras, cache=None, tok=tok, step=1,
-            out_toks=[np.asarray(tok)],
-            out_lps=[np.asarray(lp if not mc else lp.mean(-1))],
-            paged=layout, block_table=jnp.asarray(layout.decode_table),
+            out_toks=[tok], out_lps=[lp],
+            paged=layout, block_table=block_table,
             prefill_bytes_saved=float((B * plen - tail_tokens)
                                       * self.kv_token_bytes),
             pool_hit_blocks=hit_blocks, pool_evictions=evicted)
@@ -1233,30 +1252,30 @@ class ExecutionBackend:
         pp = h.pending_prefill
         idxs, s, e = pp.jobs.popleft()
         tracer = self.obs.tracer
-        t0 = time.perf_counter() if tracer.enabled else 0.0
-        pooled = h.cache is None
-        cache = self._pool_cache if pooled else h.cache
-        gl, cache = self._tail_prefill_jit(
-            self.params, jnp.asarray(pp.base[idxs][:, s:e]),
-            jnp.asarray(s, jnp.int32), cache,
-            {k: v[jnp.asarray(idxs)] for k, v in pp.extras.items()},
-            jnp.asarray(h.paged.prefill_table[idxs]), kv_len=h.plen)
-        if pooled:
-            self._pool_cache = cache
-        else:
-            h.cache = cache
-        if e == h.plen:
-            for j, i in enumerate(idxs):
-                pp.last_rows[i] = gl[j]
-        if tracer.enabled:
-            tracer.emit("prefill", t0, time.perf_counter(), clock="wall",
-                        prefill_tokens=len(idxs) * (e - s),
-                        n_sequences=h.n_sequences, plen=h.plen,
-                        chunk=[s, e])
-        if self._m is not None:
-            self._m["tokens_in"].inc(len(idxs) * (e - s))
-        if not pp.jobs:
-            self._finish_chunked_prefill(h)
+        self._prefills += 1
+        with tracer.span("prefill", step_num=self._prefills,
+                         prefill_tokens=len(idxs) * (e - s),
+                         n_sequences=h.n_sequences, plen=h.plen,
+                         chunk=[s, e]):
+            pooled = h.cache is None
+            cache = self._pool_cache if pooled else h.cache
+            with tracer.span("prefill.dispatch"):
+                gl, cache = self._tail_prefill_jit(
+                    self.params, jnp.asarray(pp.base[idxs][:, s:e]),
+                    jnp.asarray(s, jnp.int32), cache,
+                    {k: v[jnp.asarray(idxs)] for k, v in pp.extras.items()},
+                    jnp.asarray(h.paged.prefill_table[idxs]), kv_len=h.plen)
+                if pooled:
+                    self._pool_cache = cache
+                else:
+                    h.cache = cache
+                if e == h.plen:
+                    for j, i in enumerate(idxs):
+                        pp.last_rows[i] = gl[j]
+            if self._m is not None:
+                self._m["tokens_in"].inc(len(idxs) * (e - s))
+            if not pp.jobs:
+                self._finish_chunked_prefill(h)
 
     def _finish_chunked_prefill(self, h: InFlightBatch) -> None:
         """Last chunk landed: CoW-fan-out the shared partial block, index
@@ -1268,9 +1287,10 @@ class ExecutionBackend:
         pooled = h.cache is None
         cache = self._pool_cache if pooled else h.cache
         if layout.copy_src.size > 0:
-            cache = self._copy_blocks_jit(cache,
-                                          jnp.asarray(layout.copy_src),
-                                          jnp.asarray(layout.copy_dst))
+            with self.obs.tracer.span("prefill.dispatch"):
+                cache = self._copy_blocks_jit(cache,
+                                              jnp.asarray(layout.copy_src),
+                                              jnp.asarray(layout.copy_dst))
         if pooled:
             self._pool_cache = cache
             for i, p in enumerate(h.prompts):
@@ -1279,34 +1299,15 @@ class ExecutionBackend:
                         layout.prefill_table[i][:pp.full_prefix]])
         else:
             h.cache = cache
-        last_logits = jnp.stack(pp.last_rows, axis=0)
-        h.rng, sub = jax.random.split(h.rng)
-        lf = jnp.repeat(last_logits.astype(jnp.float32), pp.rep, axis=0)
-        logp0 = jax.nn.log_softmax(lf, axis=-1)
-        if h.temperature > 0:
-            tok = jax.random.categorical(sub, lf / h.temperature, axis=-1)
-        else:
-            tok = jnp.argmax(lf, axis=-1)
-        lp = jnp.take_along_axis(logp0, tok[..., None], axis=-1)[..., 0]
-        h.tok = tok
+        with self.obs.tracer.span("prefill.dispatch"):
+            last_logits = jnp.stack(pp.last_rows, axis=0)
+        h.rng, h.tok, lp = self._sample_first(last_logits, h.rng,
+                                              h.temperature, pp.rep, pp.mc)
         h.step = 1
-        h.out_toks = [np.asarray(tok)]
-        h.out_lps = [np.asarray(lp if not pp.mc else lp.mean(-1))]
+        h.out_lps = [lp]
+        self._fetch_first(h)
         if pp.n_spec > 0:
-            first = np.asarray(h.tok).ravel()
-            lp0 = np.asarray(h.out_lps[0]).ravel()
-            hists: List[np.ndarray] = []
-            for prompt, k in zip(h.prompts, h.repeats):
-                p = np.asarray(prompt, np.int64).ravel()
-                for _ in range(k):
-                    i = len(hists)
-                    hists.append(np.concatenate([p, first[i:i + 1]]))
-            h.spec = SpecState(
-                policy=self.spec_policy, n=pp.n_spec,
-                committed=np.ones(h.n_sequences, np.int64),
-                histories=hists,
-                toks=[[int(t)] for t in first],
-                lps=[[float(x)] for x in lp0])
+            self._arm_spec(h, pp.n_spec)
         if self._m is not None:
             self._m["tokens_out"].inc(h.n_sequences)
         h.pending_prefill = None
@@ -1362,30 +1363,32 @@ class ExecutionBackend:
         if h.done:
             return False
         tracer = self.obs.tracer
-        t0 = time.perf_counter() if tracer.enabled else 0.0
         mc = self._multi_codebook
-        h.rng, sub = jax.random.split(h.rng)
-        step_pos = jnp.asarray(h.plen + h.step - 1, jnp.int32)
-        tok_in = h.tok[:, None] if not mc else h.tok[:, None, :]
-        pooled = h.cache is None           # resident pool, shared by batches
-        cache = self._pool_cache if pooled else h.cache
-        h.tok, lp, cache = self._decode_jit(
-            self.params, tok_in, step_pos, cache, sub, h.temperature,
-            h.extras, h.block_table,
-            kv_len=h.paged.kv_len if h.paged is not None else None,
-            greedy=h.temperature == 0.0)
-        if pooled:
-            self._pool_cache = cache
-        else:
-            h.cache = cache
-        h.out_toks.append(np.asarray(h.tok))
-        h.out_lps.append(np.asarray(lp if not mc else lp.mean(-1)))
-        h.step += 1
-        if tracer.enabled:
-            tracer.emit("decode", t0, time.perf_counter(), clock="wall",
-                        step=h.step, n_sequences=h.n_sequences)
-        if self._m is not None:
-            self._m["tokens_out"].inc(h.n_sequences - len(h.freed_seqs))
+        with tracer.span("decode", step_num=h.step,
+                         n_sequences=h.n_sequences):
+            with tracer.span("decode.dispatch"):
+                h.rng, sub = jax.random.split(h.rng)
+                step_pos = jnp.asarray(h.plen + h.step - 1, jnp.int32)
+                tok_in = h.tok[:, None] if not mc else h.tok[:, None, :]
+                pooled = h.cache is None   # resident pool, shared by batches
+                cache = self._pool_cache if pooled else h.cache
+                h.tok, lp, cache = self._decode_jit(
+                    self.params, tok_in, step_pos, cache, sub, h.temperature,
+                    h.extras, h.block_table,
+                    kv_len=h.paged.kv_len if h.paged is not None else None,
+                    greedy=h.temperature == 0.0)
+                if mc:
+                    lp = lp.mean(-1)
+            if pooled:
+                self._pool_cache = cache
+            else:
+                h.cache = cache
+            with tracer.span("decode.fetch"):
+                h.out_toks.append(np.asarray(h.tok))
+                h.out_lps.append(np.asarray(lp))
+            h.step += 1
+            if self._m is not None:
+                self._m["tokens_out"].inc(h.n_sequences - len(h.freed_seqs))
         return not h.done
 
     def _spec_decode_step(self, h: InFlightBatch) -> bool:
@@ -1403,74 +1406,77 @@ class ExecutionBackend:
             return False
         sp = h.spec
         tracer = self.obs.tracer
-        t_step = time.perf_counter() if tracer.enabled else 0.0
         B = h.n_sequences
         n = sp.n
-        drafts = np.asarray(sp.policy.propose(sp.histories, n), np.int32)
-        if drafts.shape != (B, n):
-            raise ValueError(f"draft policy {sp.policy.name!r} returned "
-                             f"shape {drafts.shape}, expected {(B, n)}")
-        t_draft = time.perf_counter() if tracer.enabled else 0.0
-        if tracer.enabled:
-            tracer.emit("draft", t_step, t_draft, clock="wall",
-                        policy=sp.policy.name, n=n, n_sequences=B)
-        h.rng, sub = jax.random.split(h.rng)
-        last = np.asarray([row[-1] for row in sp.toks], np.int32)
-        toks_in = np.concatenate([last[:, None], drafts], axis=1)
-        base_pos = np.asarray(h.plen + sp.committed - 1, np.int32)
-        pooled = h.cache is None           # resident pool, shared by batches
-        cache = self._pool_cache if pooled else h.cache
-        accept_len, out_tokens, out_lps, cache = self._spec_verify_jit(
-            self.params, jnp.asarray(toks_in), jnp.asarray(base_pos),
-            cache, sub, h.temperature, h.extras, h.block_table,
-            kv_len=h.paged.kv_len if h.paged is not None else None,
-            greedy=h.temperature == 0.0)
-        if pooled:
-            self._pool_cache = cache
-        else:
-            h.cache = cache
-        accept_len = np.asarray(accept_len)
-        out_tokens = np.asarray(out_tokens)
-        out_lps = np.asarray(out_lps)
+        with tracer.span("decode", step_num=h.step, n_sequences=B):
+            with tracer.span("draft", policy=sp.policy.name, n=n,
+                             n_sequences=B):
+                drafts = np.asarray(sp.policy.propose(sp.histories, n),
+                                    np.int32)
+            if drafts.shape != (B, n):
+                raise ValueError(f"draft policy {sp.policy.name!r} returned "
+                                 f"shape {drafts.shape}, expected {(B, n)}")
+            with tracer.span("verify", n=n, n_sequences=B) as vspan:
+                with tracer.span("decode.dispatch"):
+                    h.rng, sub = jax.random.split(h.rng)
+                    last = np.asarray([row[-1] for row in sp.toks],
+                                      np.int32)
+                    toks_in = np.concatenate([last[:, None], drafts], axis=1)
+                    base_pos = np.asarray(h.plen + sp.committed - 1,
+                                          np.int32)
+                    pooled = h.cache is None   # resident pool, shared
+                    cache = self._pool_cache if pooled else h.cache
+                    accept_len, out_tokens, out_lps, cache = \
+                        self._spec_verify_jit(
+                            self.params, jnp.asarray(toks_in),
+                            jnp.asarray(base_pos), cache, sub,
+                            h.temperature, h.extras, h.block_table,
+                            kv_len=(h.paged.kv_len if h.paged is not None
+                                    else None),
+                            greedy=h.temperature == 0.0)
+                if pooled:
+                    self._pool_cache = cache
+                else:
+                    h.cache = cache
+                with tracer.span("decode.fetch"):
+                    accept_len = np.asarray(accept_len)
+                    out_tokens = np.asarray(out_tokens)
+                    out_lps = np.asarray(out_lps)
 
-        emitted = 0             # metric-visible tokens (non-released rows)
-        committed_this = 0      # tokens committed by rows still decoding
-        accepted_this = 0
-        active = 0
-        for b in range(B):
-            room = h.max_new - int(sp.committed[b])
-            if room <= 0:
-                continue        # finished row: verify output discarded
-            active += 1
-            a = int(accept_len[b])
-            accepted_this += a
-            sp.proposed += n
-            sp.accepted += a
-            e = min(a + 1, room)
-            new = out_tokens[b, :e]
-            sp.toks[b].extend(int(t) for t in new)
-            sp.lps[b].extend(float(x) for x in out_lps[b, :e])
-            sp.histories[b] = np.concatenate(
-                [sp.histories[b], new.astype(np.int64)])
-            sp.committed[b] += e
-            committed_this += e
-            if b not in h.freed_seqs:
-                emitted += e
-        sp.steps += 1
-        h.step = int(sp.committed.min())
-        if tracer.enabled:
-            now = time.perf_counter()
-            tracer.emit("verify", t_draft, now, clock="wall", n=n,
-                        n_sequences=B, accepted=accepted_this)
-            tracer.emit("decode", t_step, now, clock="wall", step=h.step,
-                        n_sequences=B)
-        if self._m is not None:
-            self._m["tokens_out"].inc(emitted)
-            if active and n > 0:
-                self._m["spec_proposed"].inc(active * n)
-                self._m["spec_accepted"].inc(accepted_this)
-                self._m["spec_accept"].observe(accepted_this / (active * n))
-                self._m["spec_tps"].set(committed_this / active)
+                emitted = 0         # metric-visible tokens (non-released)
+                committed_this = 0  # tokens committed by rows decoding
+                accepted_this = 0
+                active = 0
+                for b in range(B):
+                    room = h.max_new - int(sp.committed[b])
+                    if room <= 0:
+                        continue    # finished row: verify output discarded
+                    active += 1
+                    a = int(accept_len[b])
+                    accepted_this += a
+                    sp.proposed += n
+                    sp.accepted += a
+                    e = min(a + 1, room)
+                    new = out_tokens[b, :e]
+                    sp.toks[b].extend(int(t) for t in new)
+                    sp.lps[b].extend(float(x) for x in out_lps[b, :e])
+                    sp.histories[b] = np.concatenate(
+                        [sp.histories[b], new.astype(np.int64)])
+                    sp.committed[b] += e
+                    committed_this += e
+                    if b not in h.freed_seqs:
+                        emitted += e
+                vspan.set(accepted=accepted_this)
+            sp.steps += 1
+            h.step = int(sp.committed.min())
+            if self._m is not None:
+                self._m["tokens_out"].inc(emitted)
+                if active and n > 0:
+                    self._m["spec_proposed"].inc(active * n)
+                    self._m["spec_accepted"].inc(accepted_this)
+                    self._m["spec_accept"].observe(
+                        accepted_this / (active * n))
+                    self._m["spec_tps"].set(committed_this / active)
         return not h.done
 
     def release(self, h: InFlightBatch) -> None:

@@ -62,7 +62,9 @@ pipeline for its re-costed makespan. Per-request queue delay and latency in
 `CompletedRequest` are in this simulated clock, which is what the SLA caps
 and `benchmarks/serving_schedule.py` measure. The real decode interleaving
 across in-flight batches exists so wall-clock work overlaps; it does not
-change simulated accounting.
+change simulated accounting. Spans and the ``serving_queue_delay_s``
+histogram (`repro.obs`) are on the wall clock; the simulated instants ride
+on the spans as attributes.
 
 The backend is duck-typed (``start_batch`` / ``decode_step`` / ``finalize``
 / ``slots_free`` / ``note_placement``), so pure scheduling-policy tests run
@@ -72,6 +74,7 @@ against a stub without touching JAX; the router likewise only needs
 from __future__ import annotations
 
 import math
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
@@ -82,6 +85,12 @@ from repro.obs import NULL_OBS
 from repro.serving.backend import bucket_key as _default_bucket_key
 
 _MISSING = object()    # getattr sentinel: absent attr vs attr that is None
+
+
+def _submitted(req, default: float) -> float:
+    """A request's wall submit instant (``default`` where its queue ran
+    without observability)."""
+    return req.submit_wall_s if req.submit_wall_s is not None else default
 
 
 def tier_priority(tier) -> float:
@@ -125,6 +134,7 @@ class ServeRequest:
     preemptions: int = 0               # times evicted mid-decode
     retries: int = 0                   # fault-eviction retry count
     not_before_s: float = 0.0          # retry backoff: earliest re-service
+    submit_wall_s: Optional[float] = None  # wall submit instant (obs on)
 
     @property
     def tier_name(self) -> str:
@@ -289,8 +299,9 @@ class RequestQueue:
         if self._m is not None:
             self._m["admissions"].inc(outcome="rejected", reason=code)
         if self.obs.tracer.enabled:
-            self.obs.tracer.emit("admit", arrival_s, admitted=False,
-                                 reason=code, tier=tier_name)
+            self.obs.tracer.emit("admit", time.perf_counter(), clock="wall",
+                                 admitted=False, reason=code, tier=tier_name,
+                                 sim_t0_s=arrival_s)
         return res
 
     def _note_depth(self, tier_name: str) -> None:
@@ -349,6 +360,8 @@ class RequestQueue:
                            max_new_tokens, temperature, rng=rng,
                            extras=extras, arrival_s=arrival_s,
                            seq=self._seq, deadline_s=deadline)
+        if self.obs.enabled:
+            req.submit_wall_s = time.perf_counter()
         self._next_id += 1
         self._seq += 1
         self._depth[name] = self._depth.get(name, 0) + 1
@@ -357,11 +370,11 @@ class RequestQueue:
             self._m["admissions"].inc(outcome="admitted", reason="ok")
             self._note_depth(name)
         if self.obs.tracer.enabled:
-            # the request's root span (a point on the sim clock); queue /
+            # the request's root span (a wall point at submit); queue /
             # release spans auto-parent under it via request_id
-            self.obs.tracer.emit("admit", arrival_s, request_id=req.id,
-                                 admitted=True, tier=name,
-                                 n_samples=n_samples)
+            self.obs.tracer.emit("admit", req.submit_wall_s, clock="wall",
+                                 request_id=req.id, admitted=True, tier=name,
+                                 n_samples=n_samples, sim_t0_s=arrival_s)
         return AdmissionResult(True, req.id, raised_samples=raised)
 
     # ------------------------------------------------------------- queries
@@ -524,8 +537,8 @@ class ContinuousBatchingScheduler:
         # without speculative support simply never receives a note
         self.spec_planner = spec_planner
         # one obs bundle serves the whole pipeline: the scheduler emits
-        # sim-clock lifecycle spans + batch metrics, its queue the admission
-        # side, and the backend wall-clock prefill/decode spans (spans meet
+        # lifecycle and host-phase spans + batch metrics, its queue the
+        # admission side, and the backend prefill/decode spans (spans meet
         # through tracer.batch_context — see repro.obs.tracer)
         self.obs = obs if obs is not None else NULL_OBS
         self.queue = queue if queue is not None else \
@@ -544,11 +557,9 @@ class ContinuousBatchingScheduler:
                     buckets=(1, 2, 4, 8, 16, 32, 64)),
                 "queue_delay": reg.histogram(
                     "serving_queue_delay_s",
-                    "Per-request simulated wait before batch service",
+                    "Per-request wall-clock wait from submit to the start "
+                    "of its batch's prefill",
                     labelnames=("tier",)),
-                "batch_latency": reg.histogram(
-                    "serving_batch_latency_s",
-                    "Routed batch service makespan (simulated)"),
                 "energy": reg.counter(
                     "serving_energy_j_total",
                     "Batch energy attributed per member tier",
@@ -885,10 +896,11 @@ class ContinuousBatchingScheduler:
                     (2 ** (req.retries - 1))
             requeue.append(req)
             if tracer.enabled:
-                tracer.emit("preempt", t_p, request_id=req.id,
+                tracer.emit("preempt", time.perf_counter(), clock="wall",
+                            request_id=req.id,
                             batch_id=entry.record.batch_id, reason=reason,
                             tier=req.tier_name,
-                            emitted=req.emitted_tokens)
+                            emitted=req.emitted_tokens, sim_t0_s=t_p)
         self.queue.push_front(requeue)
         if self._m is not None:
             self._m["preempt"].inc(reason=reason)
@@ -909,9 +921,11 @@ class ContinuousBatchingScheduler:
             if self._m is not None:
                 self._m["shed"].inc(tier=req.tier_name)
         if self.obs.tracer.enabled:
-            self.obs.tracer.emit("cancel", self.clock, request_id=req.id,
-                                 reason=reason, tier=req.tier_name,
-                                 deadline_s=req.deadline_s)
+            self.obs.tracer.emit("cancel", time.perf_counter(), clock="wall",
+                                 request_id=req.id, reason=reason,
+                                 tier=req.tier_name,
+                                 deadline_s=req.deadline_s,
+                                 sim_t0_s=self.clock)
 
     def _maybe_preempt_for_tier(self) -> Optional[Tuple]:
         """Tier-priority preemption check: when the best waiting request
@@ -966,188 +980,207 @@ class ContinuousBatchingScheduler:
 
     def _form_batch(self, bucket: Optional[Tuple] = None
                     ) -> Optional[_InflightEntry]:
-        free = self._capacity_free()
-        if free is not None and free <= 0:
-            return None
-        reqs = self.queue.pop_batch(self.config.max_batch_requests, free,
-                                    self._request_cost, now=self.clock,
-                                    bucket=bucket)
-        if not reqs:
-            return None
-        # extras compatibility: one batch stacks one set of per-request
-        # extras rows, so a request with different (or no) extras keys
-        # splits the batch there (it heads the next one — FIFO preserved)
-        keys0 = frozenset(reqs[0].extras or ())
-        cut = next((i for i, r in enumerate(reqs)
-                    if frozenset(r.extras or ()) != keys0), None)
-        if cut is not None:
-            self.queue.push_front(reqs[cut:])
-            reqs = reqs[:cut]
-        # cap-aware sizing: merged caps tighten to the strictest member, and
-        # feasibility depends on batch size (re-costed makespan grows with
-        # it) — shed the newest half back to the queue until the routed
-        # point meets caps or the batch is a single request. Each candidate
-        # is routed/costed at what would actually execute: the members'
-        # (possibly admission-raised) mean sampling budget and the bucket's
-        # prompt length / decode horizon, not the router's canonical
-        # workload — SLA caps must hold for the real batch.
-        while True:
-            route_kwargs = dict(
-                samples=math.ceil(sum(r.n_samples for r in reqs)
-                                  / len(reqs)),
-                prompt_tokens=len(reqs[0].effective_prompt),
-                decode_tokens=reqs[0].remaining_new)
-            if self.spec_planner is not None:
-                decision = self.spec_planner.route_batch(
-                    self.router, [r.tier for r in reqs], **route_kwargs)
-            else:
-                decision = self.router.route_batch(
-                    [r.tier for r in reqs], **route_kwargs)
-            if decision.meets_caps or len(reqs) == 1 or \
-                    not self.config.respect_caps:
-                break
-            keep = max(1, len(reqs) // 2)
-            self.queue.push_front(reqs[keep:])
-            reqs = reqs[:keep]
-        # the routed draft depth applies to THIS batch only: the backend
-        # consumes the note at its next start_batch
-        spec_plan = getattr(decision, "spec", None)
-        if spec_plan is not None and hasattr(self.backend, "note_spec"):
-            self.backend.note_spec(spec_plan.n)
-
-        start = max(self.clock, self.pipeline_free_t)
-        # latency_inflation > 1 models injected slow-kernel faults (chaos
-        # harness): the routed estimate stands, service time stretches
-        done_t = start + decision.latency_s * self.latency_inflation
-        self.pipeline_free_t = done_t
-        # a resumed request contributes its n_samples per-sample histories
-        # as distinct prompt rows (repeat 1 each): samples may have diverged
-        prompts: List[np.ndarray] = []
-        nsamps: List[int] = []
-        for r in reqs:
-            if r.resume is not None:
-                prompts.extend(r.resume.prompts)
-                nsamps.extend([1] * r.n_samples)
-            else:
-                prompts.append(r.prompt)
-                nsamps.append(r.n_samples)
-        extras = None
-        if reqs[0].extras:
-            extras = {k: np.stack([r.extras[k]
-                                   for r in reqs
-                                   for _ in range(r.n_samples
-                                                  if r.resume is not None
-                                                  else 1)])
-                      for k in reqs[0].extras}
         tracer = self.obs.tracer
-        tracer.batch_context = self._batch_id
-        try:
-            handle = self.backend.start_batch(
-                prompts, nsamps, reqs[0].remaining_new,
-                reqs[0].temperature, self._batch_rng(reqs), extras)
-        finally:
-            tracer.batch_context = None
-        self.backend.note_placement(decision.assignment)
+        with tracer.span("sched.form_batch"):
+            free = self._capacity_free()
+            if free is not None and free <= 0:
+                return None
+            reqs = self.queue.pop_batch(self.config.max_batch_requests, free,
+                                        self._request_cost, now=self.clock,
+                                        bucket=bucket)
+            if not reqs:
+                return None
+            # extras compatibility: one batch stacks one set of per-request
+            # extras rows, so a request with different (or no) extras keys
+            # splits the batch there (it heads the next one — FIFO preserved)
+            keys0 = frozenset(reqs[0].extras or ())
+            cut = next((i for i, r in enumerate(reqs)
+                        if frozenset(r.extras or ()) != keys0), None)
+            if cut is not None:
+                self.queue.push_front(reqs[cut:])
+                reqs = reqs[:cut]
+            # cap-aware sizing: merged caps tighten to the strictest member,
+            # and feasibility depends on batch size (re-costed makespan
+            # grows with it) — shed the newest half back to the queue until
+            # the routed point meets caps or the batch is a single request.
+            # Each candidate is routed/costed at what would actually
+            # execute: the members' (possibly admission-raised) mean
+            # sampling budget and the bucket's prompt length / decode
+            # horizon, not the router's canonical workload — SLA caps must
+            # hold for the real batch.
+            while True:
+                route_kwargs = dict(
+                    samples=math.ceil(sum(r.n_samples for r in reqs)
+                                      / len(reqs)),
+                    prompt_tokens=len(reqs[0].effective_prompt),
+                    decode_tokens=reqs[0].remaining_new)
+                with tracer.span("route", n_requests=len(reqs)):
+                    if self.spec_planner is not None:
+                        decision = self.spec_planner.route_batch(
+                            self.router, [r.tier for r in reqs],
+                            **route_kwargs)
+                    else:
+                        decision = self.router.route_batch(
+                            [r.tier for r in reqs], **route_kwargs)
+                if decision.meets_caps or len(reqs) == 1 or \
+                        not self.config.respect_caps:
+                    break
+                keep = max(1, len(reqs) // 2)
+                self.queue.push_front(reqs[keep:])
+                reqs = reqs[:keep]
+            # the routed draft depth applies to THIS batch only: the backend
+            # consumes the note at its next start_batch
+            spec_plan = getattr(decision, "spec", None)
+            if spec_plan is not None and hasattr(self.backend, "note_spec"):
+                self.backend.note_spec(spec_plan.n)
 
-        # resume accounting: a parked victim's full-prefix chains come back
-        # as trie hits, so only the post-preemption tail prefills — the
-        # bytes-saved counter is the robustness claim's receipt
-        resume_reqs = resume_full = resume_tail = 0
-        layout = getattr(handle, "paged", None)
-        hit_counts = layout.hit_counts if layout is not None else []
-        bs = layout.block_size if layout is not None else 1
-        row = 0
-        for r in reqs:
-            n_rows = r.n_samples if r.resume is not None else 1
-            if r.resume is not None:
-                resume_reqs += 1
-                for i in range(row, row + n_rows):
-                    full = len(prompts[i])
-                    hits = hit_counts[i] if i < len(hit_counts) else 0
-                    resume_full += full
-                    resume_tail += full - hits * bs
-                if tracer.enabled:
-                    tracer.emit("resume", start, request_id=r.id,
-                                batch_id=self._batch_id,
-                                tier=r.tier_name,
-                                emitted=r.emitted_tokens,
-                                preemptions=r.preemptions)
-            row += n_rows
-        if resume_reqs:
-            self.resume_full_tokens += resume_full
-            self.resume_tail_tokens += resume_tail
-            if self._m is not None:
-                ktb = getattr(self.backend, "kv_token_bytes", 0) or 0
-                self._m["resume_saved"].inc(
-                    (resume_full - resume_tail) * int(ktb))
-
-        tier_mix: Dict[str, int] = {}
-        for r in reqs:
-            tier_mix[r.tier_name] = tier_mix.get(r.tier_name, 0) + 1
-        # the batch's ACTUAL speculation state comes off the handle (the
-        # backend may run at its default depth with no planner attached)
-        hspec = getattr(handle, "spec", None)
-        record = BatchRecord(
-            batch_id=self._batch_id, t_s=start,
-            bucket=len(reqs[0].effective_prompt), n_requests=len(reqs),
-            n_sequences=sum(r.n_samples for r in reqs), tier_mix=tier_mix,
-            queue_delay_s=max(start - r.arrival_s for r in reqs),
-            point_index=decision.point_index,
-            energy_j=decision.energy_j, latency_s=decision.latency_s,
-            meets_caps=decision.meets_caps, reroute=self._reroute_pending,
-            kv_blocks_in_use=getattr(self.backend, "blocks_in_use", None),
-            prefill_bytes_saved=float(getattr(handle, "prefill_bytes_saved",
-                                              0.0)),
-            pool_hit_blocks=int(getattr(handle, "pool_hit_blocks", 0)),
-            pool_evictions=int(getattr(handle, "pool_evictions", 0)),
-            quant=getattr(self.backend, "quant_format", "bf16"),
-            kv_format=getattr(self.backend, "kv_format", "bf16"),
-            weight_bytes=getattr(self.backend, "weight_bytes", None),
-            kv_bytes_in_use=self._kv_bytes_in_use(),
-            spec_policy=hspec.policy.name if hspec is not None else "off",
-            spec_n=hspec.n if hspec is not None else 0,
-            spec_accept_rate=(spec_plan.accept_rate
-                              if spec_plan is not None and spec_plan.enabled
-                              else None),
-            resume_requests=resume_reqs,
-            resume_full_tokens=resume_full,
-            resume_tail_tokens=resume_tail,
-            request_entries=[{"id": r.id, "tier": r.tier_name,
-                              "n_samples": r.n_samples,
-                              "queue_delay_s": start - r.arrival_s,
-                              "resumed": r.resume is not None}
-                             for r in reqs])
-        self._reroute_pending = False
-        self._batch_id += 1
-        self.records.append(record)
-        if self.trace is not None:
-            self.trace.ingest_serve(record,
-                                    signals=plan_signals(decision))
-        if tracer.enabled:
-            tracer.emit("schedule", start, batch_id=record.batch_id,
-                        point_index=record.point_index,
-                        energy_j=record.energy_j,
-                        latency_s=record.latency_s,
-                        meets_caps=record.meets_caps,
-                        n_requests=record.n_requests,
-                        tier_mix=dict(tier_mix))
+            start = max(self.clock, self.pipeline_free_t)
+            # latency_inflation > 1 models injected slow-kernel faults (chaos
+            # harness): the routed estimate stands, service time stretches
+            done_t = start + decision.latency_s * self.latency_inflation
+            self.pipeline_free_t = done_t
+            # a resumed request contributes its n_samples per-sample
+            # histories as distinct prompt rows (repeat 1 each): samples may
+            # have diverged
+            prompts: List[np.ndarray] = []
+            nsamps: List[int] = []
             for r in reqs:
-                # per-member wait on the sim clock; batch_id joins the
-                # request to its batch-level schedule/prefill/decode spans
-                tracer.emit("queue", r.arrival_s, start, request_id=r.id,
-                            batch_id=record.batch_id, tier=r.tier_name)
-        if self._m is not None:
-            self._observe_batch(record, decision, reqs)
-        return _InflightEntry(handle, reqs, decision, record, start, done_t)
+                if r.resume is not None:
+                    prompts.extend(r.resume.prompts)
+                    nsamps.extend([1] * r.n_samples)
+                else:
+                    prompts.append(r.prompt)
+                    nsamps.append(r.n_samples)
+            extras = None
+            if reqs[0].extras:
+                extras = {k: np.stack([r.extras[k]
+                                       for r in reqs
+                                       for _ in range(r.n_samples
+                                                      if r.resume is not None
+                                                      else 1)])
+                          for k in reqs[0].extras}
+            # wall instant the batch is committed; its members' queue spans
+            # end where the backend's prefill span begins
+            t_call = time.perf_counter() if self.obs.enabled else 0.0
+            tracer.batch_context = self._batch_id
+            try:
+                handle = self.backend.start_batch(
+                    prompts, nsamps, reqs[0].remaining_new,
+                    reqs[0].temperature, self._batch_rng(reqs), extras)
+            finally:
+                tracer.batch_context = None
+            t_prefill = tracer.opened_at("prefill")
+            if t_prefill is None or t_prefill < t_call:
+                t_prefill = t_call        # a backend with no prefill span
+            self.backend.note_placement(decision.assignment)
+
+            # resume accounting: a parked victim's full-prefix chains come back
+            # as trie hits, so only the post-preemption tail prefills — the
+            # bytes-saved counter is the robustness claim's receipt
+            resume_reqs = resume_full = resume_tail = 0
+            layout = getattr(handle, "paged", None)
+            hit_counts = layout.hit_counts if layout is not None else []
+            bs = layout.block_size if layout is not None else 1
+            row = 0
+            for r in reqs:
+                n_rows = r.n_samples if r.resume is not None else 1
+                if r.resume is not None:
+                    resume_reqs += 1
+                    for i in range(row, row + n_rows):
+                        full = len(prompts[i])
+                        hits = hit_counts[i] if i < len(hit_counts) else 0
+                        resume_full += full
+                        resume_tail += full - hits * bs
+                    if tracer.enabled:
+                        tracer.emit("resume", t_prefill, clock="wall",
+                                    request_id=r.id,
+                                    batch_id=self._batch_id,
+                                    tier=r.tier_name,
+                                    emitted=r.emitted_tokens,
+                                    preemptions=r.preemptions,
+                                    sim_t0_s=start)
+                row += n_rows
+            if resume_reqs:
+                self.resume_full_tokens += resume_full
+                self.resume_tail_tokens += resume_tail
+                if self._m is not None:
+                    ktb = getattr(self.backend, "kv_token_bytes", 0) or 0
+                    self._m["resume_saved"].inc(
+                        (resume_full - resume_tail) * int(ktb))
+
+            tier_mix: Dict[str, int] = {}
+            for r in reqs:
+                tier_mix[r.tier_name] = tier_mix.get(r.tier_name, 0) + 1
+            # the batch's ACTUAL speculation state comes off the handle (the
+            # backend may run at its default depth with no planner attached)
+            hspec = getattr(handle, "spec", None)
+            record = BatchRecord(
+                batch_id=self._batch_id, t_s=start,
+                bucket=len(reqs[0].effective_prompt), n_requests=len(reqs),
+                n_sequences=sum(r.n_samples for r in reqs), tier_mix=tier_mix,
+                queue_delay_s=max(start - r.arrival_s for r in reqs),
+                point_index=decision.point_index,
+                energy_j=decision.energy_j, latency_s=decision.latency_s,
+                meets_caps=decision.meets_caps, reroute=self._reroute_pending,
+                kv_blocks_in_use=getattr(self.backend, "blocks_in_use", None),
+                prefill_bytes_saved=float(getattr(
+                    handle, "prefill_bytes_saved", 0.0)),
+                pool_hit_blocks=int(getattr(handle, "pool_hit_blocks", 0)),
+                pool_evictions=int(getattr(handle, "pool_evictions", 0)),
+                quant=getattr(self.backend, "quant_format", "bf16"),
+                kv_format=getattr(self.backend, "kv_format", "bf16"),
+                weight_bytes=getattr(self.backend, "weight_bytes", None),
+                kv_bytes_in_use=self._kv_bytes_in_use(),
+                spec_policy=hspec.policy.name if hspec is not None else "off",
+                spec_n=hspec.n if hspec is not None else 0,
+                spec_accept_rate=(
+                    spec_plan.accept_rate
+                    if spec_plan is not None and spec_plan.enabled
+                    else None),
+                resume_requests=resume_reqs,
+                resume_full_tokens=resume_full,
+                resume_tail_tokens=resume_tail,
+                request_entries=[{"id": r.id, "tier": r.tier_name,
+                                  "n_samples": r.n_samples,
+                                  "queue_delay_s": start - r.arrival_s,
+                                  "resumed": r.resume is not None}
+                                 for r in reqs])
+            self._reroute_pending = False
+            self._batch_id += 1
+            self.records.append(record)
+            if self.trace is not None:
+                self.trace.ingest_serve(record,
+                                        signals=plan_signals(decision))
+            if tracer.enabled:
+                tracer.emit("schedule", t_call, clock="wall",
+                            batch_id=record.batch_id,
+                            point_index=record.point_index,
+                            energy_j=record.energy_j,
+                            latency_s=record.latency_s,
+                            meets_caps=record.meets_caps,
+                            n_requests=record.n_requests,
+                            tier_mix=dict(tier_mix),
+                            sim_t0_s=start, sim_t1_s=done_t)
+                for r in reqs:
+                    # per-member wall wait; batch_id joins the request to
+                    # its batch-level schedule/prefill/decode spans
+                    tracer.emit("queue", _submitted(r, t_prefill), t_prefill,
+                                clock="wall", request_id=r.id,
+                                batch_id=record.batch_id, tier=r.tier_name,
+                                sim_t0_s=r.arrival_s, sim_t1_s=start,
+                                queue_delay_s=start - r.arrival_s)
+            if self._m is not None:
+                self._observe_batch(record, decision, reqs, t_prefill)
+            return _InflightEntry(handle, reqs, decision, record, start,
+                                  done_t)
 
     def _observe_batch(self, record: BatchRecord, decision,
-                       reqs: List[ServeRequest]) -> None:
+                       reqs: List[ServeRequest], t_prefill: float) -> None:
         m = self._m
         m["occupancy"].observe(record.n_requests)
-        m["batch_latency"].observe(record.latency_s)
         m["prefill_saved"].inc(record.prefill_bytes_saved)
         for r in reqs:
-            m["queue_delay"].observe(record.t_s - r.arrival_s,
+            m["queue_delay"].observe(t_prefill - _submitted(r, t_prefill),
                                      tier=r.tier_name)
             m["sequences"].inc(r.n_samples, tier=r.tier_name)
             self._tier_seqs[r.tier_name] = \
@@ -1190,9 +1223,11 @@ class ContinuousBatchingScheduler:
                     freed = rel(entry.handle, [off + i for i in idxs])
                     if self.obs.tracer.enabled:
                         self.obs.tracer.emit(
-                            "early_stop", self.clock, request_id=request_id,
+                            "early_stop", time.perf_counter(), clock="wall",
+                            request_id=request_id,
                             batch_id=entry.record.batch_id, freed=freed,
-                            n_released=len(list(idxs)))
+                            n_released=len(list(idxs)),
+                            sim_t0_s=self.clock)
                     if self._m is not None and freed:
                         self._m["early_stop"].inc(freed)
                     return freed
@@ -1267,55 +1302,59 @@ class ContinuousBatchingScheduler:
                         self.preempt(entry, "shed")
 
     def _retire(self, entry: _InflightEntry) -> None:
-        results = self.backend.finalize(entry.handle)
-        self.clock = max(self.clock, entry.done_t)
         tracer = self.obs.tracer
-        sp = getattr(entry.handle, "spec", None)
-        if sp is not None:
-            # measured accept counts land on the record, and a "spec" trace
-            # record closes the loop: CalibrationFitter turns these into
-            # per-(model, tier, policy) accept rates for SpecPlanner.refresh
-            entry.record.spec_proposed = int(sp.proposed)
-            entry.record.spec_accepted = int(sp.accepted)
-            entry.record.spec_accept_rate = float(sp.accept_rate)
-            if self.trace is not None and sp.proposed:
-                cfg = getattr(getattr(self.backend, "model", None),
-                              "cfg", None)
-                merged = getattr(entry.decision, "tier", None)
-                rec = {"kind": "spec", "t_s": float(entry.done_t),
-                       "policy": str(sp.policy.name), "n": int(sp.n),
-                       "proposed": int(sp.proposed),
-                       "accepted": int(sp.accepted)}
-                if cfg is not None:
-                    rec["model"] = str(cfg.name)
-                if merged is not None:
-                    rec["tier"] = str(merged.name)
-                self.trace.ingest(rec)
-        off = 0
-        for req in entry.requests:
-            if req.resume is not None:
-                # resumed request: n_samples single-sample results, each the
-                # post-preemption tail — splice onto the snapshot so the
-                # caller sees one uninterrupted completion
-                k = req.n_samples
-                res = self._merge_resumed(req, results[off:off + k])
-                off += k
-            else:
-                res = results[off]
-                off += 1
-            self.completed[req.id] = CompletedRequest(
-                request=req, result=res, batch_id=entry.record.batch_id,
-                queue_delay_s=entry.start_t - req.arrival_s,
-                latency_s=entry.done_t - req.arrival_s,
-                decision=entry.decision)
-            if tracer.enabled:
-                tracer.emit("release", entry.done_t, request_id=req.id,
-                            batch_id=entry.record.batch_id,
-                            tier=req.tier_name,
-                            queue_delay_s=entry.start_t - req.arrival_s,
-                            latency_s=entry.done_t - req.arrival_s)
-            if self._m is not None:
-                self._m["completed"].inc(tier=req.tier_name)
+        with tracer.span("sched.retire"):
+            results = self.backend.finalize(entry.handle)
+            self.clock = max(self.clock, entry.done_t)
+            sp = getattr(entry.handle, "spec", None)
+            if sp is not None:
+                # measured accept counts land on the record, and a "spec"
+                # trace record closes the loop: CalibrationFitter turns these
+                # into per-(model, tier, policy) accept rates for
+                # SpecPlanner.refresh
+                entry.record.spec_proposed = int(sp.proposed)
+                entry.record.spec_accepted = int(sp.accepted)
+                entry.record.spec_accept_rate = float(sp.accept_rate)
+                if self.trace is not None and sp.proposed:
+                    cfg = getattr(getattr(self.backend, "model", None),
+                                  "cfg", None)
+                    merged = getattr(entry.decision, "tier", None)
+                    rec = {"kind": "spec", "t_s": float(entry.done_t),
+                           "policy": str(sp.policy.name), "n": int(sp.n),
+                           "proposed": int(sp.proposed),
+                           "accepted": int(sp.accepted)}
+                    if cfg is not None:
+                        rec["model"] = str(cfg.name)
+                    if merged is not None:
+                        rec["tier"] = str(merged.name)
+                    self.trace.ingest(rec)
+            off = 0
+            for req in entry.requests:
+                if req.resume is not None:
+                    # resumed request: n_samples single-sample results, each
+                    # the post-preemption tail — splice onto the snapshot so
+                    # the caller sees one uninterrupted completion
+                    k = req.n_samples
+                    res = self._merge_resumed(req, results[off:off + k])
+                    off += k
+                else:
+                    res = results[off]
+                    off += 1
+                self.completed[req.id] = CompletedRequest(
+                    request=req, result=res, batch_id=entry.record.batch_id,
+                    queue_delay_s=entry.start_t - req.arrival_s,
+                    latency_s=entry.done_t - req.arrival_s,
+                    decision=entry.decision)
+                if tracer.enabled:
+                    tracer.emit("release", time.perf_counter(), clock="wall",
+                                request_id=req.id,
+                                batch_id=entry.record.batch_id,
+                                tier=req.tier_name,
+                                queue_delay_s=entry.start_t - req.arrival_s,
+                                latency_s=entry.done_t - req.arrival_s,
+                                sim_t0_s=entry.done_t)
+                if self._m is not None:
+                    self._m["completed"].inc(tier=req.tier_name)
 
     # ---------------------------------------------------------------- step
     def step(self) -> bool:
@@ -1323,42 +1362,43 @@ class ContinuousBatchingScheduler:
         shedding), check tier preemption, form batches while capacity
         allows, then one decode token per in-flight batch; retire finished
         batches. Returns False when there was nothing to do."""
-        progressed = False
-        self._enforce_deadlines()
-        self._enforce_shedding()
-        # tier preemption returns the outranking waiter's bucket so its
-        # batch forms FIRST — the re-queued victims hold older seqs and
-        # would win the FIFO pop right back otherwise
-        bucket_pref = self._maybe_preempt_for_tier()
-        while len(self.inflight) < self.config.max_inflight_batches:
-            entry = self._form_batch(bucket_pref)
-            bucket_pref = None
-            if entry is None:
-                break
-            self.inflight.append(entry)
-            progressed = True
         tracer = self.obs.tracer
-        for entry in list(self.inflight):
-            if not entry.handle.done:
-                tracer.batch_context = entry.record.batch_id
-                try:
-                    self.backend.decode_step(entry.handle)
-                finally:
-                    tracer.batch_context = None
+        with tracer.span("sched.step"):
+            progressed = False
+            self._enforce_deadlines()
+            self._enforce_shedding()
+            # tier preemption returns the outranking waiter's bucket so its
+            # batch forms FIRST — the re-queued victims hold older seqs and
+            # would win the FIFO pop right back otherwise
+            bucket_pref = self._maybe_preempt_for_tier()
+            while len(self.inflight) < self.config.max_inflight_batches:
+                entry = self._form_batch(bucket_pref)
+                bucket_pref = None
+                if entry is None:
+                    break
+                self.inflight.append(entry)
                 progressed = True
-            if entry.handle.done:
-                self.inflight.remove(entry)
-                self._retire(entry)
-                progressed = True
-        if not progressed and not self.inflight and self.queue.pending:
-            # everything queued is backoff-parked: jump the sim clock to the
-            # earliest retry instant instead of reporting starvation
-            nb = self.queue.earliest_not_before()
-            if nb is not None and nb > self.clock:
-                self.advance_to(nb)
-                progressed = True
-        if self._m is not None:
-            self._m["inflight"].set(len(self.inflight))
+            for entry in list(self.inflight):
+                if not entry.handle.done:
+                    tracer.batch_context = entry.record.batch_id
+                    try:
+                        self.backend.decode_step(entry.handle)
+                    finally:
+                        tracer.batch_context = None
+                    progressed = True
+                if entry.handle.done:
+                    self.inflight.remove(entry)
+                    self._retire(entry)
+                    progressed = True
+            if not progressed and not self.inflight and self.queue.pending:
+                # everything queued is backoff-parked: jump the sim clock to
+                # the earliest retry instant instead of reporting starvation
+                nb = self.queue.earliest_not_before()
+                if nb is not None and nb > self.clock:
+                    self.advance_to(nb)
+                    progressed = True
+            if self._m is not None:
+                self._m["inflight"].set(len(self.inflight))
         return progressed
 
     def run_until_idle(self, max_steps: int = 10 ** 6

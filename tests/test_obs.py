@@ -230,6 +230,104 @@ def test_lifecycle_reconstruction_complete_and_incomplete():
     assert not lifecycles_complete(tr.spans, expect_requests=2)
 
 
+def test_tracer_span_nesting_parent_ids_and_wall_times():
+    import time
+    tr = Tracer()
+    before = time.perf_counter()
+    with tr.span("outer", plen=8) as outer:
+        with tr.span("inner", step_num=3):
+            pass
+        point = tr.emit("mark", time.perf_counter(), clock="wall")
+        outer.set(done=True)
+    after = time.perf_counter()
+    by = {s.name: s for s in tr.spans}
+    assert [s.name for s in tr.spans] == ["inner", "mark", "outer"]
+    o, i = by["outer"], by["inner"]
+    assert o.parent_id is None
+    assert i.parent_id == o.span_id and by["mark"].parent_id == o.span_id
+    assert point == by["mark"].span_id
+    assert all(s.clock == "wall" for s in tr.spans)
+    assert before <= o.t0_s <= i.t0_s <= i.t1_s <= o.t1_s <= after
+    assert o.attrs == {"plen": 8, "done": True}
+    assert i.attrs == {"step_num": 3}
+    assert tr.opened_at("inner") == i.t0_s
+    tr.close()
+
+
+def test_null_tracer_span_records_and_opens_nothing(monkeypatch):
+    import jax
+
+    from repro.obs import tracer as tracer_mod
+
+    def forbidden(*a, **k):
+        raise AssertionError("a disabled span read a clock or annotated")
+
+    monkeypatch.setattr(tracer_mod.time, "perf_counter", forbidden)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", forbidden)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", forbidden)
+    tr = NullTracer()
+    a = tr.span("decode", step_num=1, n_sequences=4)
+    with a as sp:
+        sp.set(prefill_tokens=3)
+        with tr.span("decode.fetch") as inner:
+            assert inner is sp
+    assert a is NULL_OBS.tracer.span("prefill")      # one shared context
+    assert len(tr) == 0 and tr.spans == [] and tr.opened_at("decode") is None
+
+
+def test_gc_inside_span_emits_gc_child():
+    import gc
+    tr = Tracer()
+    with tr.span("sched.retire"):
+        gc.collect()
+    [ret] = [s for s in tr.spans if s.name == "sched.retire"]
+    pauses = [s for s in tr.spans if s.name == "gc"]
+    full = [s for s in pauses if s.attrs["generation"] == 2]
+    assert full and all(s.parent_id == ret.span_id for s in pauses)
+    assert all(ret.t0_s <= s.t0_s <= s.t1_s <= ret.t1_s for s in pauses)
+    assert isinstance(full[0].attrs["collected"], int)
+    tr.close()
+
+
+def test_jit_compile_inside_span_emits_compile_child():
+    import jax
+    import jax.numpy as jnp
+    tr = Tracer()
+    with tr.span("decode", n_sequences=5):
+        with tr.span("decode.dispatch"):
+            jax.jit(lambda x: x * 7 - 2)(jnp.ones(13)).block_until_ready()
+    by = {s.name: s for s in tr.spans if s.name != "compile"}
+    comp = [s for s in tr.spans if s.name == "compile"
+            and s.attrs["fun_name"] == "jit(<lambda>)"]
+    assert comp
+    c = comp[0]
+    assert c.parent_id == by["decode.dispatch"].span_id
+    assert c.attrs["n_sequences"] == 5        # the open spans' shape attrs
+    assert by["decode.dispatch"].t0_s <= c.t0_s <= c.t1_s \
+        <= by["decode.dispatch"].t1_s
+    tr.close()
+
+
+@pytest.mark.parametrize("how", ["close", "collect"])
+def test_tracer_hooks_go_with_close_or_collection(how):
+    import gc
+
+    from jax._src import monitoring
+    n_gc = len(gc.callbacks)
+    n_dur = len(monitoring._event_duration_secs_listeners)
+    tr = Tracer()
+    assert len(gc.callbacks) == n_gc + 1
+    assert len(monitoring._event_duration_secs_listeners) == n_dur + 1
+    if how == "close":
+        tr.close()
+        tr.close()                                   # idempotent
+    else:
+        del tr
+        gc.collect()
+    assert len(gc.callbacks) == n_gc
+    assert len(monitoring._event_duration_secs_listeners) == n_dur
+
+
 # ==================================================== TraceStore hardening
 
 def test_tracestore_rejects_nonfinite_and_unknown_kind():
@@ -377,6 +475,52 @@ def test_scheduler_metrics_spans_and_queue_delay_entries():
     assert per_req == {0, 1, 2}
 
 
+def test_scheduler_lifecycle_spans_are_wall_clock_and_keep_sim_values():
+    import time
+    obs = make_observability()
+    sched = ContinuousBatchingScheduler(
+        _StubBackend(), _StubRouter(_tiers3()),
+        SchedulerConfig(max_batch_requests=2, max_new_tokens=3), obs=obs)
+    t_before = time.perf_counter()
+    sched.advance_to(1.0)
+    for i in range(3):              # two batches: the third request waits
+        sched.submit(np.arange(1, 5, dtype=np.int32), tier="economy",
+                     arrival_s=0.1 * i)
+    sched.run_until_idle()
+    t_after = time.perf_counter()
+    spans = obs.tracer.spans
+    life = {"admit", "queue", "schedule", "release"}
+    assert all(s.clock == "wall" and t_before <= s.t0_s <= s.t1_s <= t_after
+               for s in spans if s.name in life)
+    for rid in range(3):
+        mine = {s.name: s for s in spans if s.request_id == rid}
+        a, q, r = mine["admit"], mine["queue"], mine["release"]
+        assert a.t0_s == q.t0_s <= q.t1_s <= r.t0_s
+        assert q.parent_id == a.span_id and r.parent_id == a.span_id
+        # the simulated clock rides along as attributes
+        done = sched.completed[rid]
+        assert a.attrs["sim_t0_s"] == pytest.approx(0.1 * rid)
+        assert q.attrs["sim_t0_s"] == pytest.approx(0.1 * rid)
+        assert q.attrs["queue_delay_s"] == pytest.approx(done.queue_delay_s)
+        assert q.attrs["sim_t1_s"] - q.attrs["sim_t0_s"] == \
+            pytest.approx(done.queue_delay_s)
+        assert r.attrs["latency_s"] == pytest.approx(done.latency_s)
+    # the host phases: every batch forms inside a step, routed inside it
+    by_id = {s.span_id: s for s in spans}
+    for name, parent in (("route", "sched.form_batch"),
+                         ("sched.form_batch", "sched.step"),
+                         ("sched.retire", "sched.step"),
+                         ("schedule", "sched.form_batch")):
+        kids = [s for s in spans if s.name == name]
+        assert kids and all(by_id[s.parent_id].name == parent for s in kids)
+    # serving_queue_delay_s observes the queue spans' own wall intervals
+    h = obs.metrics.get("serving_queue_delay_s")
+    waits = [s.t1_s - s.t0_s for s in spans if s.name == "queue"]
+    assert h.total(tier="economy") == 3
+    assert h.sum_value(tier="economy") == pytest.approx(sum(waits))
+    assert obs.metrics.get("serving_batch_latency_s") is None
+
+
 def test_admission_reject_reason_codes():
     obs = make_observability()
     q = RequestQueue(router=_StubRouter(_tiers3()), max_queue_depth=1,
@@ -475,6 +619,66 @@ def test_pinned_bit_parity_engine_obs_on_off():
     assert la == lb
     for a, b in zip(sa, sb):
         np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("weights", ["bf16", "int8"])
+def test_bit_parity_obs_on_off_at_reduced_chatglm3(weights):
+    """The host-phase spans at the benchmark's reduced chatglm3-6b sizes
+    (pooled paged KV, shared prefixes, sampled): identical tokens and
+    log-probs with the tracer live, and every phase under its parent."""
+    pytest.importorskip("jax")
+    import jax
+    import jax.numpy as jnp
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "chipbench"))
+    from small_cells import small, small_arch
+
+    from repro.models import Model
+    from repro.serving import ExecutionBackend
+
+    conf = small("chatglm3-6b-bf16.chat-shared").config
+    model = Model(small_arch(conf), dtype=jnp.bfloat16)
+    params = model.init(jax.random.key(0))
+    if weights != "bf16":
+        from repro.quant import quantize_model
+        params = quantize_model(params, weights)
+    rng = np.random.default_rng(5)
+    shared = rng.integers(1, 500, 16).astype(np.int32)
+    prompts = [np.concatenate([shared, rng.integers(1, 500, 8)
+                               .astype(np.int32)]) for _ in range(3)]
+
+    def serve(obs):
+        backend = ExecutionBackend(model, params, kv_blocks=64,
+                                   kv_block_size=16, kv_pool=True, obs=obs)
+        sched = ContinuousBatchingScheduler(
+            backend, _StubRouter(_tiers3()),
+            SchedulerConfig(max_batch_requests=2, max_new_tokens=5, seed=9),
+            obs=obs)
+        ids = [sched.submit(p, tier="economy", n_samples=2,
+                            temperature=0.8).request_id for p in prompts]
+        done = sched.run_until_idle()
+        return [(done[i].result.samples, done[i].result.logprobs)
+                for i in ids]
+
+    off = serve(None)
+    obs = make_observability()
+    on = serve(obs)
+    obs.tracer.close()
+    for (sa, la), (sb, lb) in zip(off, on):
+        assert la == lb
+        for a, b in zip(sa, sb):
+            np.testing.assert_array_equal(a, b)
+    spans = obs.tracer.spans
+    by_id = {s.span_id: s for s in spans}
+    parents = {"pool.match": "prefill", "prefill.dispatch": "prefill",
+               "prefill.fetch": "prefill", "decode.dispatch": "decode",
+               "decode.fetch": "decode", "decode": "sched.step",
+               "prefill": "sched.form_batch"}
+    for name, parent in parents.items():
+        kids = [s for s in spans if s.name == name]
+        assert kids, name
+        assert all(by_id[s.parent_id].name == parent for s in kids), name
+    assert lifecycles_complete(spans, expect_requests=3)
 
 
 # ================================================== compare.py + profile.py
