@@ -27,17 +27,23 @@ from repro.obs.profiling import kernel_scope
 
 
 @jax.jit
-def dequant_matmul(x: jnp.ndarray, qw: jnp.ndarray,
-                   scale: jnp.ndarray) -> jnp.ndarray:
-    """``x (..., K) @ dequantize(qw, scale) -> (..., N)`` in x.dtype."""
+def dequant_matmul(x: jnp.ndarray, qw: jnp.ndarray, scale: jnp.ndarray,
+                   layer=None) -> jnp.ndarray:
+    """``x (..., K) @ dequantize(qw, scale) -> (..., N)`` in x.dtype.
+
+    With ``layer``, ``qw`` stacks a scanned model's weights and
+    ``qw[layer]`` is the one used; the int8 kernel reads it in place.
+    """
     int4 = qw.dtype == jnp.uint8
     with kernel_scope("dequant_matmul"):
+        if layer is not None and (int4 or interpret_mode()):
+            qw, layer = qw[layer], None
         if interpret_mode():
             return (dequant_matmul_int4_ref if int4
                     else dequant_matmul_int8_ref)(x, qw, scale)
-        kernel = (dequant_matmul_int4_pallas if int4
-                  else dequant_matmul_int8_pallas)
-        y = kernel(x.reshape(-1, x.shape[-1]), qw, scale)
+        x2 = x.reshape(-1, x.shape[-1])
+        y = (dequant_matmul_int4_pallas(x2, qw, scale) if int4
+             else dequant_matmul_int8_pallas(x2, qw, scale, layer))
         return y.reshape(*x.shape[:-1], y.shape[-1])
 
 

@@ -191,6 +191,10 @@ class Model:
                                    use_kernel=self.use_kernel,
                                    block_table=block_table, kv_len=kv_len,
                                    decode=decode)
+        # int8 weight stacks stay whole, read in place by the kernel
+        from repro.quant.quantize import bind_int8_stacks, split_int8_stacks
+        blocks, stacks = split_int8_stacks(params["blocks"])
+        layers = jnp.arange(cache_mod.n_scanned_super_blocks(cfg))
         if cache is None:
             def one(bp_, x_):
                 x2_, _, a_ = sb_fwd(bp_, x=x_, cache=None)
@@ -199,24 +203,26 @@ class Model:
             if self.remat:
                 one = jax.checkpoint(one)
 
-            def body(carry, bp):
+            def body(carry, inp):
                 x, aux = carry
-                x2, a = one(bp, x)
+                bp, i = inp
+                x2, a = one(bind_int8_stacks(bp, stacks, i), x)
                 return (x2, aux + a), None
 
             (h, aux_s), _ = jax.lax.scan(body, (h, jnp.zeros((), jnp.float32)),
-                                         params["blocks"])
+                                         (blocks, layers))
             new_cache = None
         else:
             def body(carry, inp):
                 x, aux = carry
-                bp, bc = inp
-                x2, nc, a = sb_fwd(bp, x=x, cache=bc)
+                bp, i, bc = inp
+                x2, nc, a = sb_fwd(bind_int8_stacks(bp, stacks, i), x=x,
+                                   cache=bc)
                 return (x2, aux + a), nc
 
             (h, aux_s), new_blocks = jax.lax.scan(
                 body, (h, jnp.zeros((), jnp.float32)),
-                (params["blocks"], cache["blocks"]))
+                (blocks, layers, cache["blocks"]))
             new_cache = {"prefix": new_prefix, "blocks": new_blocks}
 
         aux_total = aux_total + aux_s

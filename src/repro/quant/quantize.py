@@ -132,12 +132,45 @@ def is_quantized_dense(p: Any) -> bool:
 
 def qdense(p: Dict, x: jnp.ndarray) -> jnp.ndarray:
     """Quantized counterpart of `repro.models.layers.dense`: fused
-    dequant-matmul plus the (full-precision) bias."""
+    dequant-matmul plus the (full-precision) bias. A dict bound by
+    `bind_int8_stacks` carries the whole weight stack and its ``"layer"``."""
     from repro.kernels.dequant_matmul import ops as dq_ops
-    y = dq_ops.dequant_matmul(x, p["qw"], p["scale"])
+    y = dq_ops.dequant_matmul(x, p["qw"], p["scale"], p.get("layer"))
     if "b" in p:
         y = y + p["b"]
     return y
+
+
+def split_int8_stacks(node: Any) -> Tuple[Any, Dict]:
+    """Scanned super-block params -> (the rest, the stacked int8 weights).
+
+    A scan slices its inputs layer by layer, and XLA copies each slice of
+    a weight that a kernel reads: a whole layer's int8 weights, every
+    step. The ``(L, K, N)`` int8 weights therefore stay out of what the
+    scan slices; `bind_int8_stacks` hands each stack whole to `qdense`
+    with the layer index, and the kernel reads the layer's rows in place.
+    """
+    if not isinstance(node, dict):
+        return node, {}
+    if (is_quantized_dense(node) and node["qw"].dtype == jnp.int8
+            and node["qw"].ndim == 3):
+        return ({k: v for k, v in node.items() if k != "qw"},
+                {"qw": node["qw"]})
+    rest, stacks = {}, {}
+    for k, v in node.items():
+        rest[k], sub = split_int8_stacks(v)
+        if sub:
+            stacks[k] = sub
+    return rest, stacks
+
+
+def bind_int8_stacks(node: Dict, stacks: Dict, layer) -> Dict:
+    """One layer's slice of `split_int8_stacks`'s rest, with each int8
+    dense dict given its whole stack and ``"layer"`` (see `qdense`)."""
+    if "qw" in stacks:
+        return {**node, "qw": stacks["qw"], "layer": layer}
+    return {**node, **{k: bind_int8_stacks(node[k], sub, layer)
+                       for k, sub in stacks.items()}}
 
 
 # ============================================================ whole-model API

@@ -35,8 +35,10 @@ from repro.models.cache import (kv_bytes_per_token, make_cache,  # noqa: E402
                                 PagedLayout, copy_cache_blocks)
 from repro.quant import (bytes_per_param_for, dequantize_model,  # noqa: E402
                          group_size_for, pack_int4, param_bytes,
-                         params_quant_format, quant_workload, quantize_int4,
-                         quantize_int8, quantize_model)
+                         params_quant_format, qdense, quant_workload,
+                         quantize_int4, quantize_int8, quantize_model)
+from repro.quant.quantize import (bind_int8_stacks,  # noqa: E402
+                                  split_int8_stacks)
 
 CFG = ArchConfig(name="tq", arch_type="dense", n_layers=2, d_model=64,
                  n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=64)
@@ -102,16 +104,47 @@ def test_group_size_adjusts_to_even_divisor():
 
 # ===================================================== kernel vs oracle parity
 
-@pytest.mark.parametrize("M,K,N", [(5, 48, 19), (1, 32, 130), (9, 64, 64),
-                                   (17, 96, 33)])
-def test_int8_kernel_matches_oracle_nonaligned(M, K, N):
+def _exact_int8_operands(M, K, N, dtype, rng):
+    """x on a grid its dtype holds exactly (quarters for bf16; for f32,
+    steps of 1/256, finer than bf16 holds, so rounding x to bf16 would
+    show), int8 weights and power-of-two scales: every product and partial
+    sum is exact in f32, so kernel and oracle agree before the one rounding
+    to x's dtype, at any K."""
+    steps = 16 if dtype == jnp.bfloat16 else 1024
+    x = jnp.asarray(rng.integers(-steps, steps + 1, size=(M, K))
+                    / (steps // 4), dtype)
+    qw = jnp.asarray(rng.integers(-127, 128, size=(K, N)), jnp.int8)
+    scale = jnp.asarray(2.0 ** -rng.integers(4, 9, size=N), jnp.float32)
+    return x, qw, scale
+
+
+@pytest.mark.parametrize("M,K,N,dtype", [
+    pytest.param(5, 48, 19, None, id="5-48-19"),
+    pytest.param(1, 32, 130, None, id="1-32-130"),
+    pytest.param(9, 64, 64, None, id="9-64-64"),
+    pytest.param(17, 96, 33, None, id="17-96-33"),
+    # decode: one resident row block, a wide tile that leaves N a partial edge
+    pytest.param(32, 512, 1408, jnp.bfloat16, id="decode-bf16"),
+    # K no power of two, N in three tiles, the last partial
+    pytest.param(32, 1664, 1408, jnp.bfloat16, id="decode-bf16-wide-k"),
+    pytest.param(32, 1664, 1408, jnp.float32, id="decode-f32"),
+    # prefill: two row blocks, the second partial
+    pytest.param(600, 640, 1408, jnp.bfloat16, id="prefill-bf16"),
+    pytest.param(600, 640, 1408, jnp.float32, id="prefill-f32"),
+])
+def test_int8_kernel_matches_oracle_nonaligned(M, K, N, dtype):
     rng = np.random.default_rng(2)
-    x = jnp.asarray(rng.normal(size=(M, K)), jnp.float32)
-    qw, scale = quantize_int8(jnp.asarray(rng.normal(size=(K, N)),
-                                          jnp.float32))
+    if dtype is None:  # normal draws, quantized
+        x = jnp.asarray(rng.normal(size=(M, K)), jnp.float32)
+        qw, scale = quantize_int8(jnp.asarray(rng.normal(size=(K, N)),
+                                              jnp.float32))
+    else:
+        x, qw, scale = _exact_int8_operands(M, K, N, dtype, rng)
     want = dequant_matmul_int8_ref(x, qw, scale)
     got = dequant_matmul_int8_pallas(x, qw, scale, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+    assert got.dtype == x.dtype and got.shape == (M, N)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
                                rtol=1e-5, atol=1e-5)
 
 
@@ -144,6 +177,53 @@ def test_dispatch_discriminates_by_dtype():
     np.testing.assert_array_equal(np.asarray(y4),
                                   np.asarray(dequant_matmul_int4_ref(x, qw4,
                                                                      s4)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_int8_kernel_reads_one_layer_of_a_stack(dtype):
+    """Given an (L, K, N) stack and a traced layer index, as a layer scan
+    hands them over, the kernel computes with that layer's weight."""
+    rng = np.random.default_rng(5)
+    L, M, K, N = 3, 32, 256, 640
+    x, _, _ = _exact_int8_operands(M, K, N, dtype, rng)
+    stack = jnp.asarray(rng.integers(-127, 128, size=(L, K, N)), jnp.int8)
+    scale = jnp.asarray(2.0 ** -rng.integers(4, 9, size=(L, N)), jnp.float32)
+
+    def body(_, inp):
+        s, i = inp
+        return None, dequant_matmul_int8_pallas(x, stack, s, i,
+                                                interpret=True)
+
+    _, got = jax.lax.scan(body, None, (scale, jnp.arange(L)))
+    for i in range(L):
+        want = dequant_matmul_int8_ref(x, stack[i], scale[i])
+        np.testing.assert_allclose(np.asarray(got[i], np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_int8_stacks_split_from_scanned_blocks_and_bound_per_layer(
+        model_params):
+    _, params = model_params
+    rest, stacks = split_int8_stacks(quantize_model(params, "int8")["blocks"])
+    assert all(leaf.dtype != jnp.int8 for leaf in jax.tree.leaves(rest))
+    qws = jax.tree.leaves(stacks)
+    assert qws and all(q.dtype == jnp.int8 and q.ndim == 3 for q in qws)
+    # int4 stacks (and full-precision weights) stay where the scan slices
+    assert split_int8_stacks(quantize_model(params, "int4")["blocks"])[1] \
+        == {}
+    assert split_int8_stacks(params["blocks"])[1] == {}
+    x = jnp.asarray(np.random.default_rng(6).normal(size=(3, CFG.d_model)),
+                    jnp.float32)
+    for i in range(qws[0].shape[0]):
+        layer = jax.tree.map(lambda a: a[i], rest)
+        bound = bind_int8_stacks(layer, stacks, jnp.int32(i))
+        wq = bound["l0"]["attn"]["wq"]
+        assert wq["qw"] is stacks["l0"]["attn"]["wq"]["qw"]
+        want = dequant_matmul_int8_ref(x, wq["qw"][i], layer["l0"]["attn"]
+                                       ["wq"]["scale"])
+        np.testing.assert_array_equal(np.asarray(qdense(wq, x)),
+                                      np.asarray(want))
 
 
 # =========================================================== model quantizing

@@ -12,6 +12,9 @@ that runs these tests loads the TPU library.
 """
 from __future__ import annotations
 
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -80,10 +83,19 @@ def _dense():
              ((B, W), I32), ((B,), I32)])
 
 
-def _int8():
+def _int8(M=16, K=None, N=None):
     _, _, _, d, f = _glm()
+    K, N = K or d, N or f
     return (lambda *a: dequant_matmul_int8_pallas(*a, interpret=False),
-            [((16, d), BF), ((d, f), jnp.int8), ((f,), F32)])
+            [((M, K), BF), ((K, N), jnp.int8), ((N,), F32)])
+
+
+# every (K, N) chatglm3-6b serves through the int8 kernel (q, k and v, o,
+# gate and up, down), at a decode batch and a prefill tail: each compile
+# proves its blocks fit the default scoped VMEM
+_INT8_SERVED = [(M, K, N) for M in (32, 576)
+                for K, N in ((4096, 4096), (4096, 256), (4096, 13696),
+                             (13696, 4096))]
 
 
 def _int4():
@@ -116,6 +128,9 @@ def _ssd():
     pytest.param(_paged, id="paged_decode_attention"),
     pytest.param(_dense, id="decode_attention"),
     pytest.param(_int8, id="dequant_matmul_int8"),
+    *[pytest.param(functools.partial(_int8, *mkn),
+                   id="dequant_matmul_int8-{}x{}x{}".format(*mkn))
+      for mkn in _INT8_SERVED],
     pytest.param(_int4, id="dequant_matmul_int4"),
     pytest.param(_moe, id="moe_gemm"),
     pytest.param(_ssd, id="ssd_scan"),
@@ -141,3 +156,76 @@ def test_chatglm3_init_fits_one_chip(one_chip):
     assert mem.output_size_in_bytes == pytest.approx(weights, rel=1e-3)
     assert mem.temp_size_in_bytes < 0.01 * weights
     assert mem.output_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def _entry(hlo):
+    """(name, opcode, operands, result type) of each ENTRY instruction."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    return re.findall(r"^\s*(?:ROOT )?%(\S+) = (\S+) ([\w-]+)\((.*?)\)",
+                      entry, re.M)
+
+
+def test_int8_kernel_is_one_named_custom_call(one_chip):
+    """Decode over the down projection's (13696, 4096) int8 weight, the
+    kernel called on its own: the compiled program is the kernel's one
+    custom call, named after its jit (the name `chipbench`'s roofline
+    reader sums), and nothing else reads the weight: no pad, copy or
+    convert of it outside the kernel (a bitcast moves no bytes). Under a
+    layer scan see `test_int8_model_reads_weight_stacks_in_place`."""
+    fn, shapes = _int8(32, 13696, 4096)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    instrs = [(n, op, ops) for n, _, op, ops in
+              _entry(jax.jit(fn).lower(*args).compile().as_text())]
+    weight = {next(name for name, op, operands in instrs
+                   if op == "parameter" and operands == "1")}
+    readers = []
+    for name, op, operands in instrs:
+        if any(re.search(rf"%{re.escape(w)}\b", operands) for w in weight):
+            (weight.add if op == "bitcast" else
+             lambda n: readers.append((n, op)))(name)
+    calls = [name for name, op, _ in instrs
+             if op == "custom-call" and "dequant_matmul" in name]
+    assert len(calls) == 1
+    assert readers == [(calls[0], "custom-call")]
+
+
+def test_int8_model_reads_weight_stacks_in_place(one_chip, monkeypatch):
+    """A decode step of int8 chatglm3-6b at its published size, with the
+    kernel the chip dispatches to: the layer scan hands the kernel each
+    whole (L, K, N) weight stack and the layer index, so no XLA op slices,
+    copies or stages an int8 weight (a scan over the stacks themselves
+    stages each layer's seven weights into VMEM, and the kernel's device
+    time then leaves out their HBM read); every s8 array the program
+    makes is a view of a parameter."""
+    from repro.kernels.dequant_matmul import ops as dq_ops
+    from repro.quant import quantize_model
+    monkeypatch.setattr(dq_ops, "interpret_mode", lambda: False)
+    dq_ops.dequant_matmul.clear_cache()
+    model = Model(get_config("chatglm3-6b"), dtype=BF)
+    B, T = 32, 256
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: quantize_model(model.init(jax.random.key(0)), "int8")))
+    cache = on_chip(model.init_cache(B, T, spec_only=True))
+    tokens = jax.ShapeDtypeStruct((B, 1), I32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((B, 1), I32, sharding=one_chip)
+
+    def step(p, c, t, q):
+        return model.forward(p, {"tokens": t, "positions": q}, c)[:2]
+
+    try:
+        hlo = jax.jit(step).lower(params, cache, tokens, pos).compile() \
+            .as_text()
+    finally:
+        dq_ops.dequant_matmul.clear_cache()
+    s8 = {(op, name) for name, ty, op in
+          re.findall(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(", hlo, re.M)
+          if "s8[" in ty}
+    assert {op for op, _ in s8} <= {"parameter", "get-tuple-element",
+                                    "bitcast", "tuple", "while"}, sorted(s8)
+    calls = re.findall(r"%(\S+) = \S+ custom-call\(([^)]*)\)", hlo)
+    assert sum("dequant_matmul" in name for name, _ in calls) == 7
