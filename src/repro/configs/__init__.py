@@ -21,11 +21,20 @@ _ARCH_MODULES = {
 
 ASSIGNED_ARCHS: List[str] = list(_ARCH_MODULES)
 
+# a chip's share of a multi-chip deployment: (module, attribute)
+_SHARES = {
+    "deepseek-v2-lite-16b-ep4": ("deepseek_v2_lite_16b", "EP4"),
+}
+
 
 def get_config(name: str) -> ArchConfig:
     if name in _ARCH_MODULES:
         mod = importlib.import_module(f"repro.configs.{_ARCH_MODULES[name]}")
         return mod.CONFIG
+    if name in _SHARES:
+        module, attr = _SHARES[name]
+        return getattr(importlib.import_module(f"repro.configs.{module}"),
+                       attr)
     from repro.configs.paper_models import PAPER_MODELS
     if name in PAPER_MODELS:
         return PAPER_MODELS[name]

@@ -135,10 +135,11 @@ def _ffn_counts(cfg: ArchConfig, w: Workload, layer_idx: int
     if cfg.is_moe_layer(layer_idx):
         m = cfg.moe
         ff = cfg.expert_ff()
-        active = m.top_k + m.n_shared
+        # the routed pairs that land on the experts this device holds
+        active = m.top_k * m.held / m.n_experts + m.n_shared
         flops = 2 * 3 * d * ff * active + 2 * d * m.n_experts
         p_active = (3 * d * ff * active + d * m.n_experts) * bpp
-        p_total = (3 * d * ff * (m.n_experts + m.n_shared) +
+        p_total = (3 * d * ff * (m.held + m.n_shared) +
                    d * m.n_experts) * bpp
     elif cfg.d_ff > 0:
         mult = 3 if cfg.mlp_variant == "swiglu" else 2

@@ -68,7 +68,7 @@ def lower_and_compile(arch: str, shape_name: str, mesh_kind: str = "single",
                       shard_hints: bool = False, mla_naive: bool = False,
                       ssm_split: bool = False, no_tp: bool = False,
                       microbatches: int = 1, cache_fp8: bool = False,
-                      cross_cache: bool = False, moe_dense: bool = False,
+                      cross_cache: bool = False,
                       dtype=jnp.bfloat16) -> Dict:
     """One (arch x shape x mesh) dry-run. Returns the artifact dict.
 
@@ -86,8 +86,6 @@ def lower_and_compile(arch: str, shape_name: str, mesh_kind: str = "single",
         cfg = cfg.with_overrides(ssm_split_proj=True)
     if cross_cache:
         cfg = cfg.with_overrides(cross_kv_cache=True)
-    if moe_dense:
-        cfg = cfg.with_overrides(moe_dense_decode=True)
     from repro.distributed import hints
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     if shard_hints:
@@ -177,7 +175,7 @@ def lower_and_compile(arch: str, shape_name: str, mesh_kind: str = "single",
         "fsdp": fsdp, "shard_hints": shard_hints, "mla_naive": mla_naive,
         "ssm_split": ssm_split, "no_tp": no_tp,
         "microbatches": microbatches, "cache_fp8": cache_fp8,
-        "cross_cache": cross_cache, "moe_dense": moe_dense,
+        "cross_cache": cross_cache,
         "memory_analysis": mem,
         "cost_analysis": cost,
         "collective_bytes": coll,
@@ -230,7 +228,6 @@ def main() -> None:
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--cache-fp8", action="store_true")
     ap.add_argument("--cross-cache", action="store_true")
-    ap.add_argument("--moe-dense", action="store_true")
     ap.add_argument("--trace", default=None,
                     help="append each artifact's compiled.cost_analysis() "
                          "FLOP/byte counts to this JSONL telemetry trace "
@@ -258,8 +255,7 @@ def main() -> None:
                               ssm_split=args.ssm_split, no_tp=args.no_tp,
                               microbatches=args.microbatches,
                               cache_fp8=args.cache_fp8,
-                              cross_cache=args.cross_cache,
-                              moe_dense=args.moe_dense)
+                              cross_cache=args.cross_cache)
                 if "error" in art:
                     failures.append((arch, shape_name, mesh_kind))
                 elif trace_store is not None:

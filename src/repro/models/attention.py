@@ -20,7 +20,8 @@ import numpy as np
 
 from repro.models.config import ArchConfig
 from repro.models.layers import (Params, apply_rope, dense, dense_init,
-                                 dense_spec)
+                                 dense_spec, einsum_f32, rmsnorm,
+                                 rmsnorm_init, rmsnorm_spec, yarn_mscale)
 
 NEG_INF = -1e30
 
@@ -64,6 +65,7 @@ def _mla_spec(cfg: ArchConfig, dtype) -> Params:
         "w_uk": dense_spec(m.kv_lora_rank, cfg.n_heads * m.qk_nope_head_dim, dtype),
         "w_uv": dense_spec(m.kv_lora_rank, cfg.n_heads * m.v_head_dim, dtype),
         "wo": dense_spec(cfg.n_heads * m.v_head_dim, cfg.d_model, dtype),
+        "kv_norm": rmsnorm_spec(m.kv_lora_rank, dtype),
     }
 
 
@@ -78,6 +80,7 @@ def _mla_init(key, cfg: ArchConfig, dtype) -> Params:
         "w_uk": dense_init(ks[3], m.kv_lora_rank, cfg.n_heads * m.qk_nope_head_dim, dtype),
         "w_uv": dense_init(ks[4], m.kv_lora_rank, cfg.n_heads * m.v_head_dim, dtype),
         "wo": dense_init(ks[5], cfg.n_heads * m.v_head_dim, cfg.d_model, dtype),
+        "kv_norm": rmsnorm_init(m.kv_lora_rank, dtype),
     }
 
 
@@ -122,6 +125,34 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(jnp.float32))
     return out.reshape(B, Sq, Hq, v.shape[-1]).astype(v.dtype)
+
+
+# Above this many float32 score elements a call, attention over a cache runs
+# its queries in blocks: a tail prefill against pooled prefixes, or a cold
+# prefill of a long prompt, holds one block's scores at a time.
+CACHE_SCORES = 1 << 27
+
+
+def in_query_blocks(attend, per_query: int, *qs) -> jnp.ndarray:
+    """``attend(*qs)``, whose arguments share (B, S) leading axes and
+    whose result is (B, S, ...), over blocks of the S queries when B * S *
+    ``per_query`` score elements exceed `CACHE_SCORES`; padded queries are
+    computed and dropped."""
+    B, S = qs[0].shape[:2]
+    bq = CACHE_SCORES // (B * per_query)
+    if bq >= S:
+        return attend(*qs)
+    bq = max(8, bq - bq % 8)
+    pad = (-S) % bq
+    nb = (S + pad) // bq
+
+    def blocks(a):
+        a = jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+        return jnp.moveaxis(a.reshape(B, nb, bq, *a.shape[2:]), 1, 0)
+
+    out = jax.lax.map(lambda a: attend(*a), tuple(blocks(a) for a in qs))
+    return jnp.moveaxis(out, 0, 1).reshape(B, nb * bq,
+                                           *out.shape[3:])[:, :S]
 
 
 # Above this many score elements per (batch, head), causal attention switches
@@ -221,11 +252,10 @@ def gqa_forward(p: Params, cfg: ArchConfig, x: jnp.ndarray,
     pos1d = positions[..., 0] if positions.ndim == 3 else positions
 
     # Routing is static: S > 1 means train/prefill (fresh cache), S == 1 means a
-    # decode step against the ring cache. Chunked prefill (S > 1 with a non-empty
-    # cache) is intentionally unsupported — the engine always prefills whole
-    # prompts (see repro/serving/engine.py). ``decode=True`` overrides the S > 1
-    # heuristic for speculative verify steps (multiple query tokens against the
-    # populated cache).
+    # decode step against the cache. ``decode=True`` sends S > 1 queries through
+    # the cache-attending branches too: speculative verify, tail prefill
+    # against pooled prefix blocks, and chunked prefill (each chunk attends
+    # over the chunks already written).
     if cache is None or (S > 1 and not decode):
         # ---- train / prefill over full (possibly windowed) sequence
         if use_kernel:
@@ -239,7 +269,8 @@ def gqa_forward(p: Params, cfg: ArchConfig, x: jnp.ndarray,
             out = sdpa(q, k, v, mask, scale)
         new_cache = None
         if cache is not None:
-            new_cache = (_fill_cache_paged(cache, k, v, pos1d, block_table)
+            new_cache = (_fill_cache_paged(cache, {"k": k, "v": v}, pos1d,
+                                           block_table)
                          if block_table is not None
                          else _fill_cache(cfg, cache, k, v, pos1d))
         y = dense(p["wo"], out.reshape(B, S, cfg.n_heads * hd))
@@ -249,7 +280,8 @@ def gqa_forward(p: Params, cfg: ArchConfig, x: jnp.ndarray,
         # ---- paged decode: write through the block table (same scatter as
         # prefill), attend over the gathered (reference) or table-indexed
         # (kernel) pools
-        new_cache = _fill_cache_paged(cache, k, v, pos1d, block_table)
+        new_cache = _fill_cache_paged(cache, {"k": k, "v": v}, pos1d,
+                                      block_table)
         ck, cv, cpos = new_cache["k"], new_cache["v"], new_cache["pos"]
         quantized = ck.dtype == jnp.int8
         if use_kernel and not quantized and S == 1:
@@ -273,7 +305,9 @@ def gqa_forward(p: Params, cfg: ArchConfig, x: jnp.ndarray,
             if kv_len is not None:
                 kc, vc, pc = kc[:, :kv_len], vc[:, :kv_len], pc[:, :kv_len]
             ok = (pc[:, None, :] >= 0) & (pc[:, None, :] <= pos1d[:, :, None])
-            out = sdpa(q, kc, vc, ok, scale)
+            out = in_query_blocks(
+                lambda qb, okb: sdpa(qb, kc, vc, okb, scale),
+                cfg.n_heads * kc.shape[1], q, ok)
         y = dense(p["wo"], out.reshape(B, S, cfg.n_heads * hd))
         return y, new_cache
 
@@ -320,35 +354,37 @@ def _fill_cache(cfg: ArchConfig, cache: Dict, k, v, pos1d) -> Dict:
     return {"k": ck, "v": cv, "pos": cpos}
 
 
-def _fill_cache_paged(cache: Dict, k, v, pos1d,
+def _fill_cache_paged(cache: Dict, new: Dict[str, jnp.ndarray], pos1d,
                       block_table: jnp.ndarray) -> Dict:
-    """Write prefill keys/values through the block table into paged pools.
+    """Write per-position cache entries through the block table into paged
+    pools: ``new`` maps a pool's name to its (B, S, ...) values (GQA ``k``
+    and ``v``; MLA ``c_kv`` and ``k_rope``), and ``pos`` is written beside.
 
     ``block_table`` here is the *prefill* table (one row per unique prompt):
     position p lands in pool block ``table[b, p // bs]`` row ``p % bs``.
     Every row owns distinct blocks, so scatter indices stay unique.
 
-    int8 pools (``cache["k"].dtype == int8``) quantize on fill: each written
-    slot stores ``round(k / scale)`` per kv-head with ``scale = absmax / 127``
-    scattered into ``k_scale`` / ``v_scale`` alongside.
+    int8 pools quantize on fill: each written slot stores ``round(x /
+    scale)`` per kv-head with ``scale = absmax / 127`` scattered into
+    ``<name>_scale`` alongside.
     """
-    ck, cv, cpos = cache["k"], cache["v"], cache["pos"]
-    bs = ck.shape[1]
+    cpos = cache["pos"]
+    bs = cpos.shape[1]
     bidx = jnp.arange(pos1d.shape[0])[:, None]
     blk = block_table[bidx, pos1d // bs]
     row = (pos1d % bs).astype(jnp.int32)
-    if ck.dtype == jnp.int8:
-        kq, ks = _quantize_kv(k)
-        vq, vs = _quantize_kv(v)
-        return {"k": ck.at[blk, row].set(kq),
-                "v": cv.at[blk, row].set(vq),
-                "pos": cpos.at[blk, row].set(pos1d.astype(jnp.int32)),
-                "k_scale": cache["k_scale"].at[blk, row].set(ks),
-                "v_scale": cache["v_scale"].at[blk, row].set(vs)}
-    ck = ck.at[blk, row].set(k.astype(ck.dtype))
-    cv = cv.at[blk, row].set(v.astype(cv.dtype))
-    cpos = cpos.at[blk, row].set(pos1d.astype(jnp.int32))
-    return {"k": ck, "v": cv, "pos": cpos}
+    out = dict(cache)
+    for name, val in new.items():
+        pool = cache[name]
+        if pool.dtype == jnp.int8:
+            q, scale = _quantize_kv(val)
+            out[name] = pool.at[blk, row].set(q)
+            out[f"{name}_scale"] = cache[f"{name}_scale"].at[blk, row].set(
+                scale)
+        else:
+            out[name] = pool.at[blk, row].set(val.astype(pool.dtype))
+    out["pos"] = cpos.at[blk, row].set(pos1d.astype(jnp.int32))
+    return out
 
 
 def _quantize_kv(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -364,64 +400,112 @@ def _quantize_kv(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
 # MLA attention (DeepSeek-V2): latent KV cache
 # =============================================================================
 
+def mla_softmax_scale(cfg: ArchConfig) -> float:
+    """``q_head_dim ** -0.5``, times ``mscale(factor, mscale_all_dim) ** 2``
+    under YaRN scaling."""
+    m = cfg.mla
+    scale = 1.0 / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return float(scale)
+
+
+def _latent_attend(q_nope, q_rope, qpos, cc, cr, cpos, w_uk, w_uv,
+                   scale: float, window: Optional[int]) -> jnp.ndarray:
+    """Absorbed attention in latent space: each query's nope part (B, S, H,
+    nd) folded through ``w_uk`` (r, H, nd), plus its rope part (B, S, H,
+    rd), against the latent cache ``cc`` (B, K, r) and rope keys ``cr`` (B,
+    K, rd), masked by the slots' positions ``cpos`` (B, K) against the
+    queries' ``qpos`` (B, S); the latent context through ``w_uv`` (r, H,
+    vd). Inputs in the cache's dtype, accumulation in float32. Returns
+    (B, S, H, vd) in the cache's dtype."""
+    B, S, H, _ = q_nope.shape
+    K = cc.shape[1]
+    dt = cc.dtype
+
+    def attend(qn, qr, qp):
+        ql = einsum_f32("bshn,rhn->bshr", qn, w_uk).astype(dt)
+        with jax.named_scope("mla_attend"):
+            s = einsum_f32("bshr,bkr->bhsk", ql, cc)
+            s = s + einsum_f32("bshr,bkr->bhsk", qr.astype(dt), cr)
+            ok = (cpos[:, None, :] >= 0) & (cpos[:, None, :] <= qp[:, :, None])
+            if window is not None:
+                ok &= cpos[:, None, :] > qp[:, :, None] - window
+            s = jnp.where(ok[:, None], s * scale, NEG_INF)
+            p = jax.nn.softmax(s, axis=-1)
+            ctx = einsum_f32("bhsk,bkr->bshr", p.astype(dt), cc)
+        return einsum_f32("bshr,rhv->bshv", ctx.astype(dt), w_uv).astype(dt)
+
+    return in_query_blocks(attend, H * K, q_nope, q_rope, qpos)
+
+
 def mla_forward(p: Params, cfg: ArchConfig, x: jnp.ndarray,
                 positions: jnp.ndarray,
                 cache: Optional[Dict] = None,
                 absorbed_decode: bool = True,
-                use_kernel: bool = False) -> Tuple[jnp.ndarray, Optional[Dict]]:
-    """Multi-head Latent Attention.
+                use_kernel: bool = False,
+                block_table: Optional[jnp.ndarray] = None,
+                kv_len: Optional[int] = None,
+                decode: bool = False) -> Tuple[jnp.ndarray, Optional[Dict]]:
+    """Multi-head Latent Attention (DeepSeek-V2's published block):
+    ``q = x W_q``; ``[c_kv, k_rope] = x W_kv_a`` with RMSNorm on ``c_kv``;
+    ``k_nope, v = c_kv W_uk, c_kv W_uv``; rope (YaRN where configured) on
+    ``q_rope`` and the one shared ``k_rope``.
 
-    The cache stores only the compressed latent ``c_kv`` (rank kv_lora) plus the
-    shared rope key — the paper-relevant decode-bytes optimization. In absorbed
-    decode mode, scores are computed in latent space (W_uk folded into q), so the
-    per-step bytes are O(S·(kv_lora + rope_hd)) instead of O(S·2·H·hd).
+    The cache stores only the normed latent ``c_kv`` (rank kv_lora) plus the
+    shared rope key. Train/prefill over a fresh cache decompresses k and v.
+    Every cache-attending call (decode; with ``decode=True`` also tail
+    prefill against pooled prefix blocks, chunked prefill and speculative
+    verify) writes its latents first, then attends in latent space
+    (W_uk folded into q, W_uv applied to the latent context), so the bytes
+    read per step are O(S·(kv_lora + rope_hd)) instead of O(S·2·H·hd).
+    With ``block_table`` the cache entries are block pools, read by
+    gathering each sequence's blocks in logical order up to ``kv_len``.
     """
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.n_heads
-    nd, rd, vd = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
-    scale = 1.0 / np.sqrt(nd + rd)
+    nd, rd, vd, r = (m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim,
+                     m.kv_lora_rank)
+    scale = mla_softmax_scale(cfg)
     pos1d = positions[..., 0] if positions.ndim == 3 else positions
 
     q = dense(p["wq"], x).reshape(B, S, H, nd + rd)
     q_nope, q_rope = q[..., :nd], q[..., nd:]
-    q_rope = apply_rope(q_rope, pos1d, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, pos1d, cfg.rope_theta,
+                        scaling=cfg.rope_scaling)
 
-    c_kv = dense(p["w_dkv"], x)                       # (B,S,r)
+    c_kv = rmsnorm(p["kv_norm"], dense(p["w_dkv"], x), cfg.norm_eps)  # (B,S,r)
     k_rope = dense(p["w_krope"], x).reshape(B, S, 1, rd)
-    k_rope = apply_rope(k_rope, pos1d, cfg.rope_theta)
+    k_rope = apply_rope(k_rope, pos1d, cfg.rope_theta,
+                        scaling=cfg.rope_scaling)
+    new = {"c_kv": c_kv, "k_rope": k_rope[:, :, 0]}
 
-    decoding = cache is not None and S == 1
-
-    if decoding:
-        cc, cr, cpos = cache["c_kv"], cache["k_rope"], cache["pos"]
-        W = cc.shape[1]
-        slot = (pos1d % W).astype(jnp.int32)
-        bidx = jnp.arange(B)[:, None]
-        cc = cc.at[bidx, slot].set(c_kv.astype(cc.dtype))
-        cr = cr.at[bidx, slot].set(k_rope[:, :, 0].astype(cr.dtype))
-        cpos = cpos.at[bidx, slot].set(pos1d.astype(jnp.int32))
-        ok = (cpos[:, None, :] >= 0) & (cpos[:, None, :] <= pos1d[:, :, None])
-        if cfg.attn_window is not None:
-            ok &= cpos[:, None, :] > pos1d[:, :, None] - cfg.attn_window
+    if cache is not None and (S == 1 or decode):
+        if block_table is not None:
+            new_cache = _fill_cache_paged(cache, new, pos1d, block_table)
+            cc = new_cache["c_kv"][block_table].reshape(B, -1, r)
+            cr = new_cache["k_rope"][block_table].reshape(B, -1, rd)
+            cpos = new_cache["pos"][block_table].reshape(B, -1)
+            if kv_len is not None:
+                cc, cr, cpos = cc[:, :kv_len], cr[:, :kv_len], cpos[:, :kv_len]
+        else:
+            new_cache = _fill_ring(cache, new, pos1d)
+            cc, cr, cpos = (new_cache["c_kv"], new_cache["k_rope"],
+                            new_cache["pos"])
 
         if absorbed_decode:
-            # fold W_uk into q: q_lat (B,S,H,r)
-            w_uk = p["w_uk"]["w"].reshape(m.kv_lora_rank, H, nd)
-            q_lat = jnp.einsum("bshn,rhn->bshr", q_nope.astype(jnp.float32),
-                               w_uk.astype(jnp.float32))
-            scores = jnp.einsum("bshr,bkr->bhsk", q_lat,
-                                cc.astype(jnp.float32))
-            scores += jnp.einsum("bshr,bkr->bhsk", q_rope.astype(jnp.float32),
-                                 cr.astype(jnp.float32))
-            scores = jnp.where(ok[:, None], scores * scale, NEG_INF)
-            probs = jax.nn.softmax(scores, axis=-1)
-            ctx_lat = jnp.einsum("bhsk,bkr->bshr", probs,
-                                 cc.astype(jnp.float32))       # (B,S,H,r)
-            w_uv = p["w_uv"]["w"].reshape(m.kv_lora_rank, H, vd)
-            out = jnp.einsum("bshr,rhv->bshv", ctx_lat,
-                             w_uv.astype(jnp.float32)).astype(x.dtype)
+            out = _latent_attend(
+                q_nope, q_rope, pos1d, cc, cr, cpos,
+                p["w_uk"]["w"].reshape(r, H, nd),
+                p["w_uv"]["w"].reshape(r, H, vd), scale,
+                cfg.attn_window).astype(x.dtype)
         else:
+            ok = (cpos[:, None, :] >= 0) & \
+                (cpos[:, None, :] <= pos1d[:, :, None])
+            if cfg.attn_window is not None:
+                ok &= cpos[:, None, :] > pos1d[:, :, None] - cfg.attn_window
             k_nope = dense(p["w_uk"], cc).reshape(B, -1, H, nd)
             vv = dense(p["w_uv"], cc).reshape(B, -1, H, vd)
             k_full = jnp.concatenate(
@@ -429,7 +513,6 @@ def mla_forward(p: Params, cfg: ArchConfig, x: jnp.ndarray,
                                           (B, cc.shape[1], H, rd))], axis=-1)
             q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
             out = sdpa(q_full, k_full, vv, ok, scale)
-        new_cache = {"c_kv": cc, "k_rope": cr, "pos": cpos}
         y = dense(p["wo"], out.reshape(B, S, H * vd))
         return y, new_cache
 
@@ -451,19 +534,29 @@ def mla_forward(p: Params, cfg: ArchConfig, x: jnp.ndarray,
         out = sdpa(q_full, k_full, vv, mask, scale)
     new_cache = None
     if cache is not None:
-        cc, cr, cpos = cache["c_kv"], cache["k_rope"], cache["pos"]
-        W = cc.shape[1]
-        c_w, kr_w, pos_w = c_kv, k_rope[:, :, 0], pos1d
-        if S > W:
-            c_w, kr_w, pos_w = c_w[:, -W:], kr_w[:, -W:], pos_w[:, -W:]
-        slot = (pos_w % W).astype(jnp.int32)
-        bidx = jnp.arange(B)[:, None]
-        cc = cc.at[bidx, slot].set(c_w.astype(cc.dtype))
-        cr = cr.at[bidx, slot].set(kr_w.astype(cr.dtype))
-        cpos = cpos.at[bidx, slot].set(pos_w.astype(jnp.int32))
-        new_cache = {"c_kv": cc, "k_rope": cr, "pos": cpos}
+        new_cache = (_fill_cache_paged(cache, new, pos1d, block_table)
+                     if block_table is not None
+                     else _fill_ring(cache, new, pos1d))
     y = dense(p["wo"], out.reshape(B, S, H * vd))
     return y, new_cache
+
+
+def _fill_ring(cache: Dict, new: Dict[str, jnp.ndarray], pos1d) -> Dict:
+    """Write per-position entries into a dense (ring, when windowed) cache:
+    slot ``pos % W``. When S > W only the last W positions can survive, so
+    slice before scattering (duplicate scatter indices are undefined)."""
+    W = cache["pos"].shape[1]
+    if pos1d.shape[1] > W:
+        new = {k: v[:, -W:] for k, v in new.items()}
+        pos1d = pos1d[:, -W:]
+    slot = (pos1d % W).astype(jnp.int32)
+    bidx = jnp.arange(pos1d.shape[0])[:, None]
+    out = dict(cache)
+    for name, val in new.items():
+        out[name] = cache[name].at[bidx, slot].set(val.astype(
+            cache[name].dtype))
+    out["pos"] = cache["pos"].at[bidx, slot].set(pos1d.astype(jnp.int32))
+    return out
 
 
 # =============================================================================

@@ -107,7 +107,9 @@ def sublayer_forward(p: Params, cfg: ArchConfig, x: jnp.ndarray,
         if cfg.mla is not None:
             y, new_cache = attn.mla_forward(p["attn"], cfg, h, positions, cache,
                                             absorbed_decode=cfg.mla_absorbed,
-                                            use_kernel=use_kernel)
+                                            use_kernel=use_kernel,
+                                            block_table=block_table,
+                                            kv_len=kv_len, decode=decode)
         else:
             y, new_cache = attn.gqa_forward(p["attn"], cfg, h, positions, cache,
                                             use_kernel=use_kernel,
@@ -126,7 +128,10 @@ def sublayer_forward(p: Params, cfg: ArchConfig, x: jnp.ndarray,
             new_cache["xk"], new_cache["xv"] = kv
     if "moe" in p:
         h = rmsnorm(p["ln2"], x, cfg.norm_eps)
-        y, aux = moe_mod.moe_forward(p["moe"], cfg, h, use_kernel=use_kernel)
+        # serving (a cache is threaded through) computes every routed pair;
+        # training keeps capacity-based dispatch
+        y, aux = moe_mod.moe_forward(p["moe"], cfg, h, use_kernel=use_kernel,
+                                     dropless=cache is not None)
         x = x + y
     elif "mlp" in p:
         h = rmsnorm(p["ln2"], x, cfg.norm_eps)

@@ -11,15 +11,18 @@ the sliding-window variants (DESIGN.md §4).
 
 ``spec_only=True`` mirrors the allocation with ShapeDtypeStructs for the dry-run.
 
-Paged layout (``paged=PagedLayout(...)``): GQA entries become block *pools* —
-k/v ``(n_blocks, block_size, n_kv, hd)`` plus per-slot positions
-``(n_blocks, block_size)`` — addressed through a per-sequence block table the
+Paged layout (``paged=PagedLayout(...)``): attention entries become block
+*pools* — GQA k/v ``(n_blocks, block_size, n_kv, hd)``, or MLA's latent
+``c_kv (n_blocks, block_size, kv_lora)`` + ``k_rope (n_blocks, block_size,
+rope_hd)``, plus per-slot positions ``(n_blocks, block_size)`` — addressed
+through a per-sequence block table the
 serving backend builds (`repro.serving.backend.BlockAllocator`). One logical
 block id addresses the same slot in every layer's pool, so a single table
 serves the whole stack, and the k repeated samples of one prompt can share
 physical prefix blocks (prefill once, copy-on-write at the first divergent
-token). Paged caches are supported for pure-attention GQA stacks without a
-sliding window (`paged_supported`); everything else keeps the dense layout.
+token). Paged caches are supported for pure-attention (GQA or MLA) stacks
+without a sliding window (`paged_supported`); everything else keeps the
+dense layout.
 """
 from __future__ import annotations
 
@@ -41,12 +44,11 @@ class PagedLayout:
 
 
 def paged_supported(cfg: ArchConfig) -> bool:
-    """Paged KV caching covers the GQA ring-free case: every mixer is
-    attention, no MLA latent cache, no sliding window (the ring buffer's
-    slot recycling conflicts with block-granular sharing), no cross-attention
-    conditioning memory riding in the cache."""
+    """Paged KV caching covers the ring-free attention case: every mixer is
+    attention (GQA k/v or MLA latent pools), no sliding window (the ring
+    buffer's slot recycling conflicts with block-granular sharing), no
+    cross-attention conditioning memory riding in the cache."""
     return (all(m == "a" for m in cfg.pattern)
-            and cfg.mla is None
             and cfg.attn_window is None
             and not cfg.cross_attention)
 
@@ -70,13 +72,22 @@ def _attn_entry(cfg: ArchConfig, batch: int, cache_len: int, dtype, spec_only: b
                 paged: Optional[PagedLayout] = None, kv_dtype=None):
     if paged is not None:
         el_dtype = dtype if kv_dtype is None else kv_dtype
-        shapes = {
-            "k": ((paged.n_blocks, paged.block_size, cfg.n_kv_heads, cfg.hd),
-                  el_dtype),
-            "v": ((paged.n_blocks, paged.block_size, cfg.n_kv_heads, cfg.hd),
-                  el_dtype),
-            "pos": ((paged.n_blocks, paged.block_size), jnp.int32),
-        }
+        nb, bs = paged.n_blocks, paged.block_size
+        if cfg.mla is not None:
+            if kv_dtype is not None:
+                raise ValueError("the latent (MLA) pool is stored in the "
+                                 "model dtype; kv_dtype is for GQA pools")
+            shapes = {
+                "c_kv": ((nb, bs, cfg.mla.kv_lora_rank), dtype),
+                "k_rope": ((nb, bs, cfg.mla.qk_rope_head_dim), dtype),
+                "pos": ((nb, bs), jnp.int32),
+            }
+        else:
+            shapes = {
+                "k": ((nb, bs, cfg.n_kv_heads, cfg.hd), el_dtype),
+                "v": ((nb, bs, cfg.n_kv_heads, cfg.hd), el_dtype),
+                "pos": ((nb, bs), jnp.int32),
+            }
         if el_dtype == jnp.int8:
             # int8 KV: per-(block, slot, kv-head) dequant scales
             shapes["k_scale"] = ((paged.n_blocks, paged.block_size,
@@ -163,7 +174,7 @@ def make_cache(cfg: ArchConfig, batch: int, cache_len: int, dtype=jnp.bfloat16,
     """
     if paged is not None and not paged_supported(cfg):
         raise ValueError(f"paged KV cache unsupported for arch {cfg.name!r} "
-                         "(needs all-attention pattern, no MLA, no window, "
+                         "(needs all-attention pattern, no window, "
                          "no cross-attention)")
     period = len(cfg.pattern)
     prefix = [
@@ -182,7 +193,7 @@ def copy_cache_blocks(cache: Dict, src: jnp.ndarray, dst: jnp.ndarray) -> Dict:
     pool: the copy-on-write fan-out of a shared, partially-filled prefix
     block (each repeated sample of a prompt gets a private copy of the block
     its first divergent token will land in). Only valid on paged caches
-    (every entry is a GQA pool)."""
+    (every entry is a GQA or latent pool)."""
     def cp(entry: Dict, stacked: bool) -> Dict:
         # copy every pool leaf present — int8 pools carry k_scale/v_scale too
         out = dict(entry)

@@ -31,8 +31,22 @@ class MLAConfig:
 
 
 @dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rotary scaling (DeepSeek-V2's ``rope_scaling``, type "yarn"):
+    frequencies blended between interpolated and original over a
+    correction range of dimensions, and an attention-scale factor."""
+
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+
+
+@dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int = 8
+    n_experts: int = 8             # routed experts of the model (router width)
     top_k: int = 2
     n_shared: int = 0              # shared (always-on) experts, DeepSeek-style
     d_expert: int = 0              # expert FFN hidden dim (0 -> use cfg.d_ff)
@@ -40,6 +54,17 @@ class MoEConfig:
     first_dense: int = 0           # leading dense layers before MoE starts
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # expert parallelism: this device holds routed experts
+    # [held_offset, held_offset + n_held) of the n_experts the router scores
+    # (0 = all of them) and computes only their part of the layer's output
+    n_held: int = 0
+    held_offset: int = 0
+    norm_topk_prob: bool = True    # renormalize the top-k gates to sum to 1
+    routed_scaling_factor: float = 1.0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -76,6 +101,7 @@ class ArchConfig:
     rope_variant: str = "rope"     # rope | partial | mrope | none
     rope_theta: float = 10000.0
     rope_fraction: float = 1.0     # fraction of head_dim rotated ("partial"/chatglm)
+    rope_scaling: Optional[RopeScaling] = None   # YaRN (DeepSeek-V2)
     mrope_sections: Tuple[int, ...] = ()   # qwen2-vl, sums to rotary half-dim
     # --- FFN ---
     mlp_variant: str = "swiglu"    # swiglu | gelu
@@ -96,9 +122,6 @@ class ArchConfig:
     # §Perf beyond-paper: cache the cross-attention K/V of the static
     # conditioning memory at prefill instead of re-projecting every decode step
     cross_kv_cache: bool = False
-    # §Perf beyond-paper: dense all-experts MoE for small decode batches
-    # (skips sort/scatter dispatch; exact — no capacity drops)
-    moe_dense_decode: bool = False
     # --- misc ---
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
@@ -190,6 +213,7 @@ class ArchConfig:
             kw["moe"] = dataclasses.replace(
                 self.moe,
                 n_experts=min(4, self.moe.n_experts),
+                n_held=min(self.moe.n_held, 4), held_offset=0,
                 top_k=min(2, self.moe.top_k),
                 n_shared=min(1, self.moe.n_shared),
                 d_expert=min(128, self.expert_ff()),

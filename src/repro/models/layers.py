@@ -45,6 +45,30 @@ def dense(p: Params, x: jnp.ndarray) -> jnp.ndarray:
 dense_spec = _dense_spec
 
 
+def wide_dot(op, a: jnp.ndarray, b: jnp.ndarray, **kw) -> jnp.ndarray:
+    """``op(a, b, **kw)`` (an einsum or a grouped matmul) on the inputs'
+    own dtype with float32 accumulation. XLA's CPU runtime has no bf16 x
+    bf16 -> f32 dot for some of these shapes, so there the inputs are
+    widened first, which is exact; the branch is chosen when the program
+    is lowered for its platform."""
+    f32 = jnp.float32
+
+    def widened(a, b):
+        return op(a.astype(f32), b.astype(f32), **kw)
+
+    def accumulated(a, b):
+        return op(a, b, preferred_element_type=f32, **kw)
+
+    if a.dtype == f32 and b.dtype == f32:
+        return widened(a, b)
+    return jax.lax.platform_dependent(a, b, cpu=widened, default=accumulated)
+
+
+def einsum_f32(spec: str, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """``einsum(spec, a, b)`` accumulated in float32 (`wide_dot`)."""
+    return wide_dot(lambda x, y, **kw: jnp.einsum(spec, x, y, **kw), a, b)
+
+
 # --------------------------------------------------------------------------- norms
 
 def rmsnorm_spec(d: int, dtype) -> Params:
@@ -99,25 +123,63 @@ def mlp(p: Params, x: jnp.ndarray, variant: str) -> jnp.ndarray:
 
 # --------------------------------------------------------------------------- RoPE
 
-def rope_freqs(rotary_dim: int, theta: float) -> jnp.ndarray:
-    """Inverse frequencies for half the rotary dim."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction ``0.1 * mscale * ln(factor) + 1``."""
+    return 0.1 * mscale * np.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_range(rotary_dim: int, theta: float, scaling) -> Tuple[int, int]:
+    """The dimensions [low, high] over which YaRN blends interpolated into
+    original frequencies: those that turn ``beta_fast`` and ``beta_slow``
+    times over the original context."""
+    def dim(turns):
+        return (rotary_dim * np.log(scaling.original_max_position
+                                    / (turns * 2 * np.pi))
+                / (2 * np.log(theta)))
+    low = max(int(np.floor(dim(scaling.beta_fast))), 0)
+    high = min(int(np.ceil(dim(scaling.beta_slow))), rotary_dim - 1)
+    return low, high
+
+
+def rope_freqs(rotary_dim: int, theta: float, scaling=None) -> jnp.ndarray:
+    """Inverse frequencies for half the rotary dim; with ``scaling`` (a
+    `repro.models.config.RopeScaling`), YaRN's: interpolated by ``factor``
+    below the correction range, original above it, linear between."""
     half = rotary_dim // 2
-    return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if scaling is None:
+        return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    base = 1.0 / (theta ** (np.arange(0, half, dtype=np.float64) / half))
+    low, high = yarn_range(rotary_dim, theta, scaling)
+    ramp = np.clip((np.arange(half) - low)
+                   / (high - low if high > low else 0.001), 0.0, 1.0)
+    keep = 1.0 - ramp                # 1 where the original frequency stays
+    return jnp.asarray(base / scaling.factor * (1.0 - keep) + base * keep,
+                       jnp.float32)
+
+
+def rope_mscale(scaling) -> float:
+    """Factor on cos and sin (YaRN: mscale over mscale_all_dim)."""
+    if scaling is None:
+        return 1.0
+    return (yarn_mscale(scaling.factor, scaling.mscale)
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
                rope_fraction: float = 1.0,
-               mrope_sections: Tuple[int, ...] = ()) -> jnp.ndarray:
+               mrope_sections: Tuple[int, ...] = (),
+               scaling=None) -> jnp.ndarray:
     """Rotate ``x`` (..., seq, heads, head_dim) by position embeddings.
 
     positions: (..., seq) int32 for standard rope, or (..., seq, 3) for M-RoPE
-    (temporal/height/width coordinates, qwen2-vl style).
+    (temporal/height/width coordinates, qwen2-vl style). ``scaling``: YaRN
+    frequencies and cos/sin factor (`rope_freqs`, `rope_mscale`).
     """
     hd = x.shape[-1]
     rot = int(hd * rope_fraction)
     rot -= rot % 2
     x_rot, x_pass = x[..., :rot], x[..., rot:]
-    inv = rope_freqs(rot, theta)  # (rot/2,)
+    inv = rope_freqs(rot, theta, scaling)  # (rot/2,)
 
     if mrope_sections:
         assert positions.shape[-1] == 3 and sum(mrope_sections) == rot // 2
@@ -130,6 +192,8 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
 
     cos = jnp.cos(ang)[..., None, :]  # broadcast over heads
     sin = jnp.sin(ang)[..., None, :]
+    if scaling is not None:
+        cos, sin = cos * rope_mscale(scaling), sin * rope_mscale(scaling)
     x1, x2 = jnp.split(x_rot.astype(jnp.float32), 2, axis=-1)
     y = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return jnp.concatenate([y.astype(x.dtype), x_pass], axis=-1)

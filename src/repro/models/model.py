@@ -106,7 +106,7 @@ class Model:
         ff = cfg.expert_ff()
         per_expert = 3 * cfg.d_model * ff
         n_moe_layers = sum(cfg.is_moe_layer(i) for i in range(cfg.n_layers))
-        inactive = n_moe_layers * per_expert * (m.n_experts - m.top_k)
+        inactive = n_moe_layers * per_expert * max(m.held - m.top_k, 0)
         return total - inactive
 
     # ------------------------------------------------------------------ cache
@@ -129,9 +129,12 @@ class Model:
     def forward(self, params: Dict, batch: Dict,
                 cache: Optional[Dict] = None,
                 kv_len: Optional[int] = None,
-                decode: bool = False
+                decode: bool = False, last_only: bool = False
                 ) -> Tuple[jnp.ndarray, Optional[Dict], jnp.ndarray]:
-        """Returns (logits, new_cache, aux_loss).
+        """Returns (logits, new_cache, aux_loss); with ``last_only`` the
+        logits of the last position alone (a prefill samples from no
+        other, and the head over every position of a long prompt is the
+        largest buffer of its step).
 
         ``batch["block_table"]`` switches attention caching to the paged
         layout (prefill: one row per unique prompt; decode: one row per
@@ -226,6 +229,8 @@ class Model:
             new_cache = {"prefix": new_prefix, "blocks": new_blocks}
 
         aux_total = aux_total + aux_s
+        if last_only:
+            h = h[:, -1:]
         h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
         if cfg.tie_embeddings:
             table = params["embed"]["table"]

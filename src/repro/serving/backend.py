@@ -448,6 +448,8 @@ class ExecutionBackend:
                  prefill_chunk: Optional[int] = None):
         self.model = model
         self.params = params
+        # what a pool entry holds: MLA's latent and rope key, else k/v
+        self.cache_kind = "latent" if model.cfg.mla is not None else "kv"
         self.eos_token = eos_token
         self.max_slots = max_slots
         self.slots_in_use = 0
@@ -618,7 +620,8 @@ class ExecutionBackend:
         batch = {"tokens": tokens, **extras}
         if block_table is not None:
             batch["block_table"] = block_table
-        logits, cache, _ = self.model.forward(params, batch, cache)
+        logits, cache, _ = self.model.forward(params, batch, cache,
+                                              last_only=True)
         if copy_src is not None:
             # CoW fan-out of the shared partial prefix block: fused into the
             # prefill step so the batch is decode-ready in one dispatch
@@ -647,7 +650,8 @@ class ExecutionBackend:
         b = {"tokens": tokens, "positions": pos, "block_table": block_table,
              **extras}
         logits, cache, _ = self.model.forward(params, b, cache,
-                                              kv_len=kv_len, decode=True)
+                                              kv_len=kv_len, decode=True,
+                                              last_only=True)
         return logits[:, -1], cache
 
     def _decode_step(self, params, tok, step_pos, cache, rng, temperature,
@@ -765,7 +769,8 @@ class ExecutionBackend:
         return self.spec_n + 1 if self.spec_policy is not None else 0
 
     def request_blocks(self, plen: int, max_new: int, n_samples: int,
-                       prompt: Optional[np.ndarray] = None) -> int:
+                       prompt: Optional[np.ndarray] = None,
+                       charged: Optional[set] = None) -> int:
         """Block cost of a request at shared-prefix price: the full prefix
         blocks once, plus per-sample privates (the CoW copy of a partial
         prefix block and the decode blocks). Mirrors `build_paged_layout`
@@ -780,7 +785,10 @@ class ExecutionBackend:
         removes it from the evictable headroom `capacity_free` counted.
         (Pricing idle hits free double-counts them against that headroom:
         admission could pass while the execution-time eviction loop finds
-        the hits it needs to reclaim pinned under itself.) Under
+        the hits it needs to reclaim pinned under itself.) An idle hit is
+        charged once a batch: ``charged`` holds the idle blocks already
+        priced for the batch being formed (the first request that hits
+        them pins them for the rest), and gains this request's. Under
         ``pool_evict="off"`` there is no evictable headroom to consume, so
         every hit is free and the price is the pure tail. The lookup is
         LRU-neutral (``touch=False``)."""
@@ -795,27 +803,34 @@ class ExecutionBackend:
             if self.prefix_pool.evict_policy == "off":
                 free_hits = len(chain)
             else:
-                free_hits = sum(1 for g in chain
-                                if self.allocator.refcount(g) >= 2)
+                idle = {int(g) for g in chain
+                        if self.allocator.refcount(g) < 2}
+                if charged is not None:
+                    idle -= charged
+                    charged |= idle
+                free_hits = len(chain) - len(idle)
             shared = full_prefix - free_hits
         return shared + n_samples * (n_logical - full_prefix)
 
     def request_cost(self, plen: int, max_new: int, n_samples: int,
-                     prompt: Optional[np.ndarray] = None) -> int:
+                     prompt: Optional[np.ndarray] = None,
+                     charged: Optional[set] = None) -> int:
         """Admission cost in ``capacity_free`` units (blocks or slots)."""
         if self.allocator is not None:
             return self.request_blocks(plen, max_new, n_samples,
-                                       prompt=prompt)
+                                       prompt=prompt, charged=charged)
         return n_samples
 
     def marginal_request_cost(self, prompt: np.ndarray, max_new: int,
-                              n_samples: int) -> int:
+                              n_samples: int,
+                              charged: Optional[set] = None) -> int:
         """Post-dedup admission price of one request (the scheduler's
         per-batch budget check): identical to `request_cost` without a pool,
         cheaper by the already-pinned trie prefix blocks with one (see
-        `request_blocks` for why idle hits still charge under LRU)."""
+        `request_blocks` for why idle hits still charge under LRU, once a
+        batch given the batch's ``charged`` set)."""
         return self.request_cost(len(prompt), max_new, n_samples,
-                                 prompt=np.asarray(prompt))
+                                 prompt=np.asarray(prompt), charged=charged)
 
     def _max_hit(self, plen: int) -> int:
         """Cap on trie-reusable full prefix blocks: at least one prompt
@@ -907,7 +922,7 @@ class ExecutionBackend:
         self._prefills += 1
         # the batch id comes from the scheduler via tracer.batch_context
         with tracer.span("prefill", step_num=self._prefills, plen=plen,
-                         n_sequences=B) as span:
+                         n_sequences=B, cache=self.cache_kind) as span:
             if self.prefix_pool is not None:
                 h = self._start_batch_pooled(prompts, repeats, rep, base, B,
                                              plen, max_new, temperature, rng,
@@ -960,7 +975,7 @@ class ExecutionBackend:
         out ``rep`` ways unless None): ``(rng, tok, lp)``, on the device.
         One sampler for every start path keeps their first tokens
         bit-identical."""
-        with self.obs.tracer.span("prefill.dispatch"):
+        with self.obs.tracer.span("prefill.dispatch", cache=self.cache_kind):
             rng, sub = jax.random.split(rng)
             lf = last_logits.astype(jnp.float32)
             if rep is not None:
@@ -975,7 +990,7 @@ class ExecutionBackend:
 
     def _fetch_first(self, h: InFlightBatch) -> None:
         """Bring the first sampled token and log-prob to the host."""
-        with self.obs.tracer.span("prefill.fetch"):
+        with self.obs.tracer.span("prefill.fetch", cache=self.cache_kind):
             h.out_toks = [np.asarray(h.tok)]
             h.out_lps = [np.asarray(h.out_lps[0])]
 
@@ -1008,7 +1023,7 @@ class ExecutionBackend:
         tiled_extras = {k: jnp.repeat(jnp.asarray(v), rep, axis=0)
                         for k, v in extras.items()}
 
-        with self.obs.tracer.span("prefill.dispatch"):
+        with self.obs.tracer.span("prefill.dispatch", cache=self.cache_kind):
             cache = self.model.init_cache(B,
                                           plen + max_new + self._spec_slack())
             last_logits, cache = self._prefill_jit(
@@ -1036,7 +1051,7 @@ class ExecutionBackend:
         layout = build_paged_layout(self.allocator, plen,
                                     max_new + self._spec_slack(), repeats)
         try:
-            with self.obs.tracer.span("prefill.dispatch"):
+            with self.obs.tracer.span("prefill.dispatch", cache=self.cache_kind):
                 cache = self.model.init_paged_cache(
                     layout.n_pool_blocks, layout.block_size,
                     kv_dtype=jnp.int8 if self.kv_format == "int8" else None)
@@ -1068,7 +1083,7 @@ class ExecutionBackend:
                         last_rows=[None] * R, rep=rep, mc=mc,
                         full_prefix=0))
             has_cow = layout.copy_src.size > 0
-            with self.obs.tracer.span("prefill.dispatch"):
+            with self.obs.tracer.span("prefill.dispatch", cache=self.cache_kind):
                 last_logits, cache = self._prefill_jit(
                     self.params, jnp.asarray(base), cache, prefill_extras,
                     jnp.asarray(layout.prefill_table),
@@ -1120,7 +1135,7 @@ class ExecutionBackend:
         R = len(prompts)
         full_prefix = plen // bs
         tracer = self.obs.tracer
-        with tracer.span("pool.match"):
+        with tracer.span("pool.match", cache=self.cache_kind):
             # 1. acquire hit chains first: refs pin them, so the eviction
             #    pass below can never reclaim a block this batch just resolved
             hit_chains = [pool.acquire(p, self._max_hit(plen), holders=k)
@@ -1156,11 +1171,14 @@ class ExecutionBackend:
             for i, c in enumerate(layout.hit_counts):
                 groups.setdefault(c, []).append(i)
         try:
-            with tracer.span("prefill.dispatch"):
-                cache = self._ensure_pool_cache()
+            with tracer.span("prefill.dispatch", cache=self.cache_kind):
+                # each step's output pool replaces the resident one at
+                # once, so a step holds two pools (its input and output),
+                # never a third
+                self._ensure_pool_cache()
                 if fresh:
-                    cache = self._reset_blocks_jit(
-                        cache, jnp.asarray(fresh, jnp.int32))
+                    self._pool_cache = self._reset_blocks_jit(
+                        self._pool_cache, jnp.asarray(fresh, jnp.int32))
                 prefill_extras = {k: jnp.asarray(v)
                                   for k, v in extras.items()}
                 decode_extras = {k: jnp.repeat(jnp.asarray(v), rep, axis=0)
@@ -1177,7 +1195,6 @@ class ExecutionBackend:
                             e = min(s + self.prefill_chunk, plen)
                             jobs.append((idxs, s, e))
                             s = e
-                    self._pool_cache = cache
                     tail_tokens = sum(plen - c * bs
                                       for c in layout.hit_counts)
                     return InFlightBatch(
@@ -1197,9 +1214,9 @@ class ExecutionBackend:
                 #    depth
                 last_rows: List[Any] = [None] * R
                 for c, idxs in sorted(groups.items()):
-                    gl, cache = self._tail_prefill_jit(
+                    gl, self._pool_cache = self._tail_prefill_jit(
                         self.params, jnp.asarray(base[idxs][:, c * bs:]),
-                        jnp.asarray(c * bs, jnp.int32), cache,
+                        jnp.asarray(c * bs, jnp.int32), self._pool_cache,
                         {k: v[jnp.asarray(idxs)]
                          for k, v in prefill_extras.items()},
                         jnp.asarray(layout.prefill_table[idxs]),
@@ -1211,8 +1228,8 @@ class ExecutionBackend:
                     # CoW fan-out of the shared partial prefix block
                     # (partials are never pool-shared — same schedule as
                     # per-batch paging)
-                    cache = self._copy_blocks_jit(
-                        cache, jnp.asarray(layout.copy_src),
+                    self._pool_cache = self._copy_blocks_jit(
+                        self._pool_cache, jnp.asarray(layout.copy_src),
                         jnp.asarray(layout.copy_dst))
         except BaseException:
             # every reference the batch took (hits included — their holder
@@ -1222,7 +1239,6 @@ class ExecutionBackend:
                 for g in gids:
                     alloc.free(g)
             raise
-        self._pool_cache = cache
         # 5. index the freshly filled full-prefix chains (post-success: the
         #    trie must never point at unfilled blocks). A same-prefix
         #    sibling within this batch keeps the first writer's blocks.
@@ -1259,7 +1275,7 @@ class ExecutionBackend:
                          chunk=[s, e]):
             pooled = h.cache is None
             cache = self._pool_cache if pooled else h.cache
-            with tracer.span("prefill.dispatch"):
+            with tracer.span("prefill.dispatch", cache=self.cache_kind):
                 gl, cache = self._tail_prefill_jit(
                     self.params, jnp.asarray(pp.base[idxs][:, s:e]),
                     jnp.asarray(s, jnp.int32), cache,
@@ -1287,7 +1303,7 @@ class ExecutionBackend:
         pooled = h.cache is None
         cache = self._pool_cache if pooled else h.cache
         if layout.copy_src.size > 0:
-            with self.obs.tracer.span("prefill.dispatch"):
+            with self.obs.tracer.span("prefill.dispatch", cache=self.cache_kind):
                 cache = self._copy_blocks_jit(cache,
                                               jnp.asarray(layout.copy_src),
                                               jnp.asarray(layout.copy_dst))
@@ -1299,7 +1315,7 @@ class ExecutionBackend:
                         layout.prefill_table[i][:pp.full_prefix]])
         else:
             h.cache = cache
-        with self.obs.tracer.span("prefill.dispatch"):
+        with self.obs.tracer.span("prefill.dispatch", cache=self.cache_kind):
             last_logits = jnp.stack(pp.last_rows, axis=0)
         h.rng, h.tok, lp = self._sample_first(last_logits, h.rng,
                                               h.temperature, pp.rep, pp.mc)
@@ -1365,8 +1381,8 @@ class ExecutionBackend:
         tracer = self.obs.tracer
         mc = self._multi_codebook
         with tracer.span("decode", step_num=h.step,
-                         n_sequences=h.n_sequences):
-            with tracer.span("decode.dispatch"):
+                         n_sequences=h.n_sequences, cache=self.cache_kind):
+            with tracer.span("decode.dispatch", cache=self.cache_kind):
                 h.rng, sub = jax.random.split(h.rng)
                 step_pos = jnp.asarray(h.plen + h.step - 1, jnp.int32)
                 tok_in = h.tok[:, None] if not mc else h.tok[:, None, :]
@@ -1383,7 +1399,7 @@ class ExecutionBackend:
                 self._pool_cache = cache
             else:
                 h.cache = cache
-            with tracer.span("decode.fetch"):
+            with tracer.span("decode.fetch", cache=self.cache_kind):
                 h.out_toks.append(np.asarray(h.tok))
                 h.out_lps.append(np.asarray(lp))
             h.step += 1
@@ -1417,7 +1433,7 @@ class ExecutionBackend:
                 raise ValueError(f"draft policy {sp.policy.name!r} returned "
                                  f"shape {drafts.shape}, expected {(B, n)}")
             with tracer.span("verify", n=n, n_sequences=B) as vspan:
-                with tracer.span("decode.dispatch"):
+                with tracer.span("decode.dispatch", cache=self.cache_kind):
                     h.rng, sub = jax.random.split(h.rng)
                     last = np.asarray([row[-1] for row in sp.toks],
                                       np.int32)
@@ -1438,7 +1454,7 @@ class ExecutionBackend:
                     self._pool_cache = cache
                 else:
                     h.cache = cache
-                with tracer.span("decode.fetch"):
+                with tracer.span("decode.fetch", cache=self.cache_kind):
                     accept_len = np.asarray(accept_len)
                     out_tokens = np.asarray(out_tokens)
                     out_lps = np.asarray(out_lps)

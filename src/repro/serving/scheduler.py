@@ -661,19 +661,23 @@ class ContinuousBatchingScheduler:
             cap = getattr(self.backend, "max_slots", None)
         return cap
 
-    def _request_cost(self, req: ServeRequest) -> int:
+    def _request_cost(self, req: ServeRequest,
+                      charged: Optional[set] = None) -> int:
         # marginal (post-dedup) pricing: a backend with a resident prefix
         # pool charges only the tail blocks a request would actually
         # allocate — its trie-cached prefix is free — so cache-hot requests
         # admit cheaply and the block budget reflects real memory. A
         # resumed request prices each sample's own history (divergent after
         # the original prompt); its parked chains make those near-free.
+        # ``charged`` (one set per batch formed) prices an idle cached
+        # prefix once for every request of the batch that hits it.
         mrc = getattr(self.backend, "marginal_request_cost", None)
         if mrc is not None:
             if req.resume is not None:
-                return sum(mrc(p, req.remaining_new, 1)
+                return sum(mrc(p, req.remaining_new, 1, charged=charged)
                            for p in req.resume.prompts)
-            return mrc(req.prompt, req.max_new_tokens, req.n_samples)
+            return mrc(req.prompt, req.max_new_tokens, req.n_samples,
+                       charged=charged)
         rc = getattr(self.backend, "request_cost", None)
         if rc is None:
             return req.n_samples
@@ -985,9 +989,11 @@ class ContinuousBatchingScheduler:
             free = self._capacity_free()
             if free is not None and free <= 0:
                 return None
-            reqs = self.queue.pop_batch(self.config.max_batch_requests, free,
-                                        self._request_cost, now=self.clock,
-                                        bucket=bucket)
+            charged: set = set()
+            reqs = self.queue.pop_batch(
+                self.config.max_batch_requests, free,
+                lambda r: self._request_cost(r, charged), now=self.clock,
+                bucket=bucket)
             if not reqs:
                 return None
             # extras compatibility: one batch stacks one set of per-request

@@ -95,7 +95,9 @@ def test_reduced_decode_step(arch):
 
 def test_full_configs_match_assignment_sheet():
     sheet = {
-        "deepseek-v2-lite-16b": (27, 2048, 16, 16, 1408, 102400),
+        # published config.json: dense intermediate_size 10944 (layer 0);
+        # the experts' moe_intermediate_size 1408 is pinned below
+        "deepseek-v2-lite-16b": (27, 2048, 16, 16, 10944, 102400),
         "chatglm3-6b": (28, 4096, 32, 2, 13696, 65024),
         "qwen2-vl-7b": (28, 3584, 28, 4, 18944, 152064),
         "jamba-v0.1-52b": (32, 4096, 32, 8, 14336, 65536),
@@ -110,6 +112,8 @@ def test_full_configs_match_assignment_sheet():
         cfg = get_config(arch)
         assert (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                 cfg.d_ff, cfg.vocab_size) == (L, d, H, kv, ff, V), arch
+    ds = get_config("deepseek-v2-lite-16b")
+    assert (ds.expert_ff(), ds.norm_eps) == (1408, 1e-6)
 
 
 def test_param_counts_near_nameplate():

@@ -1,5 +1,5 @@
 """Beyond-paper decode optimizations must be exact (EXPERIMENTS.md §Perf):
-cross-attention K/V caching and dense all-experts MoE decode."""
+cross-attention K/V caching and the dense pass over the held experts."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -34,15 +34,18 @@ def test_cross_kv_cache_decode_matches_recompute():
 
 
 def test_moe_dense_decode_matches_dispatch():
+    """The serving layer's dense pass over the held experts (a handful of
+    tokens) equals capacity dispatch wherever no pair is dropped."""
+    from repro.models import moe
     mo = ArchConfig(name="g", arch_type="moe", n_layers=2, d_model=64,
                     n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=97,
                     moe=MoEConfig(n_experts=4, top_k=2, capacity_factor=8.0))
-    md = mo.with_overrides(moe_dense_decode=True)
-    m0, m1 = Model(mo, dtype=jnp.float32), Model(md, dtype=jnp.float32)
-    params = m0.init(jax.random.key(0))
-    toks = jax.random.randint(jax.random.key(1), (2, 16), 0, 97)
-    l0, _, a0 = m0.forward(params, {"tokens": toks})
-    l1, _, a1 = m1.forward(params, {"tokens": toks})
-    np.testing.assert_allclose(np.asarray(l0), np.asarray(l1),
+    params = Model(mo, dtype=jnp.float32).init(jax.random.key(0))
+    p = jax.tree.map(lambda a: a[0], params["blocks"]["l0"]["moe"])
+    x = jax.random.normal(jax.random.key(1), (2, 16, 64), jnp.float32)
+    y0, a0 = moe.moe_forward(p, mo, x)
+    y1, a1 = moe.moe_forward(p, mo, x, dropless=True)
+    assert 2 * 16 <= moe.DENSE_TOKENS
+    np.testing.assert_allclose(np.asarray(y0), np.asarray(y1),
                                rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(float(a0), float(a1), rtol=1e-5)

@@ -316,6 +316,31 @@ def test_paged_kernel_matches_ref_oracle():
                                rtol=1e-5, atol=1e-5)
 
 
+def test_pooled_prefill_in_query_blocks_matches_one_block(model_params,
+                                                          monkeypatch):
+    """A prefill against the pool whose scores would exceed
+    `attention.CACHE_SCORES` runs its queries in blocks; each query's row
+    is the same computation, so the served tokens agree and their
+    log-probabilities agree to float32 rounding."""
+    from repro.models import attention
+    model, params = model_params
+    prompts = [_prompt(13), _prompt(13, mult=3)]
+
+    def serve():
+        be = ExecutionBackend(model, params, kv_blocks=64, kv_block_size=4,
+                              kv_pool=True)
+        return _generate(be, prompts, 2, 5, seed=3)[0]
+
+    whole = serve()
+    monkeypatch.setattr(attention, "CACHE_SCORES", 64)
+    blocked = serve()
+    for a, b in zip(whole, blocked):
+        for s1, s2 in zip(a.samples, b.samples):
+            np.testing.assert_array_equal(s1, s2)
+        np.testing.assert_allclose(a.logprobs, b.logprobs, rtol=0,
+                                   atol=1e-5)
+
+
 def test_make_cache_rejects_unsupported_paged_archs():
     windowed = CFG.with_overrides(attn_window=8)
     assert not paged_supported(windowed)

@@ -409,6 +409,38 @@ def test_marginal_cost_free_only_for_pinned_hits(model_params):
     assert be.capacity_free == 32
 
 
+def test_idle_hits_are_charged_once_a_batch(model_params):
+    """Requests of one batch that hit the same idle cached prefix pay for
+    it once: the first pins it for the others. A budget that holds the
+    prefix once plus every request's tail admits them all in one batch;
+    priced one by one, each request paid for the prefix again."""
+    model, params = model_params
+    be = ExecutionBackend(model, params, kv_blocks=32, kv_block_size=BS,
+                          kv_pool=True)
+    p = _prompt(16)
+    h = be.start_batch([p], 1, 3, 0.0, jax.random.key(0))
+    while be.decode_step(h):
+        pass
+    be.finalize(h)
+    full = be.request_cost(16, 3, 1)
+    assert be.marginal_request_cost(p, 3, 1) == full      # idle: charged
+    charged: set = set()
+    first = be.marginal_request_cost(p, 3, 1, charged=charged)
+    assert first == full and len(charged) == 3
+    assert be.marginal_request_cost(p, 3, 1, charged=charged) == full - 3
+
+    sched = ContinuousBatchingScheduler(
+        be, _StubRouter(["economy"]),
+        SchedulerConfig(max_batch_requests=4, max_new_tokens=3))
+    for _ in range(4):
+        assert sched.submit(p, tier="economy", n_samples=1).admitted
+    # room for the prefix once and four tails, not for it four times
+    sched.kv_reserve = be.capacity_free - (full + 3 * (full - 3))
+    assert 4 * full > be.capacity_free - sched.kv_reserve
+    entry = sched._form_batch()
+    assert entry is not None and len(entry.requests) == 4
+
+
 def test_scheduler_prices_marginally_and_records_pool_fields(model_params):
     from repro.qeil2 import TraceStore
 

@@ -229,3 +229,43 @@ def test_int8_model_reads_weight_stacks_in_place(one_chip, monkeypatch):
                                     "bitcast", "tuple", "while"}, sorted(s8)
     calls = re.findall(r"%(\S+) = \S+ custom-call\(([^)]*)\)", hlo)
     assert sum("dequant_matmul" in name for name, _ in calls) == 7
+
+
+def test_deepseek_share_decode_fits_with_its_pool(one_chip):
+    """The DeepSeek-V2-Lite share's pooled decode step at its cell's size
+    (16 sequences over 16,608 positions of the 4,544-block latent pool):
+    the weights, the pool and the step's output pool (nothing donates it)
+    and its temporaries fit the chip; the gathered latent context is
+    made in bf16, and no float32 copy of it is made."""
+    import types
+
+    from repro.serving.backend import ExecutionBackend
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    model = Model(get_config("deepseek-v2-lite-16b-ep4"), dtype=BF)
+    params = on_chip(model.param_specs())
+    cache = on_chip(model.init_paged_cache(4544, 16, spec_only=True))
+    B, blocks, K = 16, 1038, 16608
+
+    def i32(*s):
+        return jax.ShapeDtypeStruct(s, I32, sharding=one_chip)
+
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    step = jax.jit(functools.partial(ExecutionBackend._decode_step,
+                                     types.SimpleNamespace(model=model)),
+                   static_argnames=("kv_len", "greedy"))
+    compiled = step.lower(
+        params, i32(B, 1), i32(), cache,
+        jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((), F32, sharding=one_chip), {},
+        i32(B, blocks), kv_len=K).compile()
+    mem = compiled.memory_analysis()
+    nbytes = [sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(t))
+              for t in (params, cache)]
+    assert sum(nbytes) + nbytes[1] + mem.temp_size_in_bytes < HBM_BYTES
+    hlo = compiled.as_text()
+    assert f"bf16[{B},{K},512]" in hlo
+    assert f"f32[{B},{K},512]" not in hlo
