@@ -48,3 +48,29 @@ def paged_decode_attention_ref(q: jnp.ndarray, k_pool: jnp.ndarray,
     if kv_len is not None:
         kc, vc, pc = kc[:, :kv_len], vc[:, :kv_len], pc[:, :kv_len]
     return decode_attention_ref(q, kc, vc, pc, q_pos, scale=scale)
+
+
+def paged_latent_decode_ref(ql: jnp.ndarray, qr: jnp.ndarray,
+                            c_kv: jnp.ndarray, k_rope: jnp.ndarray,
+                            pos: jnp.ndarray, block_table: jnp.ndarray,
+                            q_pos: jnp.ndarray, *,
+                            scale: float) -> jnp.ndarray:
+    """Oracle for the paged latent decode kernel, in float32: gather each
+    sequence's latent, rope key and positions in logical order, score
+    ``ql . c + qr . k_rope`` over the slots with ``0 <= pos <= q_pos``, and
+    return the softmax-weighted latent (B, H, r) in the pool's dtype.
+    Masked slots contribute nothing, whatever they hold."""
+    B = ql.shape[0]
+    hi = jax.lax.Precision.HIGHEST
+    c = c_kv[block_table].reshape(B, -1, c_kv.shape[-1]).astype(jnp.float32)
+    kr = k_rope[block_table].reshape(B, -1, k_rope.shape[-1]
+                                     ).astype(jnp.float32)
+    p = pos[block_table].reshape(B, -1)
+    valid = (p >= 0) & (p <= q_pos[:, None])
+    c = jnp.where(valid[..., None], c, 0.0)
+    kr = jnp.where(valid[..., None], kr, 0.0)
+    s = (jnp.einsum("bhr,bkr->bhk", ql.astype(jnp.float32), c, precision=hi)
+         + jnp.einsum("bhr,bkr->bhk", qr.astype(jnp.float32), kr,
+                      precision=hi)) * scale
+    w = jax.nn.softmax(jnp.where(valid[:, None], s, NEG_INF), axis=-1)
+    return jnp.einsum("bhk,bkr->bhr", w, c, precision=hi).astype(c_kv.dtype)

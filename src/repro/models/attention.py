@@ -3,7 +3,8 @@ cross-attention (musicgen), with unified train / prefill / decode entry points.
 
 The jnp reference path here is the semantics oracle; the Pallas kernels in
 ``repro.kernels`` implement the same math for the TPU hot path (``use_kernel`` flag in
-ops wrappers selects them; on CPU the reference path runs).
+ops wrappers selects them; on CPU the reference path runs). Absorbed MLA decode over a
+paged latent pool always runs its kernel (interpreted on the CPU).
 
 Cache layouts (see repro.models.cache):
   * GQA:    k,v            (B, S_cache, n_kv, hd)        ring-buffered when windowed
@@ -440,6 +441,62 @@ def _latent_attend(q_nope, q_rope, qpos, cc, cr, cpos, w_uk, w_uv,
     return in_query_blocks(attend, H * K, q_nope, q_rope, qpos)
 
 
+def paged_latent_kernel_runs(cfg: ArchConfig, S: int, block_table,
+                             absorbed: bool) -> bool:
+    """Whether an attention call runs the paged latent decode kernel: MLA,
+    one absorbed decode query a sequence over paged pools, no window."""
+    return (cfg.mla is not None and block_table is not None and S == 1
+            and absorbed and cfg.attn_window is None)
+
+
+# Key under which a scanned layer's latent pool dict carries the kernel's
+# view of the whole pool stacks and its layer index (`bind_latent_views`).
+RESIDENT = "resident"
+
+
+def _latent_view(pools: Dict, block_table):
+    """The paged latent decode kernel's view of stacked latent pools."""
+    from repro.kernels.decode_attention import ops as da_ops
+    return da_ops.paged_latent_pool_view(pools["c_kv"], pools["k_rope"],
+                                         pools["pos"], block_table)
+
+
+def latent_views(blocks: Dict, block_table) -> Dict:
+    """The kernel's view of each latent pool stack of a scanned model's
+    cache (``cache["blocks"]``), made once for the step's layers: the
+    kernel reads the stacks in place (a layer's slice of a pool is a scan
+    temporary that XLA stages in VMEM)."""
+    return {k: _latent_view(v, block_table) for k, v in blocks.items()
+            if isinstance(v, dict) and "c_kv" in v}
+
+
+def bind_latent_views(sub: Dict, views: Dict, layer) -> Dict:
+    """One scanned super-block's slice of a paged cache, each latent pool
+    dict given its stack's `latent_views` entry and ``layer``."""
+    return {k: {**v, RESIDENT: (views[k], layer)} if k in views else v
+            for k, v in sub.items()}
+
+
+def _paged_latent_decode(q_nope, q_rope, qpos, new, view, layer,
+                         block_table, w_uk, w_uv,
+                         scale: float) -> jnp.ndarray:
+    """`_latent_attend` of one decode query a sequence (S == 1), without a
+    window, by the Pallas kernel: layer ``layer`` of the paged latent
+    pools' ``view`` (before this step's write), read in place through
+    ``block_table`` (no gathered copy of the context), and the token
+    ``new`` the step writes at ``qpos``. Returns (B, 1, H, vd) in the
+    pools' dtype."""
+    from repro.kernels.decode_attention import ops as da_ops
+    dt = view[0].dtype
+    ql = einsum_f32("bshn,rhn->bshr", q_nope, w_uk).astype(dt)[:, 0]
+    with jax.named_scope("mla_attend"):
+        ctx = da_ops.paged_latent_decode_attention(
+            ql, q_rope[:, 0].astype(dt), new["c_kv"][:, 0].astype(dt),
+            new["k_rope"][:, 0].astype(dt), view, layer, block_table,
+            qpos[:, 0], scale=scale)
+    return einsum_f32("bhr,rhv->bhv", ctx, w_uv).astype(dt)[:, None]
+
+
 def mla_forward(p: Params, cfg: ArchConfig, x: jnp.ndarray,
                 positions: jnp.ndarray,
                 cache: Optional[Dict] = None,
@@ -460,8 +517,11 @@ def mla_forward(p: Params, cfg: ArchConfig, x: jnp.ndarray,
     verify) writes its latents first, then attends in latent space
     (W_uk folded into q, W_uv applied to the latent context), so the bytes
     read per step are O(S·(kv_lora + rope_hd)) instead of O(S·2·H·hd).
-    With ``block_table`` the cache entries are block pools, read by
-    gathering each sequence's blocks in logical order up to ``kv_len``.
+    With ``block_table`` the cache entries are block pools. An absorbed
+    decode step (S == 1, no window) reads them in place through the table
+    (`repro.kernels.decode_attention.paged_latent`); every other
+    cache-attending call gathers each sequence's blocks in logical order up
+    to ``kv_len``.
     """
     m = cfg.mla
     B, S, _ = x.shape
@@ -481,10 +541,24 @@ def mla_forward(p: Params, cfg: ArchConfig, x: jnp.ndarray,
     k_rope = apply_rope(k_rope, pos1d, cfg.rope_theta,
                         scaling=cfg.rope_scaling)
     new = {"c_kv": c_kv, "k_rope": k_rope[:, :, 0]}
+    resident = None
+    if cache is not None and RESIDENT in cache:
+        cache = dict(cache)
+        resident = cache.pop(RESIDENT)
 
     if cache is not None and (S == 1 or decode):
         if block_table is not None:
             new_cache = _fill_cache_paged(cache, new, pos1d, block_table)
+            if paged_latent_kernel_runs(cfg, S, block_table,
+                                        absorbed_decode):
+                view, layer = resident or (_latent_view(
+                    {k: v[None] for k, v in cache.items()}, block_table), 0)
+                out = _paged_latent_decode(
+                    q_nope, q_rope, pos1d, new, view, layer, block_table,
+                    p["w_uk"]["w"].reshape(r, H, nd),
+                    p["w_uv"]["w"].reshape(r, H, vd), scale).astype(x.dtype)
+                y = dense(p["wo"], out.reshape(B, S, H * vd))
+                return y, new_cache
             cc = new_cache["c_kv"][block_table].reshape(B, -1, r)
             cr = new_cache["k_rope"][block_table].reshape(B, -1, rd)
             cpos = new_cache["pos"][block_table].reshape(B, -1)

@@ -18,6 +18,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.models import attention as attn_mod
 from repro.models import blocks as blk
 from repro.models import cache as cache_mod
 from repro.models.config import ArchConfig
@@ -216,9 +217,17 @@ class Model:
                                          (blocks, layers))
             new_cache = None
         else:
+            # the paged latent decode kernel reads the pool stacks in place
+            views = {}
+            if attn_mod.paged_latent_kernel_runs(cfg, S, block_table,
+                                                 cfg.mla_absorbed):
+                views = attn_mod.latent_views(cache["blocks"], block_table)
+
             def body(carry, inp):
                 x, aux = carry
                 bp, i, bc = inp
+                if views:
+                    bc = attn_mod.bind_latent_views(bc, views, i)
                 x2, nc, a = sb_fwd(bind_int8_stacks(bp, stacks, i), x=x,
                                    cache=bc)
                 return (x2, aux + a), nc

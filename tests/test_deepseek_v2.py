@@ -21,6 +21,7 @@ from chipbench.reference import deepseek_v2 as ref  # noqa: E402
 from small_deepseek import small_arch, small_config  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
+from repro.kernels.decode_attention import paged_latent  # noqa: E402
 from repro.models import Model  # noqa: E402
 from repro.models import attention, moe  # noqa: E402
 from repro.models.layers import rope_freqs, rope_mscale, yarn_range  # noqa: E402
@@ -78,12 +79,25 @@ def _serve(model, params):
 TOL = {"float32": 1e-4, "bfloat16": 0.15}
 
 
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_served_logits_match_the_reference(dtype):
+@pytest.mark.parametrize("dtype,chunk", [
+    pytest.param("float32", None, id="float32"),
+    pytest.param("bfloat16", None, id="bfloat16"),
+    # decode reads the latent pool in chunks of 2 blocks (8 positions): its
+    # steps cross block boundaries (28, 32, 36) and a chunk boundary (32)
+    pytest.param("float32", 2, id="float32-chunk2")])
+def test_served_logits_match_the_reference(dtype, chunk, monkeypatch):
+    """Every served token's log-probability, decode through the paged
+    latent decode kernel, against the reference."""
+    if chunk is not None:
+        monkeypatch.setattr(paged_latent, "CHUNK_BLOCKS", chunk)
+    kernel, traced = paged_latent.paged_latent_decode_pallas, []
+    monkeypatch.setattr(paged_latent, "paged_latent_decode_pallas",
+                        lambda *a, **kw: traced.append(1) or kernel(*a, **kw))
     conf = _conf()
     model, params = _program(conf, jnp.dtype(dtype))
     items, hits = _serve(model, params)
     assert hits == [0, 5]
+    assert traced                 # decode attended through the kernel
     got = ref.readings(conf, SEED, [(s, f) for s, f, _ in items], "bf16")
     err = max(float(np.abs(g["lp"] - lp).max())
               for g, (_, _, lp) in zip(got, items))

@@ -108,6 +108,115 @@ def test_decode_attention_ring_semantics():
                                rtol=1e-5, atol=1e-5)
 
 
+# ======================================================= paged latent decode
+# Each case builds one layer's latent pool (block size 4) as serving leaves
+# it before a decode step, the step's token, and the pool after its write.
+LATENT_CASES = {
+    # q_pos differs across the batch, last blocks partly filled; 3-block
+    # chunks, so contexts span several chunks and the last chunk runs past
+    # the table's end
+    "ragged": dict(q_pos=[5, 18, 27, 9], chunk=3),
+    # rows 0-2 share 3 prefix blocks of one prompt (14 tokens), each with
+    # its copy-on-write copy of the partial 4th block; blocks handed out
+    # in ascending order, so runs of consecutive blocks are copied whole
+    "shared_prefix": dict(q_pos=[17, 20, 15, 6], chunk=3, run=2, shared=3,
+                          consecutive=True),
+    # empty slots (pos -1) inside the context, and row 0's slot at q_pos
+    # holding a stale entry at q_pos itself (a rejected draft)
+    "empty_slots": dict(q_pos=[13, 22, 7, 30], chunk=2, holes=True),
+    # tables 4 blocks longer than any context: blocks of NaN latents that
+    # must be neither read nor computed, one of them in a chunk the
+    # context ends in, right after the context's consecutive blocks
+    "long_table": dict(q_pos=[5, 18, 30, 9], chunk=3, run=3, spare=4,
+                       consecutive=True),
+    # every context in one chunk of the whole table, of consecutive blocks
+    # copied in runs of 3, 3 and 2
+    "one_chunk": dict(q_pos=[5, 18, 29, 9], chunk=8, run=3,
+                      consecutive=True),
+}
+
+
+def _latent_case(case, dtype, B=4, H=4, r=32, rd=16, bs=4, nb=8):
+    rng = np.random.default_rng(7)
+    q_pos = np.asarray(case["q_pos"], np.int32)
+    shared, spare = case.get("shared", 0), case.get("spare", 0)
+    P = B * (nb + spare) + 2
+    c = rng.standard_normal((P, bs, r)).astype(np.float32)
+    kr = rng.standard_normal((P, bs, rd)).astype(np.float32)
+    pos = np.full((P, bs), -1, np.int32)
+    ids = iter(np.arange(P) if case.get("consecutive")
+               else rng.permutation(P))
+    first = [next(ids) for _ in range(shared)]
+    table = np.asarray([(first if b < 3 else [])
+                        + [next(ids) for _ in range(nb + spare
+                                                    - (shared if b < 3
+                                                       else 0))]
+                        for b in range(B)], np.int32)
+    for b in range(B):
+        for t in range(q_pos[b]):
+            pos[table[b, t // bs], t % bs] = t
+        if case.get("holes"):
+            pos[table[b, 1], 1:3] = -1
+    if shared:   # the prompt's tokens, the same in each row's own CoW copy
+        c[table[1:3, shared]] = c[table[0, shared]]
+        kr[table[1:3, shared]] = kr[table[0, shared]]
+    if case.get("holes"):
+        pos[table[0, q_pos[0] // bs], q_pos[0] % bs] = q_pos[0]
+    c[table[:, nb:]] = np.nan
+    kr[table[:, nb:]] = np.nan
+    ql, qr, c_new, kr_new = (rng.standard_normal(s).astype(np.float32)
+                             for s in ((B, H, r), (B, H, rd), (B, r),
+                                       (B, rd)))
+    before = [jnp.asarray(a, d) for a, d in
+              ((c, dtype), (kr, dtype), (pos, jnp.int32))]
+    rows = (table[np.arange(B), q_pos // bs], q_pos % bs)
+    c[rows], kr[rows], pos[rows] = c_new, kr_new, q_pos
+    after = [jnp.asarray(a, d) for a, d in
+             ((c, dtype), (kr, dtype), (pos, jnp.int32))]
+    cast = lambda a: jnp.asarray(a, dtype)    # noqa: E731
+    return (cast(ql), cast(qr), cast(c_new), cast(kr_new), before, after,
+            jnp.asarray(table), jnp.asarray(q_pos))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("name", list(LATENT_CASES))
+def test_paged_latent_decode_matches_refs(name, dtype):
+    """The kernel, given the pool before the step's write and the step's
+    token, against the float32 oracle over the pool after the write, and
+    against the model's gathered `_latent_attend` at S == 1 (with W_uk and
+    W_uv the identity, so its output is the latent context)."""
+    from repro.kernels.decode_attention.paged_latent import (
+        latent_pool_view, paged_latent_decode_pallas)
+    from repro.kernels.decode_attention.ref import paged_latent_decode_ref
+    from repro.models.attention import _latent_attend
+    case = LATENT_CASES[name]
+    ql, qr, c_new, kr_new, before, after, table, q_pos = _latent_case(
+        case, dtype)
+    B, H, r = ql.shape
+    scale = 0.3
+    view = latent_pool_view(*(a[None] for a in before), table,
+                            chunk=case["chunk"])
+    got = paged_latent_decode_pallas(
+        ql, qr, c_new, kr_new, view, 0, table, q_pos, scale=scale,
+        chunk=case["chunk"], run_blocks=case.get("run", 8), interpret=True)
+    want = paged_latent_decode_ref(ql, qr, *after, table, q_pos, scale=scale)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+    live = table[:, :8]            # the blocks the longest context spans
+    eye = jnp.broadcast_to(jnp.eye(r, dtype=dtype)[:, None], (r, H, r))
+    model = _latent_attend(
+        ql[:, None], qr[:, None], q_pos[:, None],
+        after[0][live].reshape(B, -1, r),
+        after[1][live].reshape(B, -1, qr.shape[-1]),
+        after[2][live].reshape(B, -1), eye, eye, scale, None)[:, 0]
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(model, np.float32),
+                               rtol=tol, atol=tol)
+
+
 # =============================================================== ssd scan
 SSD_SHAPES = [
     # (B, L, H, P, N, chunk)

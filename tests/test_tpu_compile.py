@@ -23,6 +23,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.decode_attention.decode_attention import (
     decode_attention_pallas, paged_decode_attention_pallas)
+from repro.kernels.decode_attention.paged_latent import (
+    latent_pool_view, paged_latent_decode_pallas)
 from repro.kernels.dequant_matmul.dequant_matmul import (
     dequant_matmul_int4_pallas, dequant_matmul_int8_pallas)
 from repro.kernels.flash_attention.flash_attention import \
@@ -73,6 +75,23 @@ def _paged():
     return (lambda *a: paged_decode_attention_pallas(*a, interpret=False),
             [((B, 1, H, D), BF), ((P, bs, Hkv, D), BF), ((P, bs, Hkv, D), BF),
              ((P, bs), I32), ((B, nb), I32), ((B,), I32)])
+
+
+def _paged_latent():
+    """DeepSeek-V2-Lite's latent decode at the docqa cell's size: 16
+    sequences over 1,038 blocks of 16 in a 4,544-block pool, 16 heads,
+    latent 512, rope 64."""
+    cfg = get_config("deepseek-v2-lite-16b-ep4")
+    H, r, rd = cfg.n_heads, cfg.mla.kv_lora_rank, cfg.mla.qk_rope_head_dim
+    B, P, bs, nb, L = 16, 4544, 16, 1038, 1
+
+    def fn(ql, qr, c_new, kr_new, c_kv, k_rope, pos, table, q_pos):
+        view = latent_pool_view(c_kv, k_rope, pos, table)
+        return paged_latent_decode_pallas(ql, qr, c_new, kr_new, view, 0,
+                                          table, q_pos, scale=0.1)
+    return fn, [((B, H, r), BF), ((B, H, rd), BF), ((B, r), BF),
+                ((B, rd), BF), ((L, P, bs, r), BF), ((L, P, bs, rd), BF),
+                ((L, P, bs), I32), ((B, nb), I32), ((B,), I32)]
 
 
 def _dense():
@@ -126,6 +145,7 @@ def _ssd():
     pytest.param(lambda: _flash(1024), id="flash_attention"),
     pytest.param(lambda: _flash(1000), id="flash_attention_padded"),
     pytest.param(_paged, id="paged_decode_attention"),
+    pytest.param(_paged_latent, id="paged_latent_decode"),
     pytest.param(_dense, id="decode_attention"),
     pytest.param(_int8, id="dequant_matmul_int8"),
     *[pytest.param(functools.partial(_int8, *mkn),
@@ -231,15 +251,20 @@ def test_int8_model_reads_weight_stacks_in_place(one_chip, monkeypatch):
     assert sum("dequant_matmul" in name for name, _ in calls) == 7
 
 
-def test_deepseek_share_decode_fits_with_its_pool(one_chip):
+def test_deepseek_share_decode_fits_with_its_pool(one_chip, monkeypatch):
     """The DeepSeek-V2-Lite share's pooled decode step at its cell's size
-    (16 sequences over 16,608 positions of the 4,544-block latent pool):
-    the weights, the pool and the step's output pool (nothing donates it)
-    and its temporaries fit the chip; the gathered latent context is
-    made in bf16, and no float32 copy of it is made."""
+    (16 sequences over 16,608 positions of the 4,544-block latent pool),
+    with the kernel the chip dispatches to: the weights, the pool and the
+    step's output pool (nothing donates it) and its temporaries fit the
+    chip; latent attention is the paged latent decode kernel, which reads
+    the pools in place: no gathered (16, 16608, 512) context is made in
+    any dtype, and the one copy of a latent pool is the prefix layer's
+    write into its undonated pool."""
     import types
 
+    from repro.kernels.decode_attention import ops as da_ops
     from repro.serving.backend import ExecutionBackend
+    monkeypatch.setattr(da_ops, "interpret_mode", lambda: False)
 
     def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -267,5 +292,10 @@ def test_deepseek_share_decode_fits_with_its_pool(one_chip):
               for t in (params, cache)]
     assert sum(nbytes) + nbytes[1] + mem.temp_size_in_bytes < HBM_BYTES
     hlo = compiled.as_text()
-    assert f"bf16[{B},{K},512]" in hlo
-    assert f"f32[{B},{K},512]" not in hlo
+    assert f"[{B},{K},512]" not in hlo
+    calls = re.findall(r"%(\S+) = \S+ custom-call\(", hlo)
+    assert sum("paged_latent_decode" in name for name in calls) == 2
+    copies = re.findall(r"^\s*(?:ROOT )?%\S+ = (.*?) (?:copy|copy-start)"
+                        r"\((.*?)\)", hlo, re.M)
+    assert [ops for ty, ops in copies if "4544,16,512]" in ty] == [
+        "%cache__prefix___0___c_kv__.1"]
