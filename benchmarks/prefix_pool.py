@@ -1,22 +1,25 @@
-"""Resident prefix-sharing KV pool vs per-batch sharing (PR 9 bench).
+"""Prefix-pool trie vs the resident cache without it (PR 9 bench).
 
 A seeded request stream shaped like real serving traffic: every prompt is
 one of three Zipf-weighted **system prompts** (24 tokens = 6 full blocks)
 followed by a short unique user suffix (4 tokens). Two backends see the
 identical stream at the *identical KV block budget*:
 
-* ``batch`` — the PR-5 path: prefix sharing and CoW inside one batch only;
-  every batch re-prefills the system prompt from scratch.
-* ``pool``  — the resident `PrefixPool`: a radix trie over token-id block
-  chunks survives batch retirement, so a warm request resolves its cached
-  system-prompt chain in one trie walk and prefills only the 4-token tail.
+* ``batch`` — the resident cache without the trie (``kv_pool=False``):
+  prefix sharing and CoW inside one batch only; every batch re-prefills
+  the system prompt from scratch.
+* ``pool``  — the same cache with the `PrefixPool` trie: a radix trie over
+  token-id block chunks survives batch retirement, so a warm request
+  resolves its cached system-prompt chain in one trie walk and prefills
+  only the 4-token tail.
 
 Reported per policy: prefill bytes moved (total and **steady-state** —
-excluding the cold first batch), and throughput over a roofline-style
-service model (fixed batch overhead + bandwidth-bound prefill term per
-token actually moved + compute-bound decode term per sequence-token; the
-decode term is identical for both policies because batch formation is,
-so the throughput gap is purely the prefill traffic the pool avoids).
+excluding the cold first batch), and ``throughput_rps``: completed
+requests over a *modeled* makespan (fixed batch overhead + a prefill term
+per token actually moved + a decode term per sequence-token; the decode
+term is identical for both policies because batch formation is, so the
+ratio is purely the prefill traffic the pool avoids). It is a service
+model over the records, not a measured speed.
 
 The pooled stream's hit/miss counters are cross-checked against an
 **analytic replay**: a pure-python trie is driven with the recorded batch
@@ -26,9 +29,9 @@ eviction-free budget makes the expectation exact. A separate tight-budget
 run forces LRU evictions and must still complete every request.
 
 Acceptance (seeded, CI-gated): pooled decode is token/logprob bit-identical
-to the non-pooled paged path (cold AND cache-hot, sampled, CoW tail);
+to the paged path without the trie (cold AND cache-hot, sampled, CoW tail);
 steady-state prefill bytes are >= 3x lower than per-batch sharing at equal
-block budget; throughput matches-or-beats the per-batch path; the obs
+block budget; modeled throughput matches-or-beats the trie-less path; the obs
 counters match the analytic replay; the tight run evicts (> 0) and
 completes the full stream.
 
@@ -198,7 +201,7 @@ def _run_stream(pooled: bool, arrivals, kv_blocks: int = BUDGET_BLOCKS,
               f"(steady {out['steady_prefill_bytes_moved'] / 1e3:.1f} kB), "
               f"hits {out['pool_hit_blocks']}, "
               f"evictions {out['pool_evictions']}, "
-              f"{out['throughput_rps']:.3f} req/s")
+              f"{out['throughput_rps']:.3f} req per modeled s")
     return out
 
 
@@ -239,7 +242,7 @@ def _analytic_replay(records, prompt_by_id) -> Dict[str, int]:
 
 def _parity() -> bool:
     """Pinned acceptance parity: pooled generation must be token- and
-    logprob-identical to the non-pooled paged path, cold AND cache-hot,
+    logprob-identical to the paged path without the trie, cold AND cache-hot,
     sampled, with a CoW partial tail block (plen % bs != 0)."""
     import jax
     import jax.numpy as jnp
@@ -332,7 +335,7 @@ def run(verbose: bool = True) -> Dict:
         print(f"  parity_ok={parity_ok}, hits_match_analytic={hits_match} "
               f"({pool['obs_hits']} hits, rate {result['hit_rate']:.2f}), "
               f"steady prefill x{steady_ratio:.1f} less, "
-              f"throughput x{result['throughput_ratio']:.2f}, "
+              f"modeled throughput x{result['throughput_ratio']:.2f}, "
               f"acceptance_all={result['acceptance_all']}")
         print(json.dumps(result, indent=2))
     return result

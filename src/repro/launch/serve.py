@@ -58,11 +58,11 @@ def main(argv: Optional[Sequence[str]] = None) -> List[GenerationResult]:
     ap.add_argument("--kv-block-size", type=int, default=16,
                     help="paged KV cache: token slots per block")
     ap.add_argument("--kv-pool", action="store_true",
-                    help="resident prefix pool (needs --kv-blocks): one "
-                         "physical cache outlives batches, a radix trie "
-                         "reuses cached full prefix blocks across the "
-                         "stream, and admission prices requests at their "
-                         "marginal (post-dedup) tail blocks")
+                    help="prefix pool (needs --kv-blocks): a radix trie "
+                         "over the resident cache reuses cached full "
+                         "prefix blocks across the stream, and admission "
+                         "prices requests at their marginal (post-dedup) "
+                         "tail blocks")
     ap.add_argument("--pool-evict", default="lru", choices=["lru", "off"],
                     help="prefix-pool eviction of idle (zero-ref) trie "
                          "blocks: LRU on demand, or off (resident set only "

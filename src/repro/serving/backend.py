@@ -39,6 +39,10 @@ it:
   copy-on-write; ``kv_blocks`` is the *real* memory budget
   (``kv_blocks * block_size * kv_bytes_per_token``), and ``blocks_free`` is
   the admission currency the scheduler checks.
+* One resident physical cache of ``kv_blocks`` blocks serves every paged
+  batch: block tables carry global allocator ids, and a block's bytes are
+  reusable the moment the allocator frees it. ``kv_pool=True`` adds the
+  `PrefixPool` trie that keeps filled prefix blocks across batches.
 * Prefill runs once per *unique prompt*; the k repeats share the full
   prefix blocks by reference (`fork`). A partially-filled last prefix block
   is copy-on-write forked at ``start_batch`` — each repeat gets a private
@@ -114,15 +118,8 @@ class BlockAllocator:
     batch's physical pool layout is mirrored here block-for-block, double
     frees raise instead of silently corrupting the budget, and a shared
     prefix block only returns to the free list when its *last* holder
-    releases it.
-
-    Physical pools are per-batch arrays reclaimed whole at batch retirement
-    (`ExecutionBackend.pool_blocks_resident` is the resident footprint), so
-    budget freed mid-flight by an early release admits new work whose pool
-    is *additional* memory until the donor batch retires — transient
-    overcommit bounded by the early-released block count. A single resident
-    pool shared across batches closes that gap (ROADMAP: cross-batch
-    physical block sharing).
+    releases it. Block ids index the backend's one resident cache of
+    ``n_blocks`` blocks, so the budget bounds the bytes.
     """
 
     def __init__(self, n_blocks: int, block_size: int):
@@ -221,23 +218,21 @@ class BlockAllocator:
 
 @dataclass
 class PagedBatchLayout:
-    """Physical pool layout of one in-flight batch. Built once at
-    ``start_batch`` (the whole block schedule — prefix sharing, CoW fan-out,
-    decode blocks — is deterministic given the bucket geometry), static for
-    the batch lifetime so decode steps stay pure jitted functions."""
+    """Block tables of one in-flight batch into the resident cache. Built
+    once at ``start_batch`` (the whole block schedule — prefix sharing, CoW
+    fan-out, decode blocks — is deterministic given the bucket geometry),
+    static for the batch lifetime so decode steps stay pure jitted
+    functions. Every table holds global allocator ids."""
     block_size: int
-    n_pool_blocks: int                 # physical pool size (local ids)
     kv_len: int                        # logical slots per sequence
-    prefill_table: np.ndarray          # (R, ceil(plen/bs)) local block ids
-    decode_table: np.ndarray           # (B, ceil(kv_len/bs)) local block ids
+    prefill_table: np.ndarray          # (R, ceil(plen/bs)) block ids
+    decode_table: np.ndarray           # (B, ceil(kv_len/bs)) block ids
     copy_src: np.ndarray               # CoW pairs: partial prefix block ->
     copy_dst: np.ndarray               #   each repeat's private copy
     seq_gids: List[List[int]]          # allocator ids referenced per sequence
-    # prefix-pool path (`hit_chains` given): tables carry GLOBAL allocator
-    # ids straight into the backend's resident cache, and each prompt's
-    # leading ``hit_counts[i]`` prefill blocks are already filled — only the
-    # tail from ``hit_counts[i] * block_size`` needs prefilling
-    pooled: bool = False
+    # prompt i's leading ``hit_counts[i]`` prefill blocks came from the
+    # prefix pool already filled: only the tail from
+    # ``hit_counts[i] * block_size`` needs prefilling
     hit_counts: List[int] = field(default_factory=list)
 
 
@@ -258,34 +253,21 @@ def build_paged_layout(allocator: BlockAllocator, plen: int, max_new: int,
     for max_new == 1) and sequences never pay for a block that would hold
     only the unwritten ``plen + max_new - 1`` slot.
 
-    Pool-lookup path: ``hit_chains[i]`` is prompt *i*'s longest cached
-    full-prefix block chain from the resident `PrefixPool` — already filled,
-    already holding one reference per repeat (``PrefixPool.acquire``). Those
-    blocks head the prompt's tables instead of fresh allocations, only the
-    tail is newly allocated, and every table carries GLOBAL allocator ids
-    (the physical cache is the backend's single resident pool, so no local
-    remap exists). The partial-tail CoW fork is unchanged — partial blocks
-    are never pool-shared — so the whole block schedule stays static and
-    decode jit-friendly.
+    ``hit_chains[i]`` (empty when not given) is prompt *i*'s longest cached
+    full-prefix block chain from the resident `PrefixPool` — already
+    filled, already holding one reference per repeat
+    (``PrefixPool.acquire``). Those blocks head the prompt's tables instead
+    of fresh allocations, and only the tail is newly allocated. Partial
+    blocks are never pool-shared, so the CoW fork and the whole block
+    schedule stay static and decode jit-friendly. Tables carry global
+    allocator ids: they index the backend's one resident cache.
     """
     bs = allocator.block_size
     n_logical = max(-(-(plen + max_new - 1) // bs), 1)
     full_prefix = plen // bs
     has_partial = plen % bs != 0
-    pooled = hit_chains is not None
-    if not pooled:
+    if hit_chains is None:
         hit_chains = [[] for _ in repeats]
-
-    pool_gids: List[int] = []
-    local_of: Dict[int, int] = {}
-
-    def loc(gid: int) -> int:
-        if pooled:                     # resident pool: global ids ARE the map
-            return gid
-        if gid not in local_of:
-            local_of[gid] = len(pool_gids)
-            pool_gids.append(gid)
-        return local_of[gid]
 
     prefill_rows: List[List[int]] = []
     decode_rows: List[List[int]] = []
@@ -305,35 +287,28 @@ def build_paged_layout(allocator: BlockAllocator, plen: int, max_new: int,
                 allocator.fork(g)
             if part is not None:
                 allocator.fork(part)
-        prefill_rows.append([loc(g) for g in shared]
-                            + ([loc(part)] if part is not None else []))
+        prefill_rows.append(shared + ([part] if part is not None else []))
         for _ in range(k):
             gids = list(shared)
-            row = [loc(g) for g in shared]
             if part is not None:
                 wg, copied = allocator.cow(part)
                 if copied:
-                    copy_src.append(loc(part))
-                    copy_dst.append(loc(wg))
+                    copy_src.append(part)
+                    copy_dst.append(wg)
                 gids.append(wg)
-                row.append(loc(wg))
-            while len(row) < n_logical:
-                g = allocator.alloc()
-                gids.append(g)
-                row.append(loc(g))
-            decode_rows.append(row)
+            while len(gids) < n_logical:
+                gids.append(allocator.alloc())
+            decode_rows.append(gids)
             seq_gids.append(gids)
 
     return PagedBatchLayout(
         block_size=bs,
-        n_pool_blocks=allocator.n_blocks if pooled else len(pool_gids),
         kv_len=plen + max_new,
         prefill_table=np.asarray(prefill_rows, np.int32),
         decode_table=np.asarray(decode_rows, np.int32),
         copy_src=np.asarray(copy_src, np.int32),
         copy_dst=np.asarray(copy_dst, np.int32),
         seq_gids=seq_gids,
-        pooled=pooled,
         hit_counts=[len(h) for h in hit_chains])
 
 
@@ -380,7 +355,6 @@ class PendingPrefill:
     last_rows: List[Any]               # final-position logits per prompt
     rep: Union[int, np.ndarray]
     mc: bool
-    full_prefix: int                   # blocks to trie-index at finish
     n_spec: int = 0                    # draft depth armed for this batch
 
 
@@ -394,7 +368,8 @@ class InFlightBatch:
     temperature: float
     rng: jax.Array                     # stream state: split once per token
     extras: Dict[str, jax.Array]       # already tiled to sequence count
-    cache: Any
+    cache: Any                         # dense batches only; a paged batch
+    #                                    reads the backend's resident cache
     tok: Optional[jax.Array]           # last sampled token (B,) or (B, K);
     #                                    None while chunk-prefilling
     step: int                          # tokens sampled so far (>= 1)
@@ -404,8 +379,7 @@ class InFlightBatch:
     paged: Optional[PagedBatchLayout] = None
     block_table: Optional[jax.Array] = None    # decode table on device
     prefill_bytes_saved: float = 0.0   # KV bytes prefix sharing did not move
-    # prefix-pool accounting (pooled batches only; h.cache is None — the
-    # physical cache is the backend's resident pool)
+    # prefix-pool accounting (zero without the trie)
     pool_hit_blocks: int = 0           # trie-cached blocks this batch reused
     pool_evictions: int = 0            # idle blocks evicted to fit the tail
     freed_seqs: Set[int] = field(default_factory=set)   # early-released rows
@@ -481,7 +455,7 @@ class ExecutionBackend:
         self.weight_bytes = param_bytes(params)
         self.allocator: Optional[BlockAllocator] = None
         self.prefix_pool = None
-        self._pool_cache = None            # resident physical cache (pooled)
+        self._pool_cache = None            # resident physical cache (paged)
         if kv_blocks is not None:
             if not cache_mod.paged_supported(model.cfg):
                 raise ValueError(
@@ -490,9 +464,8 @@ class ExecutionBackend:
                     "paged_supported); use the dense max_slots budget")
             self.allocator = BlockAllocator(kv_blocks, kv_block_size)
             if kv_pool:
-                # global prefix-sharing pool: ONE resident physical cache of
-                # kv_blocks blocks outlives every batch, and the radix trie
-                # indexes filled full-prefix blocks for cross-batch reuse
+                # the radix trie indexes filled full-prefix blocks of the
+                # resident cache for cross-batch reuse
                 self.prefix_pool = PrefixPool(self.allocator,
                                               evict=pool_evict)
         elif kv_pool:
@@ -615,17 +588,11 @@ class ExecutionBackend:
             self._m["slots"].set(self.slots_in_use)
 
     # ------------------------------------------------------------------ jitted
-    def _prefill(self, params, tokens, cache, extras, block_table=None,
-                 copy_src=None, copy_dst=None):
+    def _prefill(self, params, tokens, cache, extras):
+        """Whole-prompt prefill into a dense batch's own cache."""
         batch = {"tokens": tokens, **extras}
-        if block_table is not None:
-            batch["block_table"] = block_table
         logits, cache, _ = self.model.forward(params, batch, cache,
                                               last_only=True)
-        if copy_src is not None:
-            # CoW fan-out of the shared partial prefix block: fused into the
-            # prefill step so the batch is decode-ready in one dispatch
-            cache = cache_mod.copy_cache_blocks(cache, copy_src, copy_dst)
         return logits[:, -1], cache
 
     def _tail_prefill(self, params, tokens, start_pos, cache, extras,
@@ -638,10 +605,11 @@ class ExecutionBackend:
         then each attends to every cached position ``<= its own`` — the hit
         chain's prefix KV plus the tail itself, masked causally by position.
         The gathered reference path reduces over the same ``kv_len = plen``
-        positions in the same order as a full prefill, so tail logits are
-        *bit-identical* to prefilling the whole prompt (pinned by
-        ``tests/test_prefix_pool.py``); a zero-hit prompt runs its whole
-        prompt through this path (start_pos 0) with the same guarantee."""
+        positions in the same order as a full prefill. A zero-hit prompt
+        runs its whole prompt through this path (start_pos 0), bit-identical
+        to the dense prefill (pinned by ``tests/test_kv_paging.py``); a
+        cache-hot tail's logits can differ from it in the last bits on the
+        CPU (two pins in ``tests/test_prefix_pool.py`` fail on that)."""
         B, S = tokens.shape[0], tokens.shape[1]
         pos = start_pos + jnp.arange(S, dtype=jnp.int32)[None, :]
         pos = jnp.broadcast_to(pos, (B, S))
@@ -722,26 +690,10 @@ class ExecutionBackend:
         return self.allocator.blocks_in_use if self.allocator else None
 
     @property
-    def pool_blocks_resident(self) -> Optional[int]:
-        """Physical pool blocks resident right now. Per-batch pools are
-        whole arrays until retirement, so this can exceed ``blocks_in_use``
-        after early releases (the budget frees before the memory does). With
-        the prefix pool the physical cache is ONE resident array of
-        ``kv_blocks`` blocks shared by every batch — no transient overcommit
-        remains (the ROADMAP's cross-batch physical block sharing)."""
-        if self.allocator is None:
-            return None
-        if self.prefix_pool is not None:
-            return self.allocator.n_blocks if self._pool_cache is not None \
-                else 0
-        return sum(h.paged.n_pool_blocks for h in self._live.values()
-                   if h.paged is not None)
-
-    @property
     def capacity_free(self) -> Optional[int]:
         """Admission budget remaining, in this backend's currency: KV blocks
         (paged) or sequence slots (dense); None = unbounded. Idle prefix-pool
-        blocks count as free — `_start_batch_pooled` evicts them on demand —
+        blocks count as free — `_start_batch_paged` evicts them on demand —
         so a cache full of reclaimable prefixes never starves admission."""
         if self.allocator is not None:
             free = self.allocator.blocks_free
@@ -923,18 +875,13 @@ class ExecutionBackend:
         # the batch id comes from the scheduler via tracer.batch_context
         with tracer.span("prefill", step_num=self._prefills, plen=plen,
                          n_sequences=B, cache=self.cache_kind) as span:
-            if self.prefix_pool is not None:
-                h = self._start_batch_pooled(prompts, repeats, rep, base, B,
-                                             plen, max_new, temperature, rng,
-                                             extras, mc)
-                # only the post-dedup tails were prefilled
-                prefilled = (len(prompts) * plen
-                             - h.pool_hit_blocks * self.allocator.block_size)
-            elif self.allocator is not None:
+            if self.allocator is not None:
                 h = self._start_batch_paged(prompts, repeats, rep, base, B,
                                             plen, max_new, temperature, rng,
                                             extras, mc)
-                prefilled = len(prompts) * plen  # one row per unique prompt
+                # one row per unique prompt, post-dedup tails only
+                prefilled = (len(prompts) * plen
+                             - h.pool_hit_blocks * self.allocator.block_size)
             else:
                 h = self._start_batch_dense(prompts, repeats, rep, base, B,
                                             plen, max_new, temperature, rng,
@@ -1038,77 +985,6 @@ class ExecutionBackend:
             extras=tiled_extras, cache=cache, tok=tok, step=1,
             out_toks=[tok], out_lps=[lp])
 
-    def _start_batch_paged(self, prompts, repeats, rep, base, B, plen,
-                           max_new, temperature, rng, extras,
-                           mc) -> InFlightBatch:
-        R = len(prompts)
-        need = sum(self.request_blocks(plen, max_new, k) for k in repeats)
-        if need > self.allocator.blocks_free:
-            raise RuntimeError(
-                f"KV block budget exceeded: need {need} > "
-                f"{self.allocator.blocks_free} free (scheduler must check "
-                "blocks_free)")
-        layout = build_paged_layout(self.allocator, plen,
-                                    max_new + self._spec_slack(), repeats)
-        try:
-            with self.obs.tracer.span("prefill.dispatch", cache=self.cache_kind):
-                cache = self.model.init_paged_cache(
-                    layout.n_pool_blocks, layout.block_size,
-                    kv_dtype=jnp.int8 if self.kv_format == "int8" else None)
-            # prefill rows are the unique prompts (extras per-prompt as-is);
-            # decode rows are the tiled sequences — both tiled exactly once
-            prefill_extras = {k: jnp.asarray(v) for k, v in extras.items()}
-            decode_extras = {k: jnp.repeat(jnp.asarray(v), rep, axis=0)
-                             for k, v in extras.items()}
-            if self.prefill_chunk is not None:
-                # chunked: the fresh paged cache masks every position, so
-                # slice-at-a-time tail prefills are safe; CoW fan-out and
-                # first-token sampling run at finish
-                jobs: Deque[Tuple[List[int], int, int]] = deque()
-                s = 0
-                while s < plen:
-                    e = min(s + self.prefill_chunk, plen)
-                    jobs.append((list(range(R)), s, e))
-                    s = e
-                return InFlightBatch(
-                    prompts=list(prompts), repeats=repeats, plen=plen,
-                    max_new=max_new, temperature=temperature, rng=rng,
-                    extras=decode_extras, cache=cache, tok=None, step=0,
-                    paged=layout,
-                    block_table=jnp.asarray(layout.decode_table),
-                    prefill_bytes_saved=float((B - R) * plen
-                                              * self.kv_token_bytes),
-                    pending_prefill=PendingPrefill(
-                        jobs=jobs, base=base, extras=prefill_extras,
-                        last_rows=[None] * R, rep=rep, mc=mc,
-                        full_prefix=0))
-            has_cow = layout.copy_src.size > 0
-            with self.obs.tracer.span("prefill.dispatch", cache=self.cache_kind):
-                last_logits, cache = self._prefill_jit(
-                    self.params, jnp.asarray(base), cache, prefill_extras,
-                    jnp.asarray(layout.prefill_table),
-                    jnp.asarray(layout.copy_src) if has_cow else None,
-                    jnp.asarray(layout.copy_dst) if has_cow else None)
-        except BaseException:
-            # no handle exists yet to release() — return every reference the
-            # layout took, or a failed prefill permanently shrinks the budget
-            for gids in layout.seq_gids:
-                for g in gids:
-                    self.allocator.free(g)
-            raise
-
-        # fan the unique-prompt logits out to the repeats, then sample with
-        # the same key/shape as the dense path — bit-identical first token
-        rng, tok, lp = self._sample_first(last_logits, rng, temperature, rep,
-                                          mc)
-        return InFlightBatch(
-            prompts=list(prompts), repeats=repeats, plen=plen,
-            max_new=max_new, temperature=temperature, rng=rng,
-            extras=decode_extras, cache=cache, tok=tok, step=1,
-            out_toks=[tok], out_lps=[lp],
-            paged=layout, block_table=jnp.asarray(layout.decode_table),
-            prefill_bytes_saved=float((B - R) * plen * self.kv_token_bytes))
-
     def _ensure_pool_cache(self):
         """The single resident physical cache (lazy: sized to the whole
         ``kv_blocks`` budget, so it is only materialized once serving
@@ -1120,15 +996,16 @@ class ExecutionBackend:
                 kv_dtype=jnp.int8 if self.kv_format == "int8" else None)
         return self._pool_cache
 
-    def _start_batch_pooled(self, prompts, repeats, rep, base, B, plen,
-                            max_new, temperature, rng, extras,
-                            mc) -> InFlightBatch:
-        """Paged start with the resident prefix pool: resolve each prompt's
-        longest cached block chain (pinning it with per-sequence refs),
-        evict idle LRU blocks to fit the post-dedup tails, then prefill
-        *only the tails* — grouped by hit depth so every forward keeps a
-        static shape — and finally index the freshly filled full-prefix
-        chains for the batches that follow."""
+    def _start_batch_paged(self, prompts, repeats, rep, base, B, plen,
+                           max_new, temperature, rng, extras,
+                           mc) -> InFlightBatch:
+        """Paged start over the resident cache. With the prefix pool,
+        resolve each prompt's longest cached block chain (pinning it with
+        per-sequence refs) and evict idle LRU blocks to fit the post-dedup
+        tails; without it every chain is empty and the whole prompt is the
+        tail. Then prefill *only the tails* — grouped by hit depth so every
+        forward keeps a static shape — and index the freshly filled
+        full-prefix chains for the batches that follow."""
         pool = self.prefix_pool
         alloc = self.allocator
         bs = alloc.block_size
@@ -1138,14 +1015,15 @@ class ExecutionBackend:
         with tracer.span("pool.match", cache=self.cache_kind):
             # 1. acquire hit chains first: refs pin them, so the eviction
             #    pass below can never reclaim a block this batch just resolved
-            hit_chains = [pool.acquire(p, self._max_hit(plen), holders=k)
-                          for p, k in zip(prompts, repeats)]
+            hit_chains = ([pool.acquire(p, self._max_hit(plen), holders=k)
+                           for p, k in zip(prompts, repeats)]
+                          if pool is not None else [[] for _ in prompts])
             horizon = max_new + self._spec_slack()
             n_logical = max(-(-(plen + horizon - 1) // bs), 1)
             need = sum(full_prefix - len(ch) + k * (n_logical - full_prefix)
                        for ch, k in zip(hit_chains, repeats))
             # 2. make room for the tails (LRU over idle trie leaves only)
-            evicted = pool.ensure_free(need)
+            evicted = pool.ensure_free(need) if pool is not None else 0
             if need > alloc.blocks_free:
                 for ch, k in zip(hit_chains, repeats):
                     for g in ch:
@@ -1184,6 +1062,7 @@ class ExecutionBackend:
                 decode_extras = {k: jnp.repeat(jnp.asarray(v), rep, axis=0)
                                  for k, v in extras.items()}
                 block_table = jnp.asarray(layout.decode_table)
+                pending = None
                 if self.prefill_chunk is not None:
                     # chunked: enqueue the tail slices instead of forwarding
                     # — decode_step drains one per call; CoW fan-out, trie
@@ -1195,42 +1074,25 @@ class ExecutionBackend:
                             e = min(s + self.prefill_chunk, plen)
                             jobs.append((idxs, s, e))
                             s = e
-                    tail_tokens = sum(plen - c * bs
-                                      for c in layout.hit_counts)
-                    return InFlightBatch(
-                        prompts=list(prompts), repeats=repeats, plen=plen,
-                        max_new=max_new, temperature=temperature, rng=rng,
-                        extras=decode_extras, cache=None, tok=None, step=0,
-                        paged=layout, block_table=block_table,
-                        prefill_bytes_saved=float((B * plen - tail_tokens)
-                                                  * self.kv_token_bytes),
-                        pool_hit_blocks=sum(layout.hit_counts),
-                        pool_evictions=evicted,
-                        pending_prefill=PendingPrefill(
-                            jobs=jobs, base=base, extras=prefill_extras,
-                            last_rows=[None] * R, rep=rep, mc=mc,
-                            full_prefix=full_prefix))
-                # 4. tail-only prefill, one static-shape forward per hit
-                #    depth
-                last_rows: List[Any] = [None] * R
-                for c, idxs in sorted(groups.items()):
-                    gl, self._pool_cache = self._tail_prefill_jit(
-                        self.params, jnp.asarray(base[idxs][:, c * bs:]),
-                        jnp.asarray(c * bs, jnp.int32), self._pool_cache,
-                        {k: v[jnp.asarray(idxs)]
-                         for k, v in prefill_extras.items()},
-                        jnp.asarray(layout.prefill_table[idxs]),
-                        kv_len=plen)
-                    for j, i in enumerate(idxs):
-                        last_rows[i] = gl[j]
-                last_logits = jnp.stack(last_rows, axis=0)
-                if layout.copy_src.size > 0:
-                    # CoW fan-out of the shared partial prefix block
-                    # (partials are never pool-shared — same schedule as
-                    # per-batch paging)
-                    self._pool_cache = self._copy_blocks_jit(
-                        self._pool_cache, jnp.asarray(layout.copy_src),
-                        jnp.asarray(layout.copy_dst))
+                    pending = PendingPrefill(
+                        jobs=jobs, base=base, extras=prefill_extras,
+                        last_rows=[None] * R, rep=rep, mc=mc)
+                else:
+                    # 4. tail-only prefill, one static-shape forward per hit
+                    #    depth
+                    last_rows: List[Any] = [None] * R
+                    for c, idxs in sorted(groups.items()):
+                        gl, self._pool_cache = self._tail_prefill_jit(
+                            self.params, jnp.asarray(base[idxs][:, c * bs:]),
+                            jnp.asarray(c * bs, jnp.int32), self._pool_cache,
+                            {k: v[jnp.asarray(idxs)]
+                             for k, v in prefill_extras.items()},
+                            jnp.asarray(layout.prefill_table[idxs]),
+                            kv_len=plen)
+                        for j, i in enumerate(idxs):
+                            last_rows[i] = gl[j]
+                    last_logits = jnp.stack(last_rows, axis=0)
+                    self._copy_partial_blocks(layout)
         except BaseException:
             # every reference the batch took (hits included — their holder
             # refs unwind to the trie ref) must return, or a failed prefill
@@ -1239,28 +1101,46 @@ class ExecutionBackend:
                 for g in gids:
                     alloc.free(g)
             raise
-        # 5. index the freshly filled full-prefix chains (post-success: the
-        #    trie must never point at unfilled blocks). A same-prefix
-        #    sibling within this batch keeps the first writer's blocks.
-        for i, p in enumerate(prompts):
-            pool.insert(p, [int(g) for g in
-                            layout.prefill_table[i][:full_prefix]])
-        hit_blocks = sum(layout.hit_counts)
-
-        # fan the unique-prompt logits out to the repeats, then sample with
-        # the same key/shape as the dense path — bit-identical first token
-        rng, tok, lp = self._sample_first(last_logits, rng, temperature, rep,
-                                          mc)
         tail_tokens = sum(plen - c * bs for c in layout.hit_counts)
-        return InFlightBatch(
+        h = InFlightBatch(
             prompts=list(prompts), repeats=repeats, plen=plen,
             max_new=max_new, temperature=temperature, rng=rng,
-            extras=decode_extras, cache=None, tok=tok, step=1,
-            out_toks=[tok], out_lps=[lp],
+            extras=decode_extras, cache=None, tok=None, step=0,
             paged=layout, block_table=block_table,
             prefill_bytes_saved=float((B * plen - tail_tokens)
                                       * self.kv_token_bytes),
-            pool_hit_blocks=hit_blocks, pool_evictions=evicted)
+            pool_hit_blocks=sum(layout.hit_counts), pool_evictions=evicted,
+            pending_prefill=pending)
+        if pending is None:
+            self._first_token(h, last_logits, rep, mc)
+        return h
+
+    def _copy_partial_blocks(self, layout: PagedBatchLayout) -> None:
+        """CoW fan-out of each prompt's shared partial prefix block into
+        its repeats' private copies (partials are never pool-shared)."""
+        if layout.copy_src.size > 0:
+            self._pool_cache = self._copy_blocks_jit(
+                self._pool_cache, jnp.asarray(layout.copy_src),
+                jnp.asarray(layout.copy_dst))
+
+    def _first_token(self, h: InFlightBatch, last_logits, rep,
+                     mc) -> None:
+        """The prefill landed: index the freshly filled full-prefix chains
+        (only now: the trie must never point at unfilled blocks; a
+        same-prefix sibling within the batch keeps the first writer's
+        blocks), then fan the unique-prompt logits out to the repeats and
+        sample with the same key/shape as the dense path — bit-identical
+        first token."""
+        if self.prefix_pool is not None:
+            full_prefix = h.plen // self.allocator.block_size
+            for i, p in enumerate(h.prompts):
+                self.prefix_pool.insert(
+                    p, [int(g) for g in
+                        h.paged.prefill_table[i][:full_prefix]])
+        h.rng, h.tok, lp = self._sample_first(last_logits, h.rng,
+                                              h.temperature, rep, mc)
+        h.step = 1
+        h.out_toks, h.out_lps = [h.tok], [lp]
 
     def _prefill_chunk_step(self, h: InFlightBatch) -> None:
         """Run ONE pending prefill slice (the chunked-prefill unit a
@@ -1273,18 +1153,12 @@ class ExecutionBackend:
                          prefill_tokens=len(idxs) * (e - s),
                          n_sequences=h.n_sequences, plen=h.plen,
                          chunk=[s, e]):
-            pooled = h.cache is None
-            cache = self._pool_cache if pooled else h.cache
             with tracer.span("prefill.dispatch", cache=self.cache_kind):
-                gl, cache = self._tail_prefill_jit(
+                gl, self._pool_cache = self._tail_prefill_jit(
                     self.params, jnp.asarray(pp.base[idxs][:, s:e]),
-                    jnp.asarray(s, jnp.int32), cache,
+                    jnp.asarray(s, jnp.int32), self._pool_cache,
                     {k: v[jnp.asarray(idxs)] for k, v in pp.extras.items()},
                     jnp.asarray(h.paged.prefill_table[idxs]), kv_len=h.plen)
-                if pooled:
-                    self._pool_cache = cache
-                else:
-                    h.cache = cache
                 if e == h.plen:
                     for j, i in enumerate(idxs):
                         pp.last_rows[i] = gl[j]
@@ -1295,32 +1169,14 @@ class ExecutionBackend:
 
     def _finish_chunked_prefill(self, h: InFlightBatch) -> None:
         """Last chunk landed: CoW-fan-out the shared partial block, index
-        the now-filled full-prefix chains (pooled mode), sample the first
-        token with the exact split sequence of the one-shot path (bit
-        parity), and arm the draft state if a depth was noted."""
+        the now-filled full-prefix chains, sample the first token with the
+        exact split sequence of the one-shot path (bit parity), and arm the
+        draft state if a depth was noted."""
         pp = h.pending_prefill
-        layout = h.paged
-        pooled = h.cache is None
-        cache = self._pool_cache if pooled else h.cache
-        if layout.copy_src.size > 0:
-            with self.obs.tracer.span("prefill.dispatch", cache=self.cache_kind):
-                cache = self._copy_blocks_jit(cache,
-                                              jnp.asarray(layout.copy_src),
-                                              jnp.asarray(layout.copy_dst))
-        if pooled:
-            self._pool_cache = cache
-            for i, p in enumerate(h.prompts):
-                self.prefix_pool.insert(
-                    p, [int(g) for g in
-                        layout.prefill_table[i][:pp.full_prefix]])
-        else:
-            h.cache = cache
         with self.obs.tracer.span("prefill.dispatch", cache=self.cache_kind):
+            self._copy_partial_blocks(h.paged)
             last_logits = jnp.stack(pp.last_rows, axis=0)
-        h.rng, h.tok, lp = self._sample_first(last_logits, h.rng,
-                                              h.temperature, pp.rep, pp.mc)
-        h.step = 1
-        h.out_lps = [lp]
+        self._first_token(h, last_logits, pp.rep, pp.mc)
         self._fetch_first(h)
         if pp.n_spec > 0:
             self._arm_spec(h, pp.n_spec)
@@ -1342,7 +1198,7 @@ class ExecutionBackend:
         correct — on a speculative batch that bound also keeps any stale
         rejected-draft writes (which live past the committed frontier) out
         of the trie. Returns parked blocks; degrades to a plain release
-        when there is no resident pool to park into, or mid-chunked-prefill
+        when there is no prefix pool to park into, or mid-chunked-prefill
         (no block is guaranteed filled)."""
         pool = self.prefix_pool
         if pool is None or h.paged is None or h.pending_prefill is not None:
@@ -1367,6 +1223,17 @@ class ExecutionBackend:
         self.release(h)
         return parked
 
+    def _cache_of(self, h: InFlightBatch):
+        """The cache a batch's step programs read: the resident cache for
+        a paged batch, the batch's own for a dense one."""
+        return self._pool_cache if h.paged is not None else h.cache
+
+    def _store_cache(self, h: InFlightBatch, cache) -> None:
+        if h.paged is not None:
+            self._pool_cache = cache
+        else:
+            h.cache = cache
+
     def decode_step(self, h: InFlightBatch) -> bool:
         """Advance one token (or one draft/verify round on a speculative
         batch); returns True while the batch still has decode steps left
@@ -1386,19 +1253,14 @@ class ExecutionBackend:
                 h.rng, sub = jax.random.split(h.rng)
                 step_pos = jnp.asarray(h.plen + h.step - 1, jnp.int32)
                 tok_in = h.tok[:, None] if not mc else h.tok[:, None, :]
-                pooled = h.cache is None   # resident pool, shared by batches
-                cache = self._pool_cache if pooled else h.cache
                 h.tok, lp, cache = self._decode_jit(
-                    self.params, tok_in, step_pos, cache, sub, h.temperature,
-                    h.extras, h.block_table,
+                    self.params, tok_in, step_pos, self._cache_of(h), sub,
+                    h.temperature, h.extras, h.block_table,
                     kv_len=h.paged.kv_len if h.paged is not None else None,
                     greedy=h.temperature == 0.0)
                 if mc:
                     lp = lp.mean(-1)
-            if pooled:
-                self._pool_cache = cache
-            else:
-                h.cache = cache
+            self._store_cache(h, cache)
             with tracer.span("decode.fetch", cache=self.cache_kind):
                 h.out_toks.append(np.asarray(h.tok))
                 h.out_lps.append(np.asarray(lp))
@@ -1440,20 +1302,15 @@ class ExecutionBackend:
                     toks_in = np.concatenate([last[:, None], drafts], axis=1)
                     base_pos = np.asarray(h.plen + sp.committed - 1,
                                           np.int32)
-                    pooled = h.cache is None   # resident pool, shared
-                    cache = self._pool_cache if pooled else h.cache
                     accept_len, out_tokens, out_lps, cache = \
                         self._spec_verify_jit(
                             self.params, jnp.asarray(toks_in),
-                            jnp.asarray(base_pos), cache, sub,
+                            jnp.asarray(base_pos), self._cache_of(h), sub,
                             h.temperature, h.extras, h.block_table,
                             kv_len=(h.paged.kv_len if h.paged is not None
                                     else None),
                             greedy=h.temperature == 0.0)
-                if pooled:
-                    self._pool_cache = cache
-                else:
-                    h.cache = cache
+                self._store_cache(h, cache)
                 with tracer.span("decode.fetch", cache=self.cache_kind):
                     accept_len = np.asarray(accept_len)
                     out_tokens = np.asarray(out_tokens)
@@ -1521,12 +1378,8 @@ class ExecutionBackend:
         the released rows' blocks/slots are free for new admissions *now*
         instead of at batch retirement. Returns blocks (or slots) actually
         returned to the budget; shared prefix blocks only come back with
-        their last holder.
-
-        Note this frees *budget*, not bytes: the batch's physical pool is
-        one array, resident until retirement (`pool_blocks_resident`), so
-        admissions riding on early-released budget transiently overcommit
-        by at most the released count — see `BlockAllocator`."""
+        their last holder. A freed block's bytes are the next batch's at
+        once: every paged batch indexes the one resident cache."""
         if id(h) not in self._live:
             raise RuntimeError("release_sequences on unknown or "
                                "already-released batch handle")

@@ -1,10 +1,10 @@
 """Global prefix-sharing KV pool: a radix-trie index over filled KV blocks.
 
-PR 5's paged cache shares prefix blocks only *within* one batch of k
-repeats — every new batch re-prefills system prompts the pool has already
-paid for, exactly the prefill memory traffic the paper's roofline
-decomposition says dominates edge decode. `PrefixPool` promotes the
-`BlockAllocator` budget to a single resident pool that outlives batches:
+Without it the paged cache shares prefix blocks only *within* one batch of
+k repeats — every new batch re-prefills system prompts the resident cache
+has already held, exactly the prefill memory traffic the paper's roofline
+decomposition says dominates edge decode. `PrefixPool` indexes the filled
+blocks of the backend's one resident cache so they outlive their batches:
 
 * The trie is keyed on *token-id block chunks*: each node owns one physical
   KV block holding the keys/values of exactly ``block_size`` tokens, and the

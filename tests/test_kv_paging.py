@@ -106,6 +106,17 @@ def test_allocator_free_of_trie_resident_block_raises():
         a.protect(b, "stale")           # protection requires a live block
 
 
+def _distinct_gids(layout):
+    """The allocator ids a layout's sequences reference."""
+    return {g for gids in layout.seq_gids for g in gids}
+
+
+def _cache_blocks(cache):
+    """Block counts of every pool of a paged cache (one per layer)."""
+    pools = list(cache["prefix"]) + list(cache["blocks"].values())
+    return {e["pos"].shape[-2] for e in pools}
+
+
 def _run_lifecycle(n_blocks, bs, requests, early, seed):
     """Drive build_paged_layout + early/final release over `requests`
     (plen, max_new, k) triples; checks the allocator invariants throughout.
@@ -121,7 +132,7 @@ def _run_lifecycle(n_blocks, bs, requests, early, seed):
             continue                   # admission would reject; skip
         layout = build_paged_layout(a, plen, max_new, [k])
         assert a.blocks_in_use + a.blocks_free == a.n_blocks
-        assert layout.n_pool_blocks == need
+        assert len(_distinct_gids(layout)) == need
         live.append((layout, set()))
     for layout, freed in live:
         n_seq = len(layout.seq_gids)
@@ -167,7 +178,7 @@ def test_request_blocks_matches_actual_allocation():
         a = BlockAllocator(64, 4)
         layout = build_paged_layout(a, plen, max_new, [k])
         assert a.blocks_in_use == be.request_blocks(plen, max_new, k)
-        assert layout.n_pool_blocks == a.blocks_in_use
+        assert len(_distinct_gids(layout)) == a.blocks_in_use
         # shared-prefix price is never above the dense-equivalent price
         dense_eq = k * -(-(plen + max_new) // 4)
         assert be.request_blocks(plen, max_new, k) <= dense_eq
@@ -183,31 +194,57 @@ def _generate(backend, prompts, n_samples, max_new, seed):
     return backend.finalize(h), h
 
 
-@pytest.mark.parametrize("n_samples,plen,max_new", [
-    (3, 7, 6),        # partial prefix block -> CoW fan-out; padded tail
-    (1, 8, 8),        # no sharing, block-aligned
-    ([2, 3], 7, 5),   # non-uniform per-prompt sample counts
+def _serve_batches(backend, prompts, n_samples, max_new, batches):
+    """Start ``batches`` batches of the same prompts (seeds 7, 8, ...), all
+    live at once, then drain and finalize each."""
+    hs = [backend.start_batch(prompts, n_samples, max_new, 0.8,
+                              jax.random.key(7 + i)) for i in range(batches)]
+    for h in hs:
+        while backend.decode_step(h):
+            pass
+    return [(backend.finalize(h), h) for h in hs]
+
+
+@pytest.mark.parametrize("n_samples,plen,max_new,batches", [
+    # partial prefix block -> CoW fan-out; padded tail
+    pytest.param(3, 7, 6, 1, id="3-7-6"),
+    # no sharing, block-aligned
+    pytest.param(1, 8, 8, 1, id="1-8-8"),
+    # non-uniform per-prompt sample counts
+    pytest.param([2, 3], 7, 5, 1, id="n_samples2-7-5"),
+    # the same prompts in two live batches, no trie: one resident cache
+    pytest.param(3, 7, 6, 2, id="3-7-6-two-batches"),
 ])
 def test_paged_decode_bit_identical_to_dense(model_params, n_samples, plen,
-                                             max_new):
+                                             max_new, batches):
     """Acceptance: paged decode (prefix sharing + CoW + block-table
     attention) is bit-identical to the dense path — tokens AND logprobs."""
     model, params = model_params
     prompts = [_prompt(plen), _prompt(plen, mult=3)]
     dense = ExecutionBackend(model, params)
     paged = ExecutionBackend(model, params, kv_blocks=64, kv_block_size=4)
-    want, _ = _generate(dense, prompts, n_samples, max_new, seed=7)
-    got, h = _generate(paged, prompts, n_samples, max_new, seed=7)
-    for a, b in zip(want, got):
-        assert len(a.samples) == len(b.samples)
-        for s1, s2 in zip(a.samples, b.samples):
-            np.testing.assert_array_equal(s1, s2)
-        assert a.logprobs == b.logprobs
+    assert paged.prefix_pool is None
+    want = _serve_batches(dense, prompts, n_samples, max_new, batches)
+    got = _serve_batches(paged, prompts, n_samples, max_new, batches)
     # paged prefilled one row per prompt, not per sequence
     B = sum(n_samples) if isinstance(n_samples, list) else \
         n_samples * len(prompts)
-    assert h.prefill_bytes_saved == \
-        (B - len(prompts)) * plen * paged.kv_token_bytes
+    for (rw, _), (rg, h) in zip(want, got):
+        for a, b in zip(rw, rg):
+            assert len(a.samples) == len(b.samples)
+            for s1, s2 in zip(a.samples, b.samples):
+                np.testing.assert_array_equal(s1, s2)
+            assert a.logprobs == b.logprobs
+        assert h.prefill_bytes_saved == \
+            (B - len(prompts)) * plen * paged.kv_token_bytes
+        # tables hold the allocator's global ids into the resident cache;
+        # no batch holds a cache of its own
+        assert h.cache is None
+        assert set(h.paged.decode_table.ravel()) == _distinct_gids(h.paged)
+    # batches live at once index disjoint blocks of the one cache
+    ids = [_distinct_gids(h.paged) for _, h in got]
+    assert sum(map(len, ids)) == len(set().union(*ids))
+    assert _cache_blocks(paged._pool_cache) == {paged.allocator.n_blocks}
     assert paged.allocator.blocks_free == paged.allocator.n_blocks
 
 
@@ -389,9 +426,10 @@ def test_release_sequences_frees_blocks_mid_flight(model_params):
     freed = be.release_sequences(h, [0])
     assert freed == 2
     assert be.allocator.blocks_in_use == in_use - 2
-    # the budget frees before the memory does: the batch's pool array stays
-    # resident until retirement
-    assert be.pool_blocks_resident == in_use
+    # the budget is the bytes: the batch holds no array of its own, and the
+    # one resident cache stays kv_blocks blocks after the early release
+    assert h.cache is None
+    assert _cache_blocks(be._pool_cache) == {be.allocator.n_blocks}
     assert be.release_sequences(h, [0]) == 0        # idempotent per sample
     # releasing the rest returns everything, including the shared prefix
     assert be.release_sequences(h, [1, 2]) == 5
@@ -440,7 +478,7 @@ def test_failed_paged_prefill_returns_blocks(model_params, monkeypatch):
     def _boom(*a, **k):
         raise RuntimeError("simulated prefill failure")
 
-    monkeypatch.setattr(be, "_prefill_jit", _boom)
+    monkeypatch.setattr(be, "_tail_prefill_jit", _boom)
     with pytest.raises(RuntimeError, match="simulated"):
         be.start_batch([_prompt(7)], 3, 6, 0.8, jax.random.key(0))
     assert be.allocator.blocks_free == 32

@@ -7,8 +7,8 @@
 * eviction never fires on a block any admitted request holds a ref to
   (hard error naming the block and its owning prefix), and interior nodes
   never orphan children (leaf-first peeling);
-* pooled decode is *bit-identical* to the non-pooled paged path (tokens +
-  logprobs), cold and cache-hot, greedy and sampled, including the CoW
+* pooled decode is *bit-identical* to the paged path without the trie
+  (tokens + logprobs), cold and cache-hot, greedy and sampled, including the CoW
   partial tail block and non-uniform per-prompt sample counts — the pinned
   acceptance parity;
 * admission prices cache-hot requests at marginal (post-dedup) cost and
@@ -269,7 +269,7 @@ def _assert_results_identical(got, want):
 def test_pooled_matches_paged_bitwise_cold_and_hot(model_params, temperature,
                                                    n_samples):
     """The pinned acceptance parity: pooled tokens/logprobs are bit-equal
-    to the non-pooled paged path — on a cold trie (full prefill + insert)
+    to the paged path without the trie — on a cold trie (full prefill + insert)
     and cache-hot (trie hits + tail-only prefill) — greedy and sampled,
     with the CoW partial tail block (plen=7 on bs=4) and non-uniform
     per-prompt sample counts."""
@@ -288,6 +288,13 @@ def test_pooled_matches_paged_bitwise_cold_and_hot(model_params, temperature,
     assert got[0][1].pool_hit_blocks == 0
     assert got[1][1].pool_hit_blocks == 2
     assert pooled.allocator.blocks_in_use == pooled.prefix_pool.blocks_resident
+    # with the trie or without, a paged backend holds one resident cache of
+    # kv_blocks blocks and no batch holds one of its own
+    for be, runs in ((plain, want), (pooled, got)):
+        cache = be._pool_cache
+        pools = list(cache["prefix"]) + list(cache["blocks"].values())
+        assert {e["pos"].shape[-2] for e in pools} == {64}
+        assert all(h.cache is None for _, h in runs)
 
 
 def test_pool_hits_and_prefill_bytes_saved_accounting(model_params):
